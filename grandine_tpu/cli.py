@@ -287,20 +287,10 @@ def _node_once(args, cfg) -> int:
     if getattr(args, "admission_max_share", None):
         node.admission.max_share = args.admission_max_share
     if args.use_device and not getattr(args, "no_warm", False):
-        # precompile the kernel shape manifest in the background while
-        # the node syncs — an uncompiled bucket mid-chain stalls
-        # verification for the whole compile (runtime/warmup.py). The
-        # shared registry unlocks the indexed-kernel rows, and metrics
-        # wires verify_recompiles_total so a post-warmup compile is
-        # visible; completion seals the shape ledger.
-        from grandine_tpu.runtime.warmup import warm_in_background
-
-        verifier = getattr(node, "attestation_verifier", None)
-        warm_in_background(
-            progress=lambda m: print(f"[warmup] {m}"),
-            registry=getattr(verifier, "registry", None),
-            metrics=metrics,
-            mesh=node.mesh,
+        _warm_firehose(
+            node, cfg, metrics,
+            networked=getattr(args, "listen_port", None) is not None
+            or bool(getattr(args, "peer", None)),
         )
     if getattr(args, "web3signer_url", None):
         # remote-signer registry for a ValidatorService embedding; the
@@ -481,6 +471,75 @@ def _node_once(args, cfg) -> int:
         node.stop()
         db.close()
     return 0
+
+
+def _firehose_warm_plan(state, cfg, max_batch: int, networked: bool):
+    """[(batch bucket, committee width)] — every shape the attestation
+    firehose of THIS node can dispatch to the indexed aggregate kernel,
+    read from the head state instead of the whole manifest (~80 pairs,
+    minutes of compile and gigabytes of host memory each when cold).
+
+    Both axes of the kernel are bucketed: the batch (1..max_batch
+    aggregates) and the member axis (widest committee in the batch). A
+    node without gossip ingress only ever sees its own duty loop — one
+    batch of every committee of the slot, each a full aggregate — so it
+    needs one batch bucket and the committee-size bucket(s). A networked
+    node can be handed anything from a single vote to a full batch."""
+    from grandine_tpu.consensus import accessors
+    from grandine_tpu.tpu.bls import _bucket
+
+    p = cfg.preset
+    epoch = accessors.get_current_epoch(state, p)
+    active = len(accessors.get_active_validator_indices(state, epoch))
+    per_slot = accessors.committee_count_per_slot(active, p)
+    committees = p.SLOTS_PER_EPOCH * per_slot
+    narrowest, widest = max(1, active // committees), -(-active // committees)
+
+    def ladder(lo: int, hi: int) -> "list[int]":
+        return [b for b in (4 << i for i in range(16)) if lo <= b <= hi]
+
+    if networked or per_slot > max_batch:
+        batches = ladder(4, _bucket(max_batch))
+        widths = ladder(4, _bucket(widest))
+    else:
+        batches = [_bucket(per_slot)]
+        widths = sorted({_bucket(narrowest), _bucket(widest)})
+    return [(b, w) for w in widths for b in batches]
+
+
+def _warm_firehose(node, cfg, metrics, networked: bool) -> None:
+    """Compile what this node's device lanes can dispatch BEFORE the
+    first slot: sequentially, on this thread, host memory trimmed after
+    each entry (runtime/warmup.py). Today `cli run` builds one device
+    lane, the attestation firehose over the resident pubkey registry.
+    Not in the background: a compile beside the dispatch threads' own
+    first compiles doubles a ~6 GB peak, and a slot that meets an
+    uncompiled bucket stalls for the whole compile anyway."""
+    from grandine_tpu.consensus import accessors
+    from grandine_tpu.runtime.warmup import jit_cache_dir, warm_all
+
+    verifier = node.attestation_verifier
+    state = node.controller.snapshot().head_state
+    plan = _firehose_warm_plan(state, cfg, verifier.max_batch, networked)
+    widths = sorted({w for _, w in plan})
+    print(
+        f"[warmup] {len(plan)} entries: aggregate_idx batch buckets "
+        f"{sorted({b for b, _ in plan})} x committee widths {widths}, one "
+        f"at a time (cold: minutes each; cache {jit_cache_dir()})",
+        flush=True,
+    )
+    if verifier.registry is not None:
+        verifier.registry.ensure(accessors.registry_columns(state).pubkeys)
+    for width in widths:
+        warm_all(
+            buckets=[("aggregate_idx", b) for b, w in plan if w == width],
+            progress=lambda m: print(f"[warmup] {m}", flush=True),
+            registry=verifier.registry,
+            metrics=metrics,
+            mesh=node.mesh,
+            committee_width=width,
+            seal=width == widths[-1],
+        )
 
 
 def _follow_loop(args, node, transport) -> int:

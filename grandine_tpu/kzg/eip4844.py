@@ -145,7 +145,7 @@ def _msm_device(setup: TrustedSetup, scalars: "Sequence[int]") -> Point:
         cache = setup._dev_cache = (xs, ys, inf)
     xs, ys, inf = cache
 
-    from grandine_tpu.tpu.bls import _jitted_global, note_dispatch_shapes
+    from grandine_tpu.tpu.bls import _jitted_global, dispatch_scope
 
     def msm_kernel(px, py, p_inf, bits):
         import jax.numpy as jnp
@@ -160,10 +160,10 @@ def _msm_device(setup: TrustedSetup, scalars: "Sequence[int]") -> Point:
     fn = _jitted_global("kzg_msm", msm_kernel)
     bits = C.scalars_to_bits_msb([s % BLS_MODULUS for s in scalars], 255)
     args = (xs, ys, inf, bits)
-    note_dispatch_shapes("kzg_msm", args)
     from grandine_tpu.tpu.bls import _node_profiler
 
-    with _node_profiler().annotate("kzg_msm", len(scalars)):
+    with dispatch_scope("kzg_msm", args), \
+            _node_profiler().annotate("kzg_msm", len(scalars)):
         X, Y, Z = fn(*args)
     import numpy as np
 
@@ -560,25 +560,25 @@ class KzgDeviceBackend:
             return lambda: True
         import numpy as np
 
-        from grandine_tpu.tpu.bls import _jitted_global, note_dispatch_shapes
+        from grandine_tpu.tpu.bls import _jitted_global, dispatch_scope
 
         px, py, pinf, bits, q2x, q2y, n = prep
         fn = _jitted_global("kzg_blob_verify", _blob_verify_kernel)
         args = (px, py, pinf, bits, q2x, q2y)
-        note_dispatch_shapes("kzg_blob_verify", args, self.metrics)
+        scope = dispatch_scope("kzg_blob_verify", args, self.metrics)
         self._count_kernel("kzg_blob_verify", n)
         from grandine_tpu.tpu.bls import _node_profiler
 
         prof_scope = _node_profiler().annotate("kzg_blob_verify", n)
         if self.tracer is not None:
-            with self.tracer.span(
+            with scope, self.tracer.span(
                 "device_dispatch",
                 {"kernel": "kzg_blob_verify", "lane": self.lane},
             ):
                 with prof_scope:
                     out = fn(*args)
         else:
-            with prof_scope:
+            with scope, prof_scope:
                 out = fn(*args)
 
         def settle() -> bool:

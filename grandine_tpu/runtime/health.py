@@ -41,6 +41,8 @@ import time
 from collections import deque
 from typing import Callable, Optional, Sequence
 
+from grandine_tpu.tpu.compile_scope import compile_seconds
+
 # ---------------------------------------------------------------- states
 
 CLOSED = "closed"
@@ -50,7 +52,7 @@ HALF_OPEN = "half_open"
 #: gauge encoding for verify_breaker_state (README "Fault tolerance")
 STATE_CODES = {CLOSED: 0, OPEN: 1, HALF_OPEN: 2}
 
-#: breaker fault taxonomy (the `kind` label on verify_breaker_faults)
+#: breaker fault kinds (the `kind` label on verify_breaker_faults)
 FAULT_KINDS = ("dispatch", "settle", "watchdog", "verdict")
 
 # ------------------------------------------------------- async-seam shape
@@ -78,6 +80,10 @@ def has_async_seam(backend) -> bool:
 OK = "ok"
 FAULT = "fault"
 TIMEOUT = "timeout"
+
+#: how often an expired watchdog re-reads the compile clock while its
+#: thread is still inside a compile
+_COMPILE_POLL_S = 0.05
 
 
 class SettleOutcome:
@@ -117,6 +123,7 @@ def run_with_deadline(fn: Callable[[], object],
     def _run() -> None:
         # watchdog thread: sole writer of `box`; the caller reads it
         # only after `settled` fires (or abandons it on timeout)
+        box["compiled0"] = compile_seconds(threading.get_ident())[0]
         try:
             box["value"] = fn()
         except BaseException as e:
@@ -125,9 +132,24 @@ def run_with_deadline(fn: Callable[[], object],
             settled.set()
 
     t = threading.Thread(target=_run, name=thread_name, daemon=True)
+    t0 = time.monotonic()
     t.start()
-    if not settled.wait(timeout_s):
-        return SettleOutcome(TIMEOUT)
+    # The deadline bounds DEVICE time. jax.jit compiles synchronously
+    # where a shape is first called, and some settles dispatch inside
+    # themselves (chunked batches, the sign plane); a compile there is
+    # minutes of host work, not a hung device. So time the thread spent
+    # inside a `compiling()` scope (tpu/compile_scope.py) is not charged.
+    wait_s = timeout_s
+    while not settled.wait(wait_s):
+        compiled, compiling_now = compile_seconds(t.ident)
+        compiled -= box.get("compiled0", 0.0)
+        left = timeout_s + compiled - (time.monotonic() - t0)
+        if compiling_now:
+            wait_s = max(left, _COMPILE_POLL_S)
+        elif left > 0:
+            wait_s = left
+        else:
+            return SettleOutcome(TIMEOUT)
     if "error" in box:
         return SettleOutcome(FAULT, error=box["error"])
     return SettleOutcome(OK, value=box["value"])

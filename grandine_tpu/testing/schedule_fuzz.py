@@ -130,6 +130,16 @@ class _Worker:
         self.resume.wait()
         self.resume.clear()
         if self.harness._aborted:
+            # Unwinding an exception through a frame that is being traced
+            # per OPCODE segfaults CPython 3.12 (the abort surfaces inside
+            # a watched `with` body). Stop tracing every frame of this
+            # thread before the abort starts to unwind.
+            sys.settrace(None)
+            frame = sys._getframe()
+            while frame is not None:
+                frame.f_trace = None
+                frame.f_trace_opcodes = False
+                frame = frame.f_back
             raise _FuzzAbort
 
     def _park(self) -> None:

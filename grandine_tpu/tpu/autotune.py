@@ -135,7 +135,10 @@ def write_tuning(table: "dict[str, int]", path=None) -> str:
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(tmp, "w", encoding="utf-8") as fh:
         json.dump(
-            {"windows": {k: int(v) for k, v in sorted(table.items())}},
+            {
+                "platform": jax.default_backend(),
+                "windows": {k: int(v) for k, v in sorted(table.items())},
+            },
             fh, indent=2, sort_keys=True,
         )
         fh.write("\n")

@@ -44,7 +44,7 @@ import sys
 import threading
 import time
 from collections import OrderedDict
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Sequence
 
 import jax
@@ -60,17 +60,9 @@ from grandine_tpu.tpu import field as F
 from grandine_tpu.tpu import limbs as L
 from grandine_tpu.tpu import msm as M
 from grandine_tpu.tpu import pairing as TP
+from grandine_tpu.tpu.compile_scope import compiling
 
-try:  # jax >= 0.6 exports shard_map at top level (kwarg: check_vma)
-    shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental module, kwarg named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
+shard_map = jax.shard_map
 
 
 # --- module constants (host, Montgomery limb form) -------------------------
@@ -357,11 +349,13 @@ def grouped_multi_verify_kernel(
 #
 # A measured calibration sweep (tools.shapes --autotune → tpu/autotune.py)
 # persists its winning window widths next to the shape manifest as
-# tools/shapes/msm_tune.json: {"windows": {"<n_points>:<n_groups>": w}}.
-# pick_msm_window consults the table first (keys quantized up to the same
-# pow-2 buckets the dispatch plane uses) and falls back to the analytic op
-# model for unmeasured shapes, so a node with no table behaves exactly as
-# before.
+# tools/shapes/msm_tune.json: {"platform": "<jax backend>", "windows":
+# {"<n_points>:<n_groups>": w}}. pick_msm_window consults the table first
+# (keys quantized up to powers of two, as the dispatch plane buckets) and
+# falls back to the analytic op model for unmeasured shapes. A table is
+# only believed on the platform it was measured on: widths timed on the
+# CPU say nothing about the chip. No table is checked in — none has been
+# measured on a chip yet — so the op model stands alone until one is.
 
 _MSM_TUNE: "Optional[dict]" = None
 _MSM_TUNE_LOCK = threading.Lock()
@@ -392,9 +386,12 @@ def load_msm_tuning(path: "Optional[str]" = None) -> "Optional[dict]":
             with open(path or msm_tune_path(), encoding="utf-8") as fh:
                 raw = json.load(fh)
             table = {}
+            windows = dict(raw.get("windows", {}))
+            if raw.get("platform") != jax.default_backend():
+                windows = {}  # measured elsewhere: the op model stands
             # per-entry validation: one corrupt row must not discard the
             # rest of the measured table
-            for k, v in dict(raw.get("windows", {})).items():
+            for k, v in windows.items():
                 try:
                     w = int(v)
                 except (ValueError, TypeError):
@@ -429,12 +426,17 @@ def pick_msm_window(n_points: int, n_groups: int = 1) -> int:
     also, in practice, the VMEM-resident choice.
 
     A measured entry in the autotune table (load_msm_tuning) wins over
-    the model; lookup keys quantize to the dispatch plane's pow-2
-    buckets so a table built from the calibration sweep covers every
-    shape the warmed kernels can see."""
+    the model; lookup keys quantize up to powers of two, as the dispatch
+    plane's buckets do, so a table built from the calibration sweep
+    covers every shape the warmed kernels can see. The key has no upper
+    bound: kernel-level callers (bench.py) plan shapes above MAX_BUCKET,
+    which simply miss the table."""
     table = load_msm_tuning()
     if table:
-        key = "%d:%d" % (_bucket(n_points), _bucket(max(1, n_groups), lo=1))
+        key = "%d:%d" % (
+            _bucket(n_points, hi=n_points << 1),
+            _bucket(max(1, n_groups), lo=1, hi=max(1, n_groups) << 1),
+        )
         w = table.get(key)
         if w is not None:
             return w
@@ -889,8 +891,8 @@ def g1_decompress_rows(rows, metrics=None):
     call out of runtime/)."""
     fn = _jitted_global("g1_decompress", g1_decompress_kernel)
     args = (jnp.asarray(rows),)
-    note_dispatch_shapes("g1_decompress", args, metrics)
-    return fn(*args)
+    with dispatch_scope("g1_decompress", args, metrics):
+        return fn(*args)
 
 
 def batch_sign_kernel(msg_x, msg_y, msg_inf, sk_bits, sk_neg):
@@ -1073,8 +1075,8 @@ def g2_aggregate_groups(groups, metrics=None):
         jnp.asarray(sx), jnp.asarray(sy), jnp.asarray(sinf),
         jnp.zeros((gb,), jnp.int32),
     )
-    note_dispatch_shapes("g2_aggregate", args, metrics)
-    X, Y, Z = fn(*args)
+    with dispatch_scope("g2_aggregate", args, metrics):
+        X, Y, Z = fn(*args)
     X, Y, Z = np.asarray(X), np.asarray(Y), np.asarray(Z)
     return [
         A.Signature(C.dev_to_g2_point(X[i], Y[i], Z[i])) for i in range(m)
@@ -1118,8 +1120,8 @@ def g1_aggregate_groups(groups, metrics=None):
         jnp.asarray(px), jnp.asarray(py), jnp.asarray(pinf),
         jnp.zeros((gb,), jnp.int32),
     )
-    note_dispatch_shapes("g1_aggregate", args, metrics)
-    X, Y, Z = fn(*args)
+    with dispatch_scope("g1_aggregate", args, metrics):
+        X, Y, Z = fn(*args)
     X, Y, Z = np.asarray(X), np.asarray(Y), np.asarray(Z)
     return [
         A.PublicKey(C.dev_to_g1_point(X[i], Y[i], Z[i])) for i in range(m)
@@ -1201,7 +1203,7 @@ def make_sharded_multi_verify(mesh, axis: str = "batch",
     fn = shard_map(
         local_step, mesh=mesh, in_specs=shardings, out_specs=P(), check_vma=False
     )
-    return _no_persistent_cache_first_call(jax.jit(fn))
+    return jax.jit(fn)
 
 
 def sharded_msm_plans(r_lo, r_hi, pk_inf, sig_inf, n_dev: int):
@@ -1388,7 +1390,7 @@ def make_sharded_multi_verify_msm(
         local_step_msm, mesh=mesh, in_specs=in_specs, out_specs=P(),
         check_vma=False,
     )
-    return _no_persistent_cache_first_call(jax.jit(fn))
+    return jax.jit(fn)
 
 
 # --- promoted sharded dispatch targets --------------------------------------
@@ -1451,66 +1453,6 @@ def sharded_multi_verify_msm(
             )
             _SHARDED_FACTORIES[key] = fn
     return fn
-
-
-import threading as _threading
-
-_CACHE_BYPASS_LOCK = _threading.RLock()
-_CACHE_BYPASS_DEPTH = [0]
-
-
-def _no_persistent_cache_first_call(jitted):
-    """Wrap a jitted MULTI-DEVICE function so every call runs with the
-    persistent compilation cache bypassed in both directions (jax.jit
-    compiles once per input SHAPE, so any call may compile).
-
-    Multi-device executables and the on-disk cache do not mix here:
-    serializing one ABORTS inside XLA (proto-size CHECK in
-    put_executable_and_time), and deserializing an entry written by an
-    earlier/killed run SEGFAULTS in get_executable_and_time — both
-    observed on the 8-device CPU mesh. The bypass is SCOPED: the
-    thread-local config context manager (enable_compilation_cache)
-    disables the cache for this call stack only — the process-global
-    jax_enable_compilation_cache flag is never touched, so threads
-    outside the wrapper keep their own setting. The cache-enabled
-    decision is LATCHED per process (compilation_cache.is_cache_used
-    memoizes its first config read), so the scoped flag is paired with
-    a latch reset on both sides, and the latch is re-primed from THIS
-    thread (whose scoped view is "disabled") before the jitted call so
-    a concurrent compile cannot latch it enabled first. A depth-counted
-    lock makes concurrent sharded calls nest instead of racing the
-    window shut; unrelated kernels that compile inside an open window
-    merely skip their cache entry (benign, unchanged from before)."""
-    def call(*args):
-        return _cache_bypassed_call(jitted, *args)
-
-    return call
-
-
-def _cache_bypassed_call(fn, *args):
-    """Run one call with the persistent compilation cache scoped OFF (see
-    `_no_persistent_cache_first_call` for the full rationale). Also used
-    directly by the backend's mesh-mode indexed dispatches, whose
-    executables become multi-device once the registry rows are sharded."""
-    from jax._src import compilation_cache as _cc
-    from jax._src import config as _jcfg
-
-    with _jcfg.enable_compilation_cache(False):
-        with _CACHE_BYPASS_LOCK:
-            _CACHE_BYPASS_DEPTH[0] += 1
-            if _CACHE_BYPASS_DEPTH[0] == 1:
-                _cc.reset_cache()
-                try:  # prime the latch under the scoped "disabled"
-                    _cc.is_cache_used(jax.devices()[0].client)
-                except Exception:
-                    pass  # latch priming is best-effort
-        try:
-            return fn(*args)
-        finally:
-            with _CACHE_BYPASS_LOCK:
-                _CACHE_BYPASS_DEPTH[0] -= 1
-                if _CACHE_BYPASS_DEPTH[0] == 0:
-                    _cc.reset_cache()  # re-latch lazily outside
 
 
 # --- host-facing backend ----------------------------------------------------
@@ -1599,6 +1541,17 @@ def note_dispatch_shapes(kernel: str, args: tuple, metrics=None) -> bool:
     if sealed and metrics is not None:
         metrics.verify_recompiles.inc()
     return True
+
+
+def dispatch_scope(kernel: str, args: tuple, metrics=None):
+    """Note a dispatch signature and return the scope its call runs in.
+    jax.jit compiles synchronously where a signature is first CALLED, so
+    a novel one runs inside `compiling()` (tpu/compile_scope.py): the
+    settle watchdog does not charge that time, and host memory is trimmed
+    when the compile ends. A known signature gets a null scope."""
+    if note_dispatch_shapes(kernel, args, metrics):
+        return compiling()
+    return nullcontext()
 
 
 def declare_warmup_complete() -> None:
@@ -1749,10 +1702,6 @@ class TpuBlsBackend:
             else:
                 donate_buffers = jax.default_backend() != "cpu"
         self.donate_buffers = bool(donate_buffers)
-        #: (kernel, arg shapes) pairs already dispatched — a miss means
-        #: the next dispatch blocks on XLA compilation, so its host-side
-        #: call time is attributed to the `compile` stage
-        self._seen_shapes: set = set()
 
     # -- conversions -------------------------------------------------------
 
@@ -1846,41 +1795,26 @@ class TpuBlsBackend:
             ))
 
     def _run_kernel(self, kernel: str, fn, args: tuple, sigs: int = 0,
-                    block: bool = True, mesh_operands: bool = False):
+                    block: bool = True):
         """Dispatch with compile/execute attribution. The first dispatch
-        for a (kernel, shapes) pair blocks on trace+XLA compilation, so
-        its host-side call time IS the compile stage; warm dispatches are
-        async µs and the device run is timed via block_until_ready. With
+        of a (kernel, shapes) signature in this process (the shape ledger,
+        `dispatch_scope`) blocks on trace+XLA compilation, so its
+        host-side call time IS the compile stage and runs inside
+        `compiling()`; warm dispatches are async µs and the device run is
+        timed via block_until_ready. With
         block=False the caller keeps the async seam and settles later
-        (see _settle). `mesh_operands` marks kernels consuming
-        mesh-committed arrays (sharded registry rows): on a multi-device
-        mesh their executables are multi-device, which the persistent XLA
-        cache cannot round-trip, so the call runs cache-bypassed."""
+        (see _settle)."""
         self._count_kernel(kernel, sigs)
-        note_dispatch_shapes(kernel, args, self.metrics)
+        novel = note_dispatch_shapes(kernel, args, self.metrics)
         prof = _node_profiler()
-        if mesh_operands and self.mesh is not None:
-            inner = fn
-
-            def fn(*a):
-                return _cache_bypassed_call(inner, *a)
-        if not self._observed():
-            with prof.annotate(kernel, sigs):
-                return fn(*args)
-        shapes = tuple(
-            (tuple(a.shape), str(a.dtype)) if hasattr(a, "shape") else repr(a)
-            for a in args
-        )
-        key = (kernel, shapes)
-        if key not in self._seen_shapes:
-            with self._stage("compile", kernel=kernel):
+        if novel:
+            with compiling(), self._stage("compile", kernel=kernel):
                 with prof.annotate(kernel, sigs):
                     out = fn(*args)
-            self._seen_shapes.add(key)
         else:
             with prof.annotate(kernel, sigs):
                 out = fn(*args)
-        if block:
+        if block and self._observed():
             with self._stage("execute", kernel=kernel):
                 self._block(out)
         return out
@@ -2014,7 +1948,6 @@ class TpuBlsBackend:
             )
             result = self._run_kernel(
                 "sharded_multi_verify", fn, args, sigs=n, block=False,
-                mesh_operands=True,
             )
             return lambda: self._settle("sharded_multi_verify", result)
         with self._stage("host_prep", op="msm_plan", items=n):
@@ -2166,7 +2099,6 @@ class TpuBlsBackend:
         )
         result = self._run_kernel(
             "sharded_multi_verify_msm", fn, args, sigs=n_real, block=False,
-            mesh_operands=True,
         )
         return lambda: self._settle("sharded_multi_verify_msm", result)
 
@@ -2412,7 +2344,7 @@ class TpuBlsBackend:
         )
         out = self._run_kernel(
             "agg_fast_verify_msm_idx", fn, (reg_x, reg_y, *args),
-            sigs=m, block=False, mesh_operands=True,
+            sigs=m, block=False,
         )
         return lambda: self._settle("agg_fast_verify_msm_idx", out)
 
@@ -2758,7 +2690,7 @@ class TpuBlsBackend:
         )
         out = self._run_kernel(
             "agg_fast_verify_msm_idx_comp", fn, (reg_x, reg_y, *args),
-            sigs=m, block=False, mesh_operands=True,
+            sigs=m, block=False,
         )
         return lambda: self._settle("agg_fast_verify_msm_idx_comp", out)
 
@@ -2821,7 +2753,7 @@ class TpuBlsBackend:
         )
         result = self._run_kernel(
             "multi_verify_msm_idx", fn, (reg_x, reg_y, *args),
-            sigs=n, block=False, mesh_operands=True,
+            sigs=n, block=False,
         )
         return self._settle("multi_verify_msm_idx", result)
 
@@ -3071,6 +3003,7 @@ __all__ = [
     "sharded_multi_verify_msm",
     "sharded_msm_plans",
     "note_dispatch_shapes",
+    "dispatch_scope",
     "declare_warmup_complete",
     "warmup_declared",
     "post_warmup_recompiles",

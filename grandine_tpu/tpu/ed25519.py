@@ -416,9 +416,9 @@ class Ed25519Backend:
 
         fn = B._jitted_global("ed25519_verify", verify_kernel)
         args = (px, py, pt, bits)
-        B.note_dispatch_shapes("ed25519_verify", args, self.metrics)
+        scope = B.dispatch_scope("ed25519_verify", args, self.metrics)
         self._count_kernel("ed25519_verify", n)
-        with self.tracer.span(
+        with scope, self.tracer.span(
             "device_dispatch", {"kernel": "ed25519_verify", "lane": self.lane}
         ):
             with B._node_profiler().annotate("ed25519_verify", n):

@@ -81,8 +81,8 @@ class SpanPlane:
         from grandine_tpu.tpu import bls as B
 
         self._count_kernel(kernel)
-        B.note_dispatch_shapes(kernel, args, self.metrics)
-        with B._node_profiler().annotate(kernel, len(args[0])):
+        with B.dispatch_scope(kernel, args, self.metrics), \
+                B._node_profiler().annotate(kernel, len(args[0])):
             out = fn(*args)
         for leaf in out:
             if hasattr(leaf, "block_until_ready"):

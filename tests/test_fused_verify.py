@@ -392,6 +392,22 @@ def test_fused_aggregate_and_partition_differential(fused_backend,
 # ----------------------------------------------------------- msm autotune
 
 
+@pytest.mark.parametrize(
+    "n_points,n_groups", [(32768, 256), (1 << 20, 1), (16384, 256)]
+)
+def test_pick_msm_window_above_max_bucket(n_points, n_groups):
+    """bench.py's default shape (32,768 x 256) crashed here: the table
+    key was quantized with the dispatch plane's `_bucket`, which raises
+    above MAX_BUCKET, as soon as ANY table was loaded."""
+    from grandine_tpu.tpu import bls as B
+
+    try:
+        B.set_msm_tuning({"256:1": 4, "64:1": 4, "64:16": 4})
+        assert 4 <= B.pick_msm_window(n_points, n_groups) <= 8
+    finally:
+        B.set_msm_tuning(None)
+
+
 def test_pick_msm_window_consults_table():
     from grandine_tpu.tpu import bls as B
 
@@ -418,9 +434,15 @@ def test_msm_tuning_roundtrip_and_validation(tmp_path):
         assert B.load_msm_tuning(path) == {"64:1": 5, "256:1": 4}
         # out-of-range and malformed entries are dropped, not trusted
         (tmp_path / "bad.json").write_text(
-            '{"windows": {"64:1": 99, "256:1": "x", "16:1": 6}}'
+            '{"platform": "cpu",'
+            ' "windows": {"64:1": 99, "256:1": "x", "16:1": 6}}'
         )
         assert B.load_msm_tuning(str(tmp_path / "bad.json")) == {"16:1": 6}
+        # a table measured on another platform does not steer this one
+        (tmp_path / "chip.json").write_text(
+            '{"platform": "tpu", "windows": {"16:1": 6}}'
+        )
+        assert B.load_msm_tuning(str(tmp_path / "chip.json")) is None
         assert B.load_msm_tuning(str(tmp_path / "missing.json")) is None
     finally:
         B.set_msm_tuning(None)

@@ -183,3 +183,44 @@ def test_cli_exit_codes(ledger, capsys):
     entry = json.loads(out.out.splitlines()[0])
     assert entry["metric"] == "verify_scheduler_throughput"
     assert entry["status"] == "regressed"
+
+
+# ------------------------------------------------- one process per chip
+
+
+def test_bench_parents_import_without_jax():
+    """bench.py's cold-start and device-sweep parents spawn children
+    that need the chip; a parent that has touched JAX holds it. The
+    module, and the cache-directory helper the cold-start parent calls,
+    must import without JAX."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import sys, bench\n"
+        "from grandine_tpu.runtime.warmup import jit_cache_dir\n"
+        "jit_cache_dir()\n"
+        "assert 'jax' not in sys.modules, 'bench.py imported jax'\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True,
+        text=True, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_detect_platform_does_not_hide_a_failed_device_lookup(monkeypatch):
+    """A process that imported jax and cannot list its devices must not
+    be filed as "host"."""
+    import sys
+    import types
+
+    from tools.perf import detect_platform
+
+    broken = types.SimpleNamespace(devices=lambda: 1 / 0)
+    monkeypatch.setitem(sys.modules, "jax", broken)
+    with pytest.raises(ZeroDivisionError):
+        detect_platform()
+    monkeypatch.delitem(sys.modules, "jax")
+    assert detect_platform() == "host"

@@ -150,6 +150,11 @@ def test_bench_replay_smoke(monkeypatch):
         "BENCH_REPLAY_REPS": "1",
         "BENCH_SKIP_LINT": "1",
         "BENCH_SKIP_RANGES": "1",  # preflight gate has its own tests
+        # ...and so has the perf gate (tests/test_perf_ledger.py): this
+        # smoke must not hang on whatever the checked-in ledger's newest
+        # CPU row happens to read, nor append to it
+        "BENCH_SKIP_PERF_CHECK": "1",
+        "BENCH_LEDGER": "0",
     }.items():
         monkeypatch.setenv(key, val)
     buf = io.StringIO()

@@ -297,6 +297,63 @@ def test_settle_fault_degrades_to_host_and_blocks_still_import(genesis):
         s.stop()
 
 
+@pytest.mark.parametrize("first_call_compiles", [True, False])
+def test_compile_inside_settle_is_not_a_device_fault(first_call_compiles):
+    """jax.jit compiles where a shape is first CALLED, and some settles
+    dispatch inside themselves (chunked batches, the sign plane). A
+    first call that outlasts the settle deadline because it COMPILES
+    (tpu/compile_scope.compiling) must not feed the breaker or send the
+    batch to the host twin; the same delay outside a compile still must
+    (the control: the watchdog has not been blinded)."""
+    from contextlib import nullcontext
+
+    from grandine_tpu.runtime.health import BackendHealthSupervisor
+    from grandine_tpu.tpu.compile_scope import compiling
+
+    deadline_s = 0.15
+
+    class _SlowFirstCall(_FakeAsyncBackend):
+        def fast_aggregate_verify_batch_async(self, messages, sigs, keys):
+            inner = super().fast_aggregate_verify_batch_async(
+                messages, sigs, keys
+            )
+
+            def settle():
+                if len(self.batches) == 1:  # first call of the shape
+                    scope = compiling() if first_call_compiles \
+                        else nullcontext()
+                    with scope:
+                        time.sleep(4 * deadline_s)
+                return inner()
+
+            return settle
+
+    key = _interop_keys(0)
+    msg = b"\x09" * 32
+    item = VerifyItem(
+        msg, key.sign(msg).to_bytes(), public_keys=(key.public_key(),)
+    )
+    m = Metrics()
+    health = BackendHealthSupervisor(metrics=m, settle_timeout_s=deadline_s)
+    s = VerifyScheduler(
+        backend=_SlowFirstCall(truth={msg: True}), use_device=True,
+        metrics=m, health=health,
+    )
+    try:
+        assert s.submit("sync_message", [item]).result(60.0) is True
+        faults = s.stats["sync_message"]["device_faults"]
+        degraded = m.verify_lane_batches.value("sync_message", "degraded")
+        fired = m.verify_watchdog_fired.value("sync_message")
+        if first_call_compiles:
+            assert (faults, degraded, fired) == (0, 0.0, 0.0)
+            assert m.verify_lane_batches.value("sync_message", "ok") == 1.0
+            assert health.breaker.stats["opens"] == 0
+        else:
+            assert faults == 1 and degraded == 1.0 and fired == 1.0
+    finally:
+        s.stop()
+
+
 def test_dispatch_fault_degrades_to_host(monkeypatch):
     """A fault at dispatch time (before any settle exists) is caught in
     _flush: counted, the batch host-checks, nothing drops."""

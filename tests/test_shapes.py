@@ -233,3 +233,42 @@ def test_warmed_batch_verify_zero_recompiles():
         assert m.verify_recompiles.value == 0.0
     finally:
         B.reset_shape_tracking()
+
+
+def test_compile_cache_can_be_placed(monkeypatch, tmp_path):
+    """One function places the cache: JAX_COMPILATION_CACHE_DIR when the
+    environment sets it — and then NO directory is set in code — else
+    `<checkout>/.jax_cache`."""
+    import jax
+
+    from grandine_tpu.runtime import warmup
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert warmup.jit_cache_dir() == os.path.join(REPO, ".jax_cache")
+        assert warmup.enable_persistent_cache() == warmup.jit_cache_dir()
+        assert jax.config.jax_compilation_cache_dir == warmup.jit_cache_dir()
+        # placed from outside: code sets nothing (JAX reads the variable
+        # itself at start-up; here the config must simply stay untouched)
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert warmup.enable_persistent_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_firehose_warm_plan_reads_the_head_state():
+    """`cli run --use-device` warms what ITS firehose can dispatch, not
+    the manifest: one (batch, width) pair for a gossip-less devnet, the
+    two ladders for a networked node."""
+    from grandine_tpu.cli import _firehose_warm_plan
+    from grandine_tpu.transition.genesis import interop_genesis_state
+    from grandine_tpu.types.config import Config
+
+    cfg = Config.minimal()
+    state = interop_genesis_state(64, cfg)
+    assert _firehose_warm_plan(state, cfg, 64, networked=False) == [(4, 4)]
+    plan = _firehose_warm_plan(state, cfg, 64, networked=True)
+    assert plan == [(b, 4) for b in (4, 8, 16, 32, 64)]

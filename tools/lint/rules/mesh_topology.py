@@ -9,11 +9,8 @@ injected mesh about the fleet, single-device degeneracy becomes
 unprovable, and tests cannot pin a smaller mesh than the platform
 exposes.
 
-Sanctioned exceptions, by (path, qualname):
-  - `VerifyMesh.build` — the one enumeration point the seam itself owns;
-  - `_cache_bypassed_call` in tpu/bls.py — re-primes the persistent
-    compile-cache latch via `jax.devices()[0].client`, a cache
-    implementation detail that never influences dispatch topology.
+Sanctioned exception, by (path, qualname):
+  - `VerifyMesh.build` — the one enumeration point the seam itself owns.
 """
 
 from __future__ import annotations
@@ -31,7 +28,6 @@ TOPOLOGY_CALLS = {
 #: (path, qualname) pairs allowed to enumerate devices
 SANCTIONED = {
     ("grandine_tpu/tpu/mesh.py", "VerifyMesh.build"),
-    ("grandine_tpu/tpu/bls.py", "_cache_bypassed_call"),
 }
 
 

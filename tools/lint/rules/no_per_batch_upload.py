@@ -6,7 +6,11 @@ device pubkey registry, runs the indexed verify path twice, and audits
 the backend's own `device_upload_bytes_total{kernel=...}` accounting
 (the `_upload` seam in tpu/bls.py). kind="runtime" — it compiles
 kernels and needs a working JAX, so it only runs under
-`python -m tools.lint --runtime` (or `--rules no-per-batch-upload`).
+`python -m tools.lint --runtime` (or `--rules no-per-batch-upload`),
+in a process of its own: it takes whatever device JAX finds (the CPU,
+unless the environment says otherwise), and a chip belongs to one process
+at a time — never import it into a process that later needs the chip
+(chip_smoke.py and the bench parents do not).
 
 Checks:
   1. The second warm verify uploads zero registry bytes (identity hit).
