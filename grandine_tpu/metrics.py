@@ -157,9 +157,9 @@ class _LabeledFamily:
 
     `defaults` maps TRAILING label names to fill-in values so a family
     can grow a dimension without breaking existing call sites: after
-    widening `verify_stage_seconds` from ("stage",) to ("stage", "lane")
-    with defaults={"lane": "attestation"}, `labels("execute")` keeps
-    resolving to the pre-existing attestation series."""
+    widening `verify_stage_seconds` from ("stage",) to ("stage", "lane",
+    "op") with defaults={"lane": "attestation", "op": ""},
+    `labels("execute")` keeps resolving to the attestation series."""
 
     def __init__(self, name: str, help_: str,
                  labelnames: "Sequence[str]",
@@ -360,10 +360,6 @@ class Metrics:
         # fork choice / mutator (metrics.rs:49-53,106)
         self.fc_blocks_applied = Counter(
             "fc_blocks_applied_total", "blocks applied to the store")
-        self.fc_attestations_applied = Counter(
-            "fc_attestations_applied_total", "attestations applied")
-        self.fc_block_task_times = Histogram(
-            "fc_block_task_seconds", "block validation task duration")
         self.fc_head_changes = Counter(
             "fc_head_changes_total", "head switches")
         # attestation verifier (metrics.rs:58-60)
@@ -462,16 +458,36 @@ class Metrics:
         # and call sites keep resolving to the same series. Finer low
         # end than the defaults: host prep for a 64-att batch is
         # ~100 µs.
+        # `op` (closed set: tracing.STAGE_OPS) splits a stage that
+        # several call sites feed (host_prep: prevalidate / g2_decompress
+        # / registry_sync / pack_*; feedback: deliver / slasher_feed); ""
+        # for a stage of one part. A stage's time is the SUM over `op`.
         self.verify_stage_seconds = LabeledHistogram(
             "verify_stage_seconds",
-            "batch-verify latency, by pipeline stage and lane",
-            ("stage", "lane"),
+            "batch-verify latency, by pipeline stage, lane and part (op)",
+            ("stage", "lane", "op"),
             buckets=(
                 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
             ),
-            defaults={"lane": "attestation"},
+            defaults={"lane": "attestation", "op": ""},
         )
+        # the compile scope by phase (tpu/compile_scope.py): what JAX
+        # itself reports (jax.monitoring) of the time the program spent
+        # inside `compiling()`. Process-wide counts, brought up to date
+        # at each scrape (`expose`), so a fresh Metrics shows all of it.
+        self.verify_compile_phase_seconds = LabeledCounter(
+            "verify_compile_phase_seconds_total",
+            "seconds inside the compile scope by JAX phase: trace "
+            "(Python to jaxpr), lower (jaxpr to MLIR), backend (XLA "
+            "compile OR persistent-cache load), cache_retrieval (the "
+            "cache read alone, part of backend)",
+            ("phase",))
+        self.verify_compile_cache = LabeledCounter(
+            "verify_compile_cache_total",
+            "persistent compilation cache lookups inside the compile "
+            "scope, by result (hit / miss)",
+            ("result",))
         # verify scheduler (runtime/verify_scheduler.py): per-lane
         # queue occupancy, flushed batches by outcome, enqueue→flush
         # wait, and overload sheds (low lanes drop oldest-first rather
@@ -844,8 +860,22 @@ class Metrics:
             if isinstance(v, (Counter, Gauge, Histogram, _LabeledFamily))
         ]
 
+    def _sync_compile_scope(self) -> None:
+        """Raise the compile-phase counters to the process's totals."""
+        from grandine_tpu.tpu import compile_scope
+
+        seconds, lookups = compile_scope.phase_totals()
+        for family, totals in (
+            (self.verify_compile_phase_seconds, seconds),
+            (self.verify_compile_cache, lookups),
+        ):
+            for label, total in totals.items():
+                child = family.labels(label)
+                child.inc(total - child.value)
+
     def expose(self) -> str:
         """Prometheus text exposition of every registered metric."""
+        self._sync_compile_scope()
         return "".join(m.expose() for m in self.all())
 
 

@@ -18,8 +18,7 @@ import queue
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
-from typing import Callable, Optional, Sequence
+from typing import Optional, Sequence
 
 from grandine_tpu.consensus import accessors, keys, signing
 from grandine_tpu.consensus.verifier import SignatureInvalid
@@ -28,7 +27,7 @@ from grandine_tpu.fork_choice.store import ForkChoiceError, ValidAttestation
 from grandine_tpu.runtime import flight as _flight
 from grandine_tpu.runtime import health as _health
 from grandine_tpu.runtime.thread_pool import Priority
-from grandine_tpu.tracing import NULL_TRACER
+from grandine_tpu.tracing import NULL_TRACER, stage as _stage
 
 MAX_BATCH = 64  # attestation_verifier.rs:37
 
@@ -38,13 +37,36 @@ class GossipAttestation:
     gossip peer attribution ("peer:<id>") for the flight recorder's
     failing-origin table — never a metrics label."""
 
-    __slots__ = ("attestation", "received_at", "origin")
+    __slots__ = ("attestation", "received_at", "origin", "arrived")
 
     def __init__(self, attestation, received_at: "Optional[float]" = None,
-                 origin: "Optional[str]" = None) -> None:
+                 origin: "Optional[str]" = None,
+                 arrived: "Optional[float]" = None) -> None:
         self.attestation = attestation
         self.received_at = received_at if received_at is not None else time.time()
         self.origin = origin
+        #: monotonic arrival stamp (`perf_counter`, the spans' clock):
+        #: every wait of the item's batch is measured from it
+        self.arrived = arrived if arrived is not None else time.perf_counter()
+
+
+class _BatchLife:
+    """What travels with one batch from the collector to its verdict: the
+    root span of its chain and the stamps its waits are measured from."""
+
+    __slots__ = ("root", "arrived", "popped", "pool_span")
+
+    def __init__(self, tracer, batch, popped: float) -> None:
+        #: arrival of the batch's oldest item: where its life begins
+        self.arrived = min(it.arrived for it in batch)
+        self.popped = popped
+        self.root = tracer.span(
+            "verify_batch", {"batch": len(batch)}, start=self.arrived
+        )
+        tracer.span(
+            "collect_wait", parent=self.root, start=self.arrived
+        ).finish()
+        self.pool_span = tracer.span("pool_wait", parent=self.root)
 
 
 class AttestationVerifier:
@@ -196,9 +218,12 @@ class AttestationVerifier:
 
     def submit_many(self, attestations: "Sequence",
                     origin: "Optional[str]" = None) -> None:
+        # one call, one arrival: a stamp for the lot, not one an item
+        wall, arrived = time.time(), time.perf_counter()
         with self._cond:
             self._queue.extend(
-                GossipAttestation(a, origin=origin) for a in attestations
+                GossipAttestation(a, wall, origin, arrived)
+                for a in attestations
             )
             self._cond.notify()
 
@@ -253,9 +278,12 @@ class AttestationVerifier:
             if not batch:
                 return False
             self._active += 1
+        # the batch's chain of spans begins here, back-dated to the
+        # arrival of its oldest item: collect_wait is over, pool_wait runs
+        life = _BatchLife(self.tracer, batch, time.perf_counter())
         try:
             self.controller.pool.spawn(
-                lambda b=batch: self._verify_batch(b), Priority.LOW
+                lambda: self._verify_batch(batch, life), Priority.LOW
             )
         except Exception:
             # pool stopped / spawn failure: release the active slot so
@@ -263,6 +291,7 @@ class AttestationVerifier:
             with self._cond:
                 self._active -= 1
                 self._cond.notify_all()
+            life.root.finish()
             raise
         return False
 
@@ -272,24 +301,26 @@ class AttestationVerifier:
     #: the scheduler's sibling "attestation" lane
     lane = "attestation"
 
-    @contextmanager
     def _stage(self, stage: str, **attrs):
-        """One pipeline stage: a child span under the current trace
-        context plus a `verify_stage_seconds{stage=...,lane=...}`
-        observation."""
-        t0 = time.perf_counter()
-        with self.tracer.span(stage, attrs or None):
-            yield
-        if self.metrics is not None:
-            self.metrics.verify_stage_seconds.labels(
-                stage, self.lane
-            ).observe(time.perf_counter() - t0)
+        """One pipeline stage (tracing.stage): a child span under the
+        current trace context, a `verify_stage_seconds{stage,lane,op}`
+        observation and, during a profiler capture session, a host span
+        on the profiler's clock."""
+        return _stage(self.tracer, self.metrics, stage, self.lane, **attrs)
 
-    def _verify_batch(self, batch: "Sequence[GossipAttestation]") -> None:
+    def _verify_batch(self, batch: "Sequence[GossipAttestation]",
+                      life: _BatchLife) -> None:
+        """Pool-thread entry. Every span of the batch hangs under the
+        root the collector opened (`life.root`), which ends where the
+        flight record commits — here, or on the completion thread."""
         t_batch = time.perf_counter()
+        life.pool_span.finish()
         try:
-            with self.tracer.span("verify_batch", {"batch": len(batch)}):
-                self._verify_batch_traced(batch)
+            with self.tracer.attach(life.root):
+                self._verify_batch_traced(batch, life, t_batch)
+        except BaseException:
+            life.root.finish()  # no record committed: end the chain here
+            raise
         finally:
             with self._cond:
                 self._active -= 1
@@ -302,11 +333,12 @@ class AttestationVerifier:
                     time.perf_counter() - t_batch
                 )
 
-    def _verify_batch_traced(self, batch: "Sequence[GossipAttestation]") -> None:
+    def _verify_batch_traced(self, batch: "Sequence[GossipAttestation]",
+                             life: _BatchLife, t_start: float) -> None:
         snapshot = self.controller.snapshot()
         state = snapshot.head_state
         prepared = []
-        with self._stage("host_prep", items=len(batch)):
+        with self._stage("host_prep", op="prevalidate", items=len(batch)):
             for item in batch:
                 try:
                     prepared.append(
@@ -319,17 +351,19 @@ class AttestationVerifier:
                     with self._stats_lock:
                         self.stats["rejected"] += 1
         if not prepared:
+            life.root.finish()
             return
-        # accumulate-wait of the OLDEST attestation in the batch is its
-        # queue_wait component for flight SLO attribution
+        # what the SLO tracker charges to the queue: the oldest item's
+        # arrival to here, i.e. collect_wait + pool_wait + prevalidation
         fl = self.flight.begin_batch(
             self.lane, "", len(prepared),
-            queue_wait_s=max(
-                0.0, time.time() - min(it.received_at for it in batch)
-            ),
+            queue_wait_s=time.perf_counter() - life.arrived,
             breaker_state=self.health.state if self.use_device else "",
             devices=self.mesh.device_count if self.mesh is not None else 1,
         )
+        fl.trace(life.root)
+        fl.record.collect_wait_s = life.popped - life.arrived
+        fl.record.pool_wait_s = max(0.0, t_start - life.popped)
         skipped = False
         if self.use_device and self._completion is not None:
             if not self.health.allow_device():
@@ -383,13 +417,7 @@ class AttestationVerifier:
         if ok:
             with self._stats_lock:
                 self.stats["accepted"] += len(prepared)
-            with self._stage("feedback", items=len(prepared)):
-                self.controller.on_valid_attestation_batch(
-                    [p[3] for p in prepared]
-                )
-                # AFTER delivery: a slasher problem must never cost fork
-                # choice its verified votes
-                self._feed_slasher([(p[4], p[3]) for p in prepared])
+            self._feedback(prepared)
             fl.finish(True)
             return
         # batch failed: BISECT to the bad items with batch checks —
@@ -428,12 +456,20 @@ class AttestationVerifier:
             self.stats["accepted"] += len(good_items)
             self.stats["rejected"] += bad_count
         if good_items:
-            with self._stage("feedback", items=len(good_items)):
-                self.controller.on_valid_attestation_batch(
-                    [p[3] for p in good_items]
-                )
-                self._feed_slasher([(p[4], p[3]) for p in good_items])
+            self._feedback(good_items)
         fl.finish(bad_count == 0)
+
+    def _feedback(self, accepted) -> None:
+        """The `feedback` stage in its two parts, one after the other:
+        the verdicts to fork choice, then the slasher feed — AFTER
+        delivery, so a slasher problem never costs fork choice its
+        verified votes."""
+        with self._stage("feedback", op="deliver", items=len(accepted)):
+            self.controller.on_valid_attestation_batch(
+                [p[3] for p in accepted]
+            )
+        with self._stage("feedback", op="slasher_feed", items=len(accepted)):
+            self._feed_slasher([(p[4], p[3]) for p in accepted])
 
     # ------------------------------------------------------------ pipeline
 
@@ -450,7 +486,8 @@ class AttestationVerifier:
             # decompress WITHOUT the per-signature host subgroup
             # scalar-mul; the device checks the whole batch in one ψ
             # ladder (see _batch_check for the rationale)
-            with self._stage("host_prep", op="g2_decompress"):
+            with self._stage("host_prep", op="g2_decompress",
+                             items=len(prepared)):
                 points = [
                     A.g2_from_bytes(bytes(p[1]), subgroup_check=False)
                     for p in prepared
@@ -545,7 +582,8 @@ class AttestationVerifier:
         if registry is None:
             return None
         try:
-            with self._stage("host_prep", op="registry_sync"):
+            with self._stage("host_prep", op="registry_sync",
+                             items=len(prepared)):
                 if registry.ensure(prepared[0][6]):
                     return registry
         except A.BlsError:
@@ -560,14 +598,25 @@ class AttestationVerifier:
         bounds device residency."""
         # the slot is released on the completion thread in _complete's
         # finally, so a `with` cannot express this handoff
+        t0 = time.perf_counter()
+        wait = self.tracer.span("dispatch_wait")
         self._dispatch_sem.acquire()  # lint: disable=thread-affinity
+        wait.finish()
+        t_put = time.perf_counter()
+        if fl is not None:
+            fl.record.dispatch_wait_s = t_put - t0
         with self._cond:
             self._inflight += 1
             depth = self._inflight
         if self.metrics is not None:
             self.metrics.verify_pipeline_depth.set(depth)
         self.flight.device_enter()
-        self._completion.put((settle, prepared, self.tracer.capture(), fl))
+        # the batch's root rides along (the pool thread's current span):
+        # the completion thread's stages hang under it too
+        self._completion.put((
+            settle, prepared, self.tracer.capture(), fl,
+            self.tracer.span("settle_wait"), t_put,
+        ))
 
     def _complete(self) -> None:
         """Completion thread: force settled batch verdicts in dispatch
@@ -577,7 +626,10 @@ class AttestationVerifier:
             item = self._completion.get()
             if item is None:
                 return
-            settle, prepared, span_ctx, fl = item
+            settle, prepared, span_ctx, fl, wait, t_put = item
+            wait.finish()
+            if fl is not None:
+                fl.record.settle_wait_s = time.perf_counter() - t_put
             try:
                 with self.tracer.attach(span_ctx):
                     self._settle_one(settle, prepared, fl)
@@ -606,11 +658,21 @@ class AttestationVerifier:
         fresh (breaker-gated device or host) re-check — honest votes are
         never dropped on a backend hiccup."""
         t0 = time.perf_counter()
-        outcome = self.health.guard_settle(
-            settle, thread_name="attestation-settle-watchdog"
-        )
+        with self._stage("settle", items=len(prepared)) as span:
+
+            def settle_traced():
+                # the watchdog runs the settle on a thread of its own:
+                # the backend's execute / readback stages hang under this
+                # stage all the same
+                with self.tracer.attach(span):
+                    return settle()
+
+            outcome = self.health.guard_settle(
+                settle_traced, thread_name="attestation-settle-watchdog"
+            )
         if fl is not None:
-            fl.note_device(time.perf_counter() - t0)
+            fl.record.settle_s = time.perf_counter() - t0
+            fl.note_device(fl.record.settle_s)
         if outcome.status == _health.OK:
             self.health.record_success()
             self._resolve_batch(prepared, bool(outcome.value), fl)
@@ -887,7 +949,8 @@ class AttestationVerifier:
             # the device checks the whole batch in one ψ ladder.
             # A failed batch falls to the singular path, which uses
             # the fully-checked from_bytes and isolates the item.
-            with self._stage("host_prep", op="g2_decompress"):
+            with self._stage("host_prep", op="g2_decompress",
+                             items=len(signatures)):
                 points = [
                     A.g2_from_bytes(bytes(s), subgroup_check=False)
                     for s in signatures

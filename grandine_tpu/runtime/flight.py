@@ -132,7 +132,8 @@ class BatchRecord:
         "queue_wait_s", "device_s", "host_s", "bisect_s", "verdict",
         "fault", "retries", "bisect_depth", "breaker_state", "recompile",
         "slo_miss", "slo_cause", "origin", "note", "devices",
-        "quarantined", "brownout",
+        "quarantined", "brownout", "trace_id", "collect_wait_s",
+        "pool_wait_s", "dispatch_wait_s", "settle_wait_s", "settle_s",
     )
 
     def __init__(self, kind: str, lane: str) -> None:
@@ -144,10 +145,37 @@ class BatchRecord:
         self.items = 0
         self.bucket = 0
         self.fill = 0.0
+        #: what the SLO tracker calls the queue: from the arrival of the
+        #: batch's oldest item to the moment the batch is ready to
+        #: dispatch. On the firehose that is collect_wait_s + pool_wait_s
+        #: + the prevalidation stage (host work, counted as waiting); on
+        #: the scheduler lanes the oldest ticket's enqueue -> flush
         self.queue_wait_s = 0.0
+        #: a HOST delta under a device's name: everything between "start
+        #: dispatching" and "verdict forced" that the emission sites
+        #: note. On the firehose: the whole of `_device_dispatch` (G2
+        #: decompression, registry sync, packing, upload, the dispatch
+        #: call) plus `settle_s`; NOT dispatch_wait_s or settle_wait_s.
+        #: The device's own time is in the profiler's trace alone
         self.device_s = 0.0
         self.host_s = 0.0
         self.bisect_s = 0.0
+        #: the batch's spans in the Tracer ring carry this id (0: no
+        #: tracer); a flight row finds its chain of spans by it
+        self.trace_id = 0
+        #: the firehose's waits, each also a span under the batch's root
+        #: (all `perf_counter` deltas; 0.0 where the path has no such
+        #: wait): oldest item's arrival -> the collector pops the batch
+        self.collect_wait_s = 0.0
+        #: batch popped -> its task starts on a pool thread
+        self.pool_wait_s = 0.0
+        #: the pool thread blocked on the pipeline's dispatch semaphore
+        self.dispatch_wait_s = 0.0
+        #: dispatched batch handed over -> the completion thread takes it
+        #: (it sits behind its predecessor's settle and feedback)
+        self.settle_wait_s = 0.0
+        #: forcing the verdict: device remainder + readback
+        self.settle_s = 0.0
         self.verdict: "Optional[bool]" = None
         self.fault: "Optional[str]" = None
         self.retries = 0
@@ -200,6 +228,12 @@ class BatchRecord:
             "devices": self.devices,
             "quarantined": self.quarantined,
             "brownout": self.brownout,
+            "trace_id": self.trace_id,
+            "collect_wait_s": round(self.collect_wait_s, 6),
+            "pool_wait_s": round(self.pool_wait_s, 6),
+            "dispatch_wait_s": round(self.dispatch_wait_s, 6),
+            "settle_wait_s": round(self.settle_wait_s, 6),
+            "settle_s": round(self.settle_s, 6),
         }
 
 
@@ -210,13 +244,23 @@ class BatchFlight:
     are called from the single thread that owns the batch at that stage,
     so no locking here."""
 
-    __slots__ = ("record", "_recorder", "_done", "_recompiles_before")
+    __slots__ = ("record", "root", "_recorder", "_done",
+                 "_recompiles_before")
 
     def __init__(self, recorder: "FlightRecorder", record: BatchRecord) -> None:
         self.record = record
+        #: the batch's root span (tracing.Span), when its owner traces
+        #: the batch's whole life: `finish` ends it where the record
+        #: commits, on whichever thread that is
+        self.root = None
         self._recorder = recorder
         self._done = False
         self._recompiles_before = _recompile_count()
+
+    def trace(self, root) -> None:
+        """Tie the record to its chain of spans."""
+        self.root = root
+        self.record.trace_id = root.trace_id
 
     def note_device(self, seconds: float) -> None:
         self.record.device_s += max(0.0, seconds)
@@ -259,6 +303,8 @@ class BatchFlight:
             rec.recompile = bool(after is not None
                                  and after > self._recompiles_before)
         self._recorder._commit(rec)
+        if self.root is not None:
+            self.root.finish()
 
 
 class OriginTable:

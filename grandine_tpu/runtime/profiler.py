@@ -17,10 +17,11 @@ Two independent planes share this module:
 * **Capture sessions** — `start()`/`stop()` open at most one session at
   a time; while a session is active every dispatch runs inside a
   `jax.profiler.TraceAnnotation("{scheme}/{kernel}/b{bucket}")` scope
-  (and bench loops may add `step()` = `StepTraceAnnotation` marks), so
-  the device timeline in the resulting perfetto/Chrome trace is keyed
-  by the same `(scheme, kernel, bucket)` coordinates the shape ledger
-  uses. Sessions with a `trace_dir` also drive `jax.profiler.
+  and every pipeline stage (tracing.stage) inside
+  `TraceAnnotation("{lane}/{op or stage}/b{bucket}")`, so the device
+  timeline in the resulting perfetto/Chrome trace is keyed by the same
+  `(scheme, kernel, bucket)` coordinates the shape ledger uses and the
+  host's stages lie beside it on the profiler's clock. Sessions with a `trace_dir` also drive `jax.profiler.
   start_trace`/`stop_trace`; finished sessions land in a bounded ring
   of the last K. `GET /eth/v1/debug/grandine/profile` serves the
   summary and the start/stop control (http_api/routing.py).
@@ -162,8 +163,10 @@ class KernelProfiler:
         self.trace_root = trace_root
         self.clock = clock
         self._lock = threading.Lock()
-        #: capture flag annotate()/step() read per dispatch (under the
-        #: same lock as the dispatch bump); only start/stop write it
+        #: capture flag annotate() reads per dispatch (under the same
+        #: lock as the dispatch bump) and tracing.stage reads per stage
+        #: through `capturing()` (no lock: one bool); only start/stop
+        #: write it
         self._capturing = False
         self._active: "Optional[dict]" = None
         self._ring: "list[dict]" = []  # finished sessions, newest last
@@ -218,23 +221,6 @@ class KernelProfiler:
         label = f"{self.scheme_of(kernel)}/{kernel}/b{_bucket(items)}"
         try:
             return jax.profiler.TraceAnnotation(label)
-        except Exception:
-            return contextlib.nullcontext()
-
-    def step(self, step_num: int):
-        """Batch-iteration mark for bench/soak loops: a StepTrace
-        Annotation while capturing, a no-op otherwise."""
-        with self._lock:
-            capturing = self._capturing
-        if not capturing:
-            return contextlib.nullcontext()
-        jax = sys.modules.get("jax")
-        if jax is None:
-            return contextlib.nullcontext()
-        try:
-            return jax.profiler.StepTraceAnnotation(
-                "verify_batch", step_num=int(step_num)
-            )
         except Exception:
             return contextlib.nullcontext()
 
@@ -457,6 +443,28 @@ def set_profiler(profiler: KernelProfiler) -> KernelProfiler:
     return profiler
 
 
+def stage_annotation(lane: str, what: str, items: int = 0):
+    """`<lane>/<op or stage>/b<bucket>` in the profiler's trace: the
+    kernel annotation's coordinates, so the host stages and the device
+    kernels of one batch line up on one clock (tracing.stage opens it
+    while `capturing()`). None when JAX is not loaded."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        return jax.profiler.TraceAnnotation(f"{lane}/{what}/b{_bucket(items)}")
+    except Exception:
+        return None
+
+
+def capturing() -> bool:
+    """Whether the process-wide profiler has a capture session on. The
+    stage helper (tracing.stage) asks before every stage, so: no lock, no
+    lazy construction — a torn read mislabels one stage's edge."""
+    prof = _DEFAULT
+    return prof is not None and prof._capturing
+
+
 # ------------------------------- shared helpers for the tools/ shims
 
 
@@ -540,6 +548,8 @@ __all__ = [
     "DEFAULT_SESSION_RING",
     "get_profiler",
     "set_profiler",
+    "capturing",
+    "stage_annotation",
     "time_jit",
     "capture_trace",
     "summarize_trace",

@@ -50,7 +50,7 @@ from grandine_tpu.consensus.verifier import (
 from grandine_tpu.crypto import bls as A
 from grandine_tpu.runtime import flight as _flight
 from grandine_tpu.runtime.verify_scheduler import VerifyItem, host_check_item
-from grandine_tpu.tracing import NULL_TRACER
+from grandine_tpu.tracing import NULL_TRACER, stage as _stage
 
 logger = logging.getLogger("grandine.replay")
 
@@ -227,7 +227,8 @@ class BulkReplayPipeline:
             self.metrics.replay_pipeline_depth.set(depth)
 
     def _stage(self, stage: str, **attrs):
-        return _StageTimer(self, stage, attrs)
+        """The shared stage helper (tracing.stage) on lane "replay"."""
+        return _stage(self.tracer, self.metrics, stage, "replay", **attrs)
 
     # --------------------------------------------------- transition+collect
 
@@ -501,32 +502,6 @@ class BulkReplayPipeline:
                         hit.validator_index, slot,
                         rec and (rec[0], rec[1].hex()[:16]),
                     )
-
-
-class _StageTimer:
-    """Span + verify_stage_seconds{stage,lane="replay"} per stage, the
-    attestation pipeline's observability contract."""
-
-    __slots__ = ("pipe", "stage", "attrs", "t0", "_span")
-
-    def __init__(self, pipe: BulkReplayPipeline, stage: str, attrs) -> None:
-        self.pipe = pipe
-        self.stage = stage
-        self.attrs = attrs
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        self._span = self.pipe.tracer.span(self.stage, self.attrs or None)
-        self._span.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        self._span.__exit__(*exc)
-        if self.pipe.metrics is not None:
-            self.pipe.metrics.verify_stage_seconds.labels(
-                self.stage, "replay"
-            ).observe(time.perf_counter() - self.t0)
-        return False
 
 
 __all__ = [

@@ -49,7 +49,6 @@ import queue
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Callable, Optional, Sequence
 
 from grandine_tpu.consensus.verifier import (
@@ -63,7 +62,7 @@ from grandine_tpu.runtime import health as _health
 from grandine_tpu.runtime import isolation as _isolation
 from grandine_tpu.runtime.thread_pool import Priority
 from grandine_tpu.tpu import schemes as _schemes
-from grandine_tpu.tracing import NULL_TRACER
+from grandine_tpu.tracing import NULL_TRACER, stage as _stage
 
 
 class LaneConfig:
@@ -660,16 +659,9 @@ class VerifyScheduler:
 
     # ------------------------------------------------------------- flush
 
-    @contextmanager
     def _stage(self, lane: LaneConfig, stage: str, **attrs):
-        """PR-1 stage-span vocabulary, lane-attributed."""
-        t0 = time.perf_counter()
-        with self.tracer.span(stage, attrs or None):
-            yield
-        if self.metrics is not None:
-            self.metrics.verify_stage_seconds.labels(
-                stage, lane.name
-            ).observe(time.perf_counter() - t0)
+        """The shared stage helper (tracing.stage), lane-attributed."""
+        return _stage(self.tracer, self.metrics, stage, lane.name, **attrs)
 
     def _set_depth(self, lane_name: str) -> None:
         if self.metrics is not None:

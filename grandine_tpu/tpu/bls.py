@@ -42,9 +42,8 @@ import os
 import secrets
 import sys
 import threading
-import time
 from collections import OrderedDict
-from contextlib import contextmanager, nullcontext
+from contextlib import nullcontext
 from typing import Sequence
 
 import jax
@@ -55,12 +54,18 @@ from grandine_tpu.crypto import constants
 from grandine_tpu.crypto import bls as A
 from grandine_tpu.crypto.curves import G1, LAMBDA, decompose_glv, endo_constants
 from grandine_tpu.crypto.hash_to_curve import hash_to_g2
+from grandine_tpu import tracing as _tracing
+from grandine_tpu.tpu import compile_scope as _compile_scope
 from grandine_tpu.tpu import curve as C
 from grandine_tpu.tpu import field as F
 from grandine_tpu.tpu import limbs as L
 from grandine_tpu.tpu import msm as M
 from grandine_tpu.tpu import pairing as TP
 from grandine_tpu.tpu.compile_scope import compiling
+
+# JAX's own account of each compile (trace / lower / backend, cache hit or
+# miss) goes to the compile scope's phase counters from here on
+_compile_scope.listen(jax.monitoring)
 
 shard_map = jax.shard_map
 
@@ -1729,23 +1734,17 @@ class TpuBlsBackend:
     def _observed(self) -> bool:
         return self.metrics is not None or self.tracer is not None
 
-    @contextmanager
     def _stage(self, stage: str, **attrs):
-        """One device-plane stage: span (when tracing) + one
-        `verify_stage_seconds{stage=...}` observation (when metered)."""
+        """One device-plane stage (tracing.stage): span (when tracing) +
+        one `verify_stage_seconds{stage,lane,op}` observation (when
+        metered) + a host span in the profiler's trace during a capture
+        session."""
         if not self._observed():
-            yield
-            return
-        t0 = time.perf_counter()
-        if self.tracer is not None:
-            with self.tracer.span(stage, attrs or None):
-                yield
-        else:
-            yield
-        if self.metrics is not None:
-            self.metrics.verify_stage_seconds.labels(
-                stage, self.lane
-            ).observe(time.perf_counter() - t0)
+            return nullcontext()
+        return _tracing.stage(
+            self.tracer or _tracing.NULL_TRACER, self.metrics, stage,
+            self.lane, **attrs
+        )
 
     def _count_kernel(self, kernel: str, sigs: int) -> None:
         if self.metrics is not None:

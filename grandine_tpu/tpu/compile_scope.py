@@ -16,7 +16,17 @@ entry of the warm loop in runtime/warmup.py) behaves the same:
     to the deadline, so a first call that lands inside a watchdog-bounded
     settle cannot open the breaker.
 
-Imports nothing heavy: runtime/health.py pulls this in on host-only nodes.
+  - what a compile costs is three different things (Python tracing,
+    lowering to MLIR, the XLA compile or the persistent cache's load), and
+    only the last one the cache saves. JAX reports each itself
+    (`jax.monitoring`); `listen()` subscribes once per process and
+    `phase_totals()` holds what arrived while the reporting thread was
+    inside `compiling()`. `Metrics.expose()` publishes them as
+    `verify_compile_phase_seconds_total{phase}` and
+    `verify_compile_cache_total{result}`.
+
+Imports nothing heavy: runtime/health.py pulls this in on host-only nodes
+(`listen()` is handed `jax.monitoring` by tpu/bls.py, which has JAX).
 """
 
 from __future__ import annotations
@@ -27,9 +37,30 @@ import time
 from contextlib import contextmanager
 
 _LOCK = threading.Lock()
-#: thread ident -> [finished compile seconds, start of the open scope or None]
+#: thread ident -> [finished compile seconds, start of the open scope or
+#: None, {phase: [(start, seconds), ...] reported inside the open scope}]
 _CLOCK: "dict[int, list]" = {}
 _TOTAL = [0.0, 0]  # process-wide compile seconds, compile count
+
+#: the CLOSED phase set of verify_compile_phase_seconds_total, by the JAX
+#: 0.9 event that feeds each (checked against the installed JAX's
+#: jax/_src/dispatch.py and compiler.py). `backend` is the XLA compile OR
+#: the cache load, whichever happened; `cache_retrieval` is the cache read
+#: alone and lies inside `backend`.
+PHASE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+PHASES = tuple(PHASE_EVENTS.values())
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_PHASE_S = {phase: 0.0 for phase in PHASES}
+_CACHE_N = {"hit": 0, "miss": 0}
+_LISTENING = [False]
 
 try:
     _malloc_trim = ctypes.CDLL(None).malloc_trim
@@ -50,7 +81,7 @@ def compiling():
     only the outermost one runs the clock and the trim."""
     ident = threading.get_ident()
     with _LOCK:
-        row = _CLOCK.setdefault(ident, [0.0, None])
+        row = _CLOCK.setdefault(ident, [0.0, None, {}])
         outer = row[1] is None
         if outer:
             row[1] = time.monotonic()
@@ -62,6 +93,7 @@ def compiling():
                 dt = time.monotonic() - row[1]
                 row[0] += dt
                 row[1] = None
+                row[2].clear()
                 _TOTAL[0] += dt
                 _TOTAL[1] += 1
             trim_host_memory()
@@ -85,4 +117,56 @@ def totals() -> "tuple[float, int]":
         return _TOTAL[0], _TOTAL[1]
 
 
-__all__ = ["compiling", "compile_seconds", "totals", "trim_host_memory"]
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    """A `jax.monitoring` duration, on the thread that did the work. Kept
+    only inside `compiling()`; an event JAX does not have in PHASE_EVENTS
+    is dropped. JAX times a jitted function called while another is being
+    traced on its own AND inside the outer one's duration (every `jnp`
+    operation is such a function), so a duration that contains earlier
+    ones of its phase replaces them: a phase reads the union."""
+    phase = PHASE_EVENTS.get(event)
+    if phase is None:
+        return
+    end = time.monotonic()
+    with _LOCK:
+        row = _CLOCK.get(threading.get_ident())
+        if row is None or row[1] is None:
+            return
+        start = end - seconds
+        inner = row[2].setdefault(phase, [])
+        while inner and inner[-1][0] >= start:
+            seconds -= inner.pop()[1]
+        inner.append((start, end - start))
+        _PHASE_S[phase] += seconds
+
+
+def _on_event(event: str, **_kw) -> None:
+    result = CACHE_EVENTS.get(event)
+    if result is None:
+        return
+    with _LOCK:
+        row = _CLOCK.get(threading.get_ident())
+        if row is not None and row[1] is not None:
+            _CACHE_N[result] += 1
+
+
+def listen(monitoring) -> None:
+    """Subscribe to `jax.monitoring` (the module is handed in: nothing
+    here imports JAX). Once per process; later calls do nothing."""
+    with _LOCK:
+        if _LISTENING[0]:
+            return
+        _LISTENING[0] = True
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+
+
+def phase_totals() -> "tuple[dict, dict]":
+    """({phase: seconds}, {"hit"|"miss": lookups}) reported from inside
+    compile scopes, process-wide."""
+    with _LOCK:
+        return dict(_PHASE_S), dict(_CACHE_N)
+
+
+__all__ = ["compiling", "compile_seconds", "totals", "trim_host_memory",
+           "listen", "phase_totals", "PHASES"]

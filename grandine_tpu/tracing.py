@@ -14,19 +14,25 @@ runtime's thread pool (see runtime/thread_pool.py, which captures at
 
 from __future__ import annotations
 
+import atexit
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "NULL_TRACER"]
+__all__ = ["Span", "Tracer", "NULL_TRACER", "STAGE_OPS", "stage"]
 
 #: process-wide epoch for trace timestamps: Chrome trace-event `ts` is in
 #: microseconds from an arbitrary origin; anchoring every tracer at import
 #: keeps spans from different tracers on one comparable timeline.
 _EPOCH = time.perf_counter()
+#: the wall clock at that instant: `chrome_trace()` hands it out so a span
+#: dump can be laid beside a profiler trace (whose events are on the
+#: profiler's clock, Unix-epoch nanoseconds on a TPU host)
+_EPOCH_TIME_NS = time.time_ns()
 
 
 class Span:
@@ -55,12 +61,15 @@ class Span:
         span_id: int,
         parent_id: Optional[int],
         attrs: Optional[Dict[str, Any]] = None,
+        start: Optional[float] = None,
     ) -> None:
         self.name = name
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
-        self.start = time.perf_counter()
+        #: `start` (a `perf_counter` reading) back-dates a span whose
+        #: beginning was stamped before anyone could open it: a wait
+        self.start = time.perf_counter() if start is None else start
         self.end: Optional[float] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         t = threading.current_thread()
@@ -165,7 +174,10 @@ class Tracer:
         self._finished: deque = deque(maxlen=self.capacity)
         self._next_id = 1
         self._local = threading.local()
-        self._jsonl_path: Optional[str] = None
+        #: the `--trace-out` sink: ONE handle kept open, written under a
+        #: lock, flushed when a root span ends (a whole trace is then on
+        #: disk), on `clear()`, on `flush()` and at interpreter exit
+        self._jsonl = None
         self._jsonl_lock = threading.Lock()
 
     # ----------------------------------------------------------- span API
@@ -184,9 +196,11 @@ class Tracer:
         name: str,
         attrs: Optional[Dict[str, Any]] = None,
         parent: Optional[Span] = None,
+        start: Optional[float] = None,
     ):
-        """New span parented on `parent` (or the thread's current span).
-        Returns a no-op span when the tracer is disabled."""
+        """New span parented on `parent` (or the thread's current span),
+        begun now or at the `perf_counter` reading `start`. Returns a
+        no-op span when the tracer is disabled."""
         if not self.enabled:
             return _NULL_SPAN
         if parent is None:
@@ -195,8 +209,9 @@ class Tracer:
             parent = None  # a _NullSpan or foreign token: no parent
         sid = self._alloc_id()
         if parent is not None:
-            return Span(self, name, parent.trace_id, sid, parent.span_id, attrs)
-        return Span(self, name, sid, sid, None, attrs)
+            return Span(self, name, parent.trace_id, sid, parent.span_id,
+                        attrs, start)
+        return Span(self, name, sid, sid, None, attrs, start)
 
     def _push(self, span: Span):
         prev = getattr(self._local, "span", None)
@@ -210,15 +225,18 @@ class Tracer:
     def _on_finish(self, span: Span) -> None:
         with self._lock:
             self._finished.append(span)
-        path = self._jsonl_path
-        if path is not None:
+        if self._jsonl is not None:
             line = json.dumps(span.to_chrome_event(), separators=(",", ":"))
             with self._jsonl_lock:
+                fh = self._jsonl
+                if fh is None:
+                    return
                 try:
-                    with open(path, "a") as fh:
-                        fh.write(line + "\n")
-                except OSError:
-                    self._jsonl_path = None  # dead sink: stop trying
+                    fh.write(line + "\n")
+                    if span.parent_id is None:
+                        fh.flush()
+                except (OSError, ValueError):
+                    self._jsonl = None  # dead sink: stop trying
 
     # --------------------------------------------------- cross-thread hops
 
@@ -240,6 +258,16 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._finished.clear()
+        self.flush()
+
+    def flush(self) -> None:
+        """Push what the JSONL sink has buffered to its file."""
+        with self._jsonl_lock:
+            if self._jsonl is not None:
+                try:
+                    self._jsonl.flush()
+                except (OSError, ValueError):
+                    self._jsonl = None
 
     def chrome_trace(self) -> Dict[str, Any]:
         """The whole ring buffer as a Chrome trace-event JSON object."""
@@ -249,6 +277,8 @@ class Tracer:
             "displayTimeUnit": "ms",
             "otherData": {
                 "clock": "perf_counter",
+                # `ts` 0 of this dump on the wall clock
+                "epoch_time_ns": _EPOCH_TIME_NS,
                 "span_count": len(spans),
                 "capacity": self.capacity,
             },
@@ -257,11 +287,15 @@ class Tracer:
     def set_jsonl_path(self, path: Optional[str]) -> None:
         """Mirror every finished span to `path` as one JSON line each
         (Chrome trace-event objects; `jq -s '{traceEvents:.}'` rebuilds a
-        loadable trace). Truncates any existing file."""
-        if path is not None:
-            with open(path, "w"):
-                pass
-        self._jsonl_path = path
+        loadable trace). Truncates any existing file; `None` closes the
+        sink."""
+        fh = open(path, "w") if path is not None else None
+        with self._jsonl_lock:
+            old, self._jsonl = self._jsonl, fh
+        if old is not None:
+            old.close()
+        elif fh is not None:
+            atexit.register(self.flush)
 
 
 class _Attach:
@@ -284,3 +318,96 @@ class _Attach:
 #: shared disabled tracer: modules can default to this and never check
 #: for None before opening spans.
 NULL_TRACER = Tracer(capacity=1, enabled=False)
+
+
+# ------------------------------------------------------- pipeline stages
+#
+# One helper for every verify plane (the firehose, the scheduler lanes,
+# bulk replay, the device backend): a stage is a child span under the
+# thread's current span, one `verify_stage_seconds{stage,lane,op}`
+# observation and, while a profiler capture session is on, a host span in
+# the profiler's own trace.
+
+#: the CLOSED set of `op` values on verify_stage_seconds: what a stage
+#: that several call sites feed is split by. "" is a stage with one part.
+#: The metrics-cardinality lint rule reads this tuple and refuses an
+#: `op="..."` literal outside it; at run time an unknown op reads "other".
+STAGE_OPS = (
+    "",
+    # host_prep, firehose (runtime/attestation_verifier.py)
+    "prevalidate", "g2_decompress", "registry_sync",
+    # host_prep, device backend (tpu/bls.py)
+    "pack", "pack_aggregate", "pack_aggregate_idx",
+    "pack_aggregate_compressed", "pack_aggregate_idx_compressed",
+    "pack_compressed", "pack_grouped", "pack_idx", "pack_partition",
+    "pack_sign", "pack_subgroup", "point_convert", "msm_plan",
+    "sharded_msm_plan",
+    # host_prep, scheduler lanes (tpu/schemes.py)
+    "sig_bytes", "resolve_keys", "ed25519_decode", "kzg_prep",
+    # feedback (the firehose's settle side)
+    "deliver", "slasher_feed",
+    "other",
+)
+_STAGE_OPS = frozenset(STAGE_OPS)
+
+
+def _profiler_span(lane: str, what: str, attrs, tracer: Tracer):
+    """The stage's host span in the profiler's own trace while the node's
+    KernelProfiler has a capture session on (the flag its `annotate()`
+    reads), else None: one module lookup and one flag read.
+    runtime/profiler.py is never imported from here. The bucket is the
+    stage's own `items`, else the enclosing span's (`items`, or the
+    root's `batch`)."""
+    mod = sys.modules.get("grandine_tpu.runtime.profiler")
+    if mod is None or not mod.capturing():
+        return None
+    items = (attrs or {}).get("items")
+    if items is None:
+        outer = tracer.current()
+        if outer is not None:
+            items = outer.attrs.get("items", outer.attrs.get("batch"))
+    return mod.stage_annotation(lane, what, int(items or 0))
+
+
+class stage:
+    """`with stage(tracer, metrics, "host_prep", lane, op="pack", items=n)`
+    — yields the stage's span. `metrics` may be None (span only) and
+    `tracer` NULL_TRACER (observation only). A stage must not be opened
+    inside a span of its own stage name: the sum over `op` of a stage is
+    what the stage took."""
+
+    __slots__ = ("_tracer", "_metrics", "_stage", "_lane", "_op", "_attrs",
+                 "_t0", "_span", "_mark")
+
+    def __init__(self, tracer: Tracer, metrics, stage: str, lane: str,
+                 op: str = "", **attrs) -> None:
+        if op not in _STAGE_OPS:
+            op = "other"
+        if op:
+            attrs["op"] = op
+        self._tracer = tracer
+        self._metrics = metrics
+        self._stage = stage
+        self._lane = lane
+        self._op = op
+        self._attrs = attrs or None
+
+    def __enter__(self):
+        mark = self._mark = _profiler_span(
+            self._lane, self._op or self._stage, self._attrs, self._tracer
+        )
+        if mark is not None:
+            mark.__enter__()
+        self._t0 = time.perf_counter()
+        self._span = self._tracer.span(self._stage, self._attrs)
+        return self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        dt = time.perf_counter() - self._t0
+        if self._mark is not None:
+            self._mark.__exit__(*exc)
+        if self._metrics is not None:
+            self._metrics.verify_stage_seconds.labels(
+                self._stage, self._lane, self._op
+            ).observe(dt)

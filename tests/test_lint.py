@@ -233,6 +233,42 @@ class U:
 """, "metrics-cardinality") == 0
 
 
+_STAGE_OP_FIXTURE = """
+from grandine_tpu.metrics import LabeledHistogram
+
+STAGE_OPS = ("", "prevalidate", "deliver", "other")
+
+class M:
+    def __init__(self):
+        self.verify_stage_seconds = LabeledHistogram(
+            "verify_stage_seconds", "h", ("stage", "lane", "op"),
+            defaults={"lane": "attestation", "op": ""},
+        )
+
+class U:
+    def use(self, m, tracer, op):
+        m.verify_stage_seconds.labels("host_prep", "attestation", <lit>)
+        with self._stage("host_prep", op=<lit>, items=4):
+            pass
+        with stage(tracer, m, "feedback", "attestation", op=op):
+            pass
+"""
+
+
+@pytest.mark.parametrize("literal,code", [
+    ('"prevalidate"', 0), ('"deliver"', 0), ('""', 0),
+    ('"coffee_break"', 1),
+])
+def test_metrics_cardinality_holds_stage_op_to_its_enum(tmp_path, literal,
+                                                        code):
+    """The `op` label of verify_stage_seconds is a closed enum
+    (tracing.STAGE_OPS): a literal outside it is flagged at a `.labels()`
+    call AND where it enters a stage helper (`_stage(..., op="...")`); a
+    variable stays quiet."""
+    src = _STAGE_OP_FIXTURE.replace("<lit>", literal)
+    assert lint(tmp_path, src, "metrics-cardinality") == code
+
+
 def test_jit_purity_flags_clock_global_and_config_update(tmp_path):
     assert lint(tmp_path, """
 import time

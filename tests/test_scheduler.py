@@ -724,19 +724,25 @@ def test_blob_sidecar_header_rides_scheduler(genesis):
 
 
 def test_verify_stage_seconds_lane_label_defaults():
-    """Widening verify_stage_seconds to (stage, lane) must not break the
-    pre-existing single-label call sites: they resolve to the
-    attestation series."""
+    """Widening verify_stage_seconds to (stage, lane, op) must not break
+    the pre-existing single- and two-label call sites: they resolve to
+    the attestation series with no `op`."""
     m = Metrics()
     m.verify_stage_seconds.labels("execute").observe(0.001)
     m.verify_stage_seconds.observe("execute", value=0.002)
     m.verify_stage_seconds.labels("execute", "sync_message").observe(0.003)
+    m.verify_stage_seconds.labels("host_prep", "attestation",
+                                  "prevalidate").observe(0.004)
     children = m.verify_stage_seconds.children()
-    assert ("execute", "attestation") in children
-    assert ("execute", "sync_message") in children
-    assert all(len(k) == 2 for k in children)
+    assert ("execute", "attestation", "") in children
+    assert ("execute", "sync_message", "") in children
+    assert ("host_prep", "attestation", "prevalidate") in children
+    assert all(len(k) == 3 for k in children)
     assert m.verify_stage_seconds.labels(stage="fallback") is (
         m.verify_stage_seconds.labels("fallback", "attestation")
+    )
+    assert m.verify_stage_seconds.labels("fallback", "attestation", "") is (
+        m.verify_stage_seconds.labels("fallback")
     )
 
 

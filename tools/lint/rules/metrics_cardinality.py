@@ -24,7 +24,10 @@ Two further checks ride on the same parse:
 - families listed in _ENUM_LABELS must pass the named label from a
   CLOSED enum: literal values at call sites are checked against the
   tuple constant (e.g. flight.SLO_CAUSES) parsed from source, so a
-  typo'd or ad-hoc `cause` can never mint a new series.
+  typo'd or ad-hoc `cause` can never mint a new series. The `op` label
+  of verify_stage_seconds reaches its one `.labels()` call through the
+  stage helpers, so `op="..."` literals of `stage(...)` / `_stage(...)`
+  calls are held to tracing.STAGE_OPS too.
 """
 
 from __future__ import annotations
@@ -72,7 +75,16 @@ _ENUM_LABELS = {
     "verify_brownout_transitions": (
         ("from", "to"), "grandine_tpu/runtime/brownout.py", "LEVELS"
     ),
+    "verify_stage_seconds": (
+        "op", "grandine_tpu/tracing.py", "STAGE_OPS"
+    ),
 }
+#: the stage helpers (tracing.stage and the `_stage` methods that bind a
+#: tracer, metrics and a lane to it) take the `op` label as a keyword and
+#: hand it to verify_stage_seconds: a literal there is held to the same
+#: closed enum as one at a `.labels()` call
+_STAGE_HELPERS = {"stage", "_stage"}
+_STAGE_OP_ENUM = "verify_stage_seconds"
 
 
 def _enum_label_tuple(labels) -> "tuple[str, ...]":
@@ -301,7 +313,33 @@ class MetricsCardinalityRule(Rule):
                     )
         return out
 
+    def _check_stage_op(self, path, call: ast.Call, enums):
+        """`op="..."` literals of stage-helper calls, against STAGE_OPS."""
+        fn = call.func
+        name = fn.attr if isinstance(fn, ast.Attribute) else (
+            fn.id if isinstance(fn, ast.Name) else None
+        )
+        enum = enums.get(_STAGE_OP_ENUM)
+        if name not in _STAGE_HELPERS or enum is None:
+            return
+        for kw in call.keywords:
+            if (
+                kw.arg == "op"
+                and isinstance(kw.value, ast.Constant)
+                and isinstance(kw.value.value, str)
+                and kw.value.value not in enum[1]
+            ):
+                yield Finding(
+                    self.name, path, kw.value.lineno,
+                    f"stage helper called with op={kw.value.value!r} — "
+                    f"not a member of the closed enum STAGE_OPS "
+                    f"(grandine_tpu/tracing.py); it would read \"other\"",
+                    key=(f"{self.name}:{path}:{_STAGE_OP_ENUM}:enum:op:"
+                         f"{kw.value.value}"),
+                )
+
     def _check_call(self, path, call: ast.Call, families, enums):
+        yield from self._check_stage_op(path, call, enums)
         fn = call.func
         if not (isinstance(fn, ast.Attribute) and fn.attr in _OPS):
             return
