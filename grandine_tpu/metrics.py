@@ -781,6 +781,19 @@ class Metrics:
             "slasher_span_indices_total",
             "attesting indices folded into the slasher span store",
         )
+        # one storage transaction per slasher call: commits count the
+        # `put` / `put_batch` calls the slasher issues, reads say how
+        # many record lookups the call's own write set served. The
+        # source label is a closed set.
+        self.slasher_storage_commits = Counter(
+            "slasher_storage_commits_total",
+            "storage transactions (put / put_batch) the slasher issued",
+        )
+        self.slasher_record_reads = LabeledCounter(
+            "slasher_record_reads_total",
+            "slasher record lookups, by source (write_set/db)",
+            ("source",),
+        )
         # pubkey registry memory accounting (tpu/registry.py): the
         # mainnet-capacity audit's observables — allocated vs occupied
         # rows, host-mirror footprint, and device bytes total/per shard

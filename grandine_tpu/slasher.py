@@ -35,15 +35,21 @@ oracle for the differential tests and the bench's batched-vs-loop
 diagnostic.
 
 Storage: (VALIDATORS_PER_CHUNK × CHUNK_EPOCHS) uint64 arrays in the K-V
-store (the reference's mdbx chunk tables), an in-memory LRU chunk cache
-flushed per call, per-(validator, target) attestation records for
-evidence retrieval, and epoch-ordered index rows (`sl:e:`, `sl:t:`) so
-`prune()` walks only the doomed prefix instead of scanning every key
-per finalization.
+store (the reference's mdbx chunk tables) behind an in-memory LRU chunk
+cache, per-(validator, target) attestation records for evidence
+retrieval, and epoch-ordered index rows (`sl:e:`, `sl:t:`) so `prune()`
+walks only the doomed prefix instead of scanning every key per
+finalization. Both kinds are flushed per call: a mutating call keeps the
+records it produces in a write set (read before the database, so a later
+occurrence of a validator sees the earlier one's record) and the chunks
+it changes dirty in the cache, and writes all of them in ONE `put_batch`
+— one storage transaction — before it returns. Nothing is deferred past
+the call; a call that raises is rolled back (`_one_transaction`).
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
 from collections import Counter as _Counter
 from collections import OrderedDict
@@ -104,6 +110,18 @@ class Slasher:
         #: call and pinned against eviction until then
         self._chunks: "OrderedDict[tuple, np.ndarray]" = OrderedDict()
         self._dirty: "set[tuple]" = set()
+        #: (validator, target) -> (source, data_root): the records of the
+        #: mutating call under way (last write wins, as `INSERT OR
+        #: REPLACE` has it), read before the database and written with
+        #: the dirty chunks by `flush`; empty between calls
+        self._write_set: "dict[tuple[int, int], tuple[int, bytes]]" = {}
+        # the two children of the read counter, looked up once: `_record`
+        # runs several times per attesting index
+        self._reads_write_set = self._reads_db = None
+        if metrics is not None:
+            self._reads_write_set = metrics.slasher_record_reads.labels(
+                "write_set")
+            self._reads_db = metrics.slasher_record_reads.labels("db")
 
     # ------------------------------------------------------------- chunks
 
@@ -154,9 +172,14 @@ class Slasher:
         return arr
 
     def flush(self) -> None:
-        if not self._dirty:
+        """Write the call's records (record + `sl:t:` row per key, each
+        key once) and dirty chunks (with their `sl:e:` rows) in ONE
+        `put_batch`, one storage transaction, and empty both sets."""
+        if not (self._write_set or self._dirty):
             return
         batch = []
+        for (index, target), (source, root) in self._write_set.items():
+            batch.extend(self._rec_rows(index, source, target, root))
         for kind, vchunk, echunk in self._dirty:
             batch.append((
                 self._chunk_key(kind, vchunk, echunk),
@@ -169,8 +192,33 @@ class Slasher:
                 + vchunk.to_bytes(8, "big"),
                 b"",
             ))
-        self.db.put_batch(batch)
+        self._commit(batch)
+        self._write_set.clear()
         self._dirty.clear()
+
+    def _commit(self, rows) -> None:
+        self.db.put_batch(rows)
+        if self.metrics is not None:
+            self.metrics.slasher_storage_commits.inc()
+
+    @contextlib.contextmanager
+    def _one_transaction(self):
+        """The body of a mutating call: `flush` at its end, so the call's
+        durability point is its return. A call that raises (in the body
+        or in the flush) is rolled back: its write set is dropped and the
+        chunks it dirtied leave the cache, to be read again from the
+        database — nothing of it is written, by this call or a later
+        one, and the caller sees the exception."""
+        try:
+            yield
+            self.flush()
+        except BaseException:
+            self._write_set.clear()
+            for key in self._dirty:
+                self._chunks.pop(key, None)
+            self._dirty.clear()
+            self._sync_cache_gauge()
+            raise
 
     # ------------------------------------------------------------ records
 
@@ -192,10 +240,17 @@ class Slasher:
 
     def _put_record(self, index: int, source: int, target: int,
                     data_root: bytes) -> None:
-        self.db.put_batch(self._rec_rows(index, source, target, data_root))
+        self._write_set[(index, target)] = (source, data_root)
 
     def _record(self, index: int, target: int):
+        rec = self._write_set.get((index, target))
+        if rec is not None:
+            if self._reads_write_set is not None:
+                self._reads_write_set.inc()
+            return rec
         raw = self.db.get(self._rec_key(index, target))
+        if self._reads_db is not None:
+            self._reads_db.inc()
         if raw is None:
             return None
         raw = bytes(raw)
@@ -226,11 +281,11 @@ class Slasher:
         data_root = bytes(data_root)
         ids = [int(i) for i in attesting_indices]
         t0 = time.perf_counter()
-        if len(set(ids)) != len(ids):
-            out = self._on_attestation_seq(ids, s, t, data_root)
-        else:
-            out = self._on_attestation_batched(ids, s, t, data_root)
-        self.flush()
+        with self._one_transaction():
+            if len(set(ids)) != len(ids):
+                out = self._on_attestation_seq(ids, s, t, data_root)
+            else:
+                out = self._on_attestation_batched(ids, s, t, data_root)
         self._observe_span_update(t0, len(ids))
         self.detected.extend(out)
         return out
@@ -245,8 +300,8 @@ class Slasher:
         s, t = int(source_epoch), int(target_epoch)
         data_root = bytes(data_root)
         ids = [int(i) for i in attesting_indices]
-        out = self._on_attestation_seq(ids, s, t, data_root)
-        self.flush()
+        with self._one_transaction():
+            out = self._on_attestation_seq(ids, s, t, data_root)
         self.detected.extend(out)
         return out
 
@@ -265,11 +320,8 @@ class Slasher:
                                 data_root: bytes) -> "list[Slashing]":
         checks = self._check_rows(ids, s, t, data_root)
         out = [hit for hit in checks if hit is not None]
-        rows = []
         for i in ids:
-            rows.extend(self._rec_rows(i, s, t, data_root))
-        if rows:
-            self.db.put_batch(rows)
+            self._put_record(i, s, t, data_root)
         ids_arr = np.asarray(ids, dtype=np.int64)
         vchunks = ids_arr // VALIDATORS_PER_CHUNK
         for vc in np.unique(vchunks):
@@ -482,32 +534,31 @@ class Slasher:
 
         hits: "dict[tuple[int, int], Slashing]" = {}
         solo_updates: "list[tuple[int, int, int]]" = []
-        record_rows: "list[tuple[bytes, bytes]]" = []
         n_indices = 0
-        for a, (ids, s, t, root) in enumerate(norm):
-            n_indices += len(ids)
-            solo_pos = [p for p, i in enumerate(ids) if i not in collision]
-            for p, i in enumerate(ids):
-                if i in collision:
-                    hit = self._check_one(i, s, t, root)
-                    if hit is not None:
-                        hits[(a, p)] = hit
-                    self._put_record(i, s, t, root)
-                    self._update_spans(i, s, t)
-            if solo_pos:
-                solo_ids = [ids[p] for p in solo_pos]
-                for p, hit in zip(solo_pos,
-                                  self._check_rows(solo_ids, s, t, root)):
-                    if hit is not None:
-                        hits[(a, p)] = hit
-                for i in solo_ids:
-                    record_rows.extend(self._rec_rows(i, s, t, root))
-                    solo_updates.append((i, s, t))
-        if solo_updates:
-            self._merge_span_updates(solo_updates)
-        if record_rows:
-            self.db.put_batch(record_rows)
-        self.flush()
+        with self._one_transaction():
+            for a, (ids, s, t, root) in enumerate(norm):
+                n_indices += len(ids)
+                solo_pos = [p for p, i in enumerate(ids)
+                            if i not in collision]
+                for p, i in enumerate(ids):
+                    if i in collision:
+                        hit = self._check_one(i, s, t, root)
+                        if hit is not None:
+                            hits[(a, p)] = hit
+                        self._put_record(i, s, t, root)
+                        self._update_spans(i, s, t)
+                if solo_pos:
+                    solo_ids = [ids[p] for p in solo_pos]
+                    for p, hit in zip(
+                        solo_pos, self._check_rows(solo_ids, s, t, root)
+                    ):
+                        if hit is not None:
+                            hits[(a, p)] = hit
+                    for i in solo_ids:
+                        self._put_record(i, s, t, root)
+                        solo_updates.append((i, s, t))
+            if solo_updates:
+                self._merge_span_updates(solo_updates)
         self._observe_span_update(t0, n_indices)
 
         out: "list[list[Slashing]]" = [[] for _ in norm]
@@ -735,7 +786,7 @@ class Slasher:
             })
             self.detected.append(hit)
             return hit
-        self.db.put(key, bytes(header_root))
+        self._commit([(key, bytes(header_root))])
         return None
 
     def drain(self) -> "list[Slashing]":

@@ -20,11 +20,13 @@ import random
 import numpy as np
 import pytest
 
+from grandine_tpu.metrics import Metrics
 from grandine_tpu.slasher import (
     CHUNK_EPOCHS,
     VALIDATORS_PER_CHUNK,
     Slasher,
 )
+from grandine_tpu.storage.database import Database
 
 
 def _dump(db):
@@ -232,3 +234,211 @@ def test_prune_after_batched_matches_reference():
         new.on_attestation(ids, s, t, root)
     assert new.prune(150) == ref.prune(150)
     assert _dump(new.db) == _dump(ref.db)
+
+
+# ------------------------------------- one storage transaction per call
+
+DBS = ["memory", "sqlite"]
+
+
+def _make_db(kind, tmp_path, name="chain.sqlite"):
+    if kind == "memory":
+        return Database.in_memory()
+    return Database.persistent(str(tmp_path / name))
+
+
+def _firehose_slot(seed, committees=12, aggregators=16, size=130,
+                   source=2, target=3):
+    """One slot's aggregates as the gossip firehose sees them: every
+    committee's members in `aggregators` aggregates, each missing 0 or 1
+    member, shuffled — so in any 64 of them nearly every validator appears
+    several times. Two aggregators of committee 0 vote another root (a
+    double vote per member) and one of committee 1 votes a span that
+    surrounds the slot's (source, target)."""
+    rng = random.Random(seed)
+    aggs = []
+    for c in range(committees):
+        members = list(range(c * (size + 1), c * (size + 1) + size + c % 2))
+        for a in range(aggregators):
+            ids = list(members)
+            if rng.random() < 0.5:
+                ids.pop(rng.randrange(len(ids)))
+            rng.shuffle(ids)
+            s, t, root = source, target, bytes([c + 1]) * 32
+            if c == 0 and a in (3, 11):
+                root = b"\xee" * 32
+            if c == 1 and a == 7:
+                s, t, root = source - 1, target + 1, b"\xdd" * 32
+            aggs.append((ids, s, t, root))
+    rng.shuffle(aggs)
+    return aggs
+
+
+def _commits(metrics):
+    return metrics.slasher_storage_commits.value
+
+
+@pytest.mark.parametrize("db_kind", DBS)
+def test_firehose_shape_matches_reference(db_kind, tmp_path):
+    """(a) three calls of 64 over one slot's 192 aggregates: every index
+    of every call is a collision; hits and the database's whole `sl:`
+    keyspace equal the reference applied aggregate by aggregate."""
+    aggs = _firehose_slot(5)
+    assert len(aggs) == 192
+    ref = Slasher(_make_db(db_kind, tmp_path, "ref.sqlite"))
+    ref_out = [
+        ref.on_attestation_reference(ids, s, t, root)
+        for ids, s, t, root in aggs
+    ]
+    metrics = Metrics()
+    new = Slasher(_make_db(db_kind, tmp_path), metrics=metrics)
+    new_out = []
+    for k in range(0, 192, 64):
+        new_out.extend(new.on_attestations_bulk(aggs[k:k + 64]))
+    assert [_hits_key(h) for h in new_out] == [_hits_key(h) for h in ref_out]
+    kinds = {h.kind for hits in new_out for h in hits}
+    assert {"double_vote", "surround_vote"} <= kinds
+    assert _dump(new.db) == _dump(ref.db)
+    assert _hits_key(new.drain()) == _hits_key(ref.drain())
+    assert _commits(metrics) == 3
+    # a validator's later lookups in a call are served by its write set
+    reads = metrics.slasher_record_reads
+    assert reads.value("write_set") > 3 * reads.value("db") > 0
+    assert new.prune(4200) == ref.prune(4200)
+    assert _dump(new.db) == _dump(ref.db)
+
+
+@pytest.mark.parametrize("db_kind", DBS)
+def test_offences_inside_one_call_are_found(db_kind, tmp_path):
+    """(b) both votes of a double vote in ONE call, and a surround whose
+    surrounded record was written earlier in the SAME call: found, with
+    the evidence read through the call's write set."""
+    sl = Slasher(_make_db(db_kind, tmp_path))
+    out = sl.on_attestations_bulk([
+        ([7, 8], 10, 20, b"\xaa" * 32),
+        ([7], 11, 20, b"\xbb" * 32),      # double vote of 7
+        ([8, 9], 5, 30, b"\xcc" * 32),    # surrounds 8's (10, 20)
+        ([9], 6, 29, b"\xdd" * 32),       # surrounded by 9's (5, 30)
+    ])
+    assert [len(lst) for lst in out] == [0, 1, 1, 1]
+    hits = [h for lst in out for h in lst]
+    assert [(h.kind, h.validator_index) for h in hits] == [
+        ("double_vote", 7), ("surround_vote", 8), ("surrounded_vote", 9),
+    ]
+    assert hits[0].evidence == {
+        "target_epoch": 20,
+        "roots": [(b"\xaa" * 32).hex(), (b"\xbb" * 32).hex()],
+    }
+    assert hits[1].evidence == {"existing": [10, 20], "new": [5, 30]}
+    assert hits[2].evidence == {"existing": [5, 30], "new": [6, 29]}
+    assert sl.record_for(7, 20) == (11, b"\xbb" * 32)  # last write wins
+
+
+@pytest.mark.parametrize("db_kind", DBS)
+@pytest.mark.parametrize("call", [
+    "bulk_collisions", "bulk_solo", "aggregate", "aggregate_repeated",
+    "reference", "block",
+])
+def test_one_commit_per_call(db_kind, call, tmp_path):
+    """(c) `slasher_storage_commits_total` grows by exactly 1 per
+    mutating call, collisions or not."""
+    metrics = Metrics()
+    sl = Slasher(_make_db(db_kind, tmp_path), metrics=metrics)
+    sl.on_attestation(list(range(600)), 3, 4, b"\x11" * 32)
+    before = _commits(metrics)
+    assert before == 1
+    if call == "bulk_collisions":
+        sl.on_attestations_bulk(_firehose_slot(9)[:64])
+    elif call == "bulk_solo":
+        sl.on_attestations_bulk(
+            [([i], 40, 41, b"\x22" * 32) for i in range(64)])
+    elif call == "aggregate":
+        sl.on_attestation(list(range(300)), 40, 41, b"\x22" * 32)
+    elif call == "aggregate_repeated":
+        sl.on_attestation([5, 6, 5, 300, 6], 40, 41, b"\x22" * 32)
+    elif call == "reference":
+        sl.on_attestation_reference(list(range(300)), 40, 41, b"\x22" * 32)
+    else:
+        assert sl.on_block(3, 9, b"\x33" * 32) is None
+    assert _commits(metrics) == before + 1
+    assert not sl._write_set and not sl._dirty
+
+
+@pytest.mark.parametrize("entry", ["bulk", "aggregate"])
+def test_call_is_committed_when_it_returns(entry, tmp_path):
+    """(d) the call's durability point is its return: a second connection
+    to the sqlite file (the first still open, as after a crash) finds
+    every record and chunk of the call."""
+    aggs = _firehose_slot(13, committees=3)[:40]
+    sl = Slasher(_make_db("sqlite", tmp_path))
+    if entry == "bulk":
+        sl.on_attestations_bulk(aggs)
+    else:
+        for ids, s, t, root in aggs:
+            sl.on_attestation(ids, s, t, root)
+    ref = Slasher()
+    for ids, s, t, root in aggs:
+        ref.on_attestation_reference(ids, s, t, root)
+    reopened = Database.persistent(str(tmp_path / "chain.sqlite"))
+    try:
+        rows = _dump(reopened)
+        assert rows == _dump(ref.db)
+        prefixes = {k[:5] for k, _ in rows}
+        assert prefixes == {b"sl:r:", b"sl:t:", b"sl:m:", b"sl:x:", b"sl:e:"}
+        # and a slasher built on the reopened file detects against them
+        again = Slasher(reopened)
+        hits = again.on_attestation([aggs[0][0][0]], 2, 3, b"\x99" * 32)
+        assert [h.kind for h in hits] == ["double_vote"]
+    finally:
+        reopened.close()
+
+
+@pytest.mark.parametrize("db_kind", DBS)
+@pytest.mark.parametrize("fault", ["in_the_body", "in_the_flush"])
+def test_call_that_raises_is_rolled_back(db_kind, fault, tmp_path,
+                                         monkeypatch):
+    """(e) a call that raises midway leaves no write set (and no dirty
+    chunk) for a later call to write: the database and every later call
+    are as if the failed call had never been made."""
+    good1 = _firehose_slot(17, committees=2)[:20]
+    bad = _firehose_slot(18, committees=2, source=6, target=9)[:20]
+    good2 = _firehose_slot(19, committees=2, source=4, target=5)[:20]
+    metrics = Metrics()
+    sl = Slasher(_make_db(db_kind, tmp_path), metrics=metrics)
+    sl.on_attestations_bulk(good1)
+    state = _dump(sl.db)
+
+    class Fault(Exception):
+        pass
+
+    with monkeypatch.context() as patch:
+        if fault == "in_the_body":
+            real, seen = sl._update_spans, []
+
+            def failing(i, s, t):
+                seen.append(i)
+                if len(seen) > 700:
+                    raise Fault()
+                real(i, s, t)
+
+            patch.setattr(sl, "_update_spans", failing)
+        else:
+            def refusing(rows):
+                raise Fault()
+
+            patch.setattr(sl.db, "put_batch", refusing)
+        with pytest.raises(Fault):
+            sl.on_attestations_bulk(bad)
+    assert not sl._write_set and not sl._dirty
+    assert _dump(sl.db) == state
+    assert sl.record_for(bad[0][0][0], 9) is None
+    assert _commits(metrics) == 1
+    out = sl.on_attestations_bulk(good2)
+    assert _commits(metrics) == 2
+
+    ref = Slasher()
+    ref.on_attestations_bulk(good1)
+    ref_out = ref.on_attestations_bulk(good2)
+    assert [_hits_key(h) for h in out] == [_hits_key(h) for h in ref_out]
+    assert _dump(sl.db) == _dump(ref.db)
+    assert _hits_key(sl.detected) == _hits_key(ref.detected)
