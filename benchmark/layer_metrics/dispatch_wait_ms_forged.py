@@ -1,0 +1,12 @@
+"""`dispatch_wait_ms` in the hostile cell (singles-forged), under a base name
+of its own: tests/benchmark_harness/test_span_readers.py pins the
+manifest's entries of base `dispatch_wait_ms` to the two clean cells',
+and a PR that adds a cell may not edit that file. The same reading as
+benchmark/layer_metrics/dispatch_wait_ms.py."""
+from benchmark import span_metrics
+
+LAYER, UNIT = "firehose batching", "ms"
+
+
+def read(run):
+    return span_metrics.flight_median_ms(run, "dispatch_wait_s")

@@ -370,6 +370,21 @@ class Metrics:
         self.att_fallbacks = Counter(
             "attestation_verifier_fallbacks_total",
             "batches degraded to singular verification")
+        # the descent over a failed batch (_isolate): its probes are
+        # calls of the batch's own executable, padded to the batch's
+        # bucket — items / slots is what that padding costs
+        self.att_isolation_probes = Counter(
+            "attestation_isolation_probes_total",
+            "device calls made by descents over failed batches")
+        self.att_isolation_probe_items = Counter(
+            "attestation_isolation_probe_items_total",
+            "real items in those calls")
+        self.att_isolation_probe_slots = Counter(
+            "attestation_isolation_probe_slots_total",
+            "padded batch slots of those calls")
+        self.att_isolated_batches = Counter(
+            "attestation_isolated_batches_total",
+            "failed batches whose descent named at least one bad item")
         # device plane
         self.device_batch_sigs = Counter(
             "device_batch_signatures_total",

@@ -391,11 +391,8 @@ class AttestationVerifier:
                     fl.record.kernel = "fast_aggregate"
                     self._enqueue_settle(settle, prepared, fl)
                     return
-        messages = [p[0] for p in prepared]
-        signatures = [p[1] for p in prepared]
-        members = [p[2] for p in prepared]
         t0 = time.perf_counter()
-        ok = self._batch_check(messages, signatures, members)
+        ok = self._batch_check(prepared)
         dt = time.perf_counter() - t0
         if self.use_device and not skipped:
             fl.note_device(dt)
@@ -434,11 +431,14 @@ class AttestationVerifier:
             self.metrics.att_fallbacks.inc()
         with self._stage("fallback", items=len(prepared)):
             t0 = time.perf_counter()
-            good_items, bad_count = self._isolate(prepared)
+            good_items, bad_count, probes = self._isolate(prepared)
             fl.note_bisect(
                 time.perf_counter() - t0,
                 depth=max(1, len(prepared).bit_length()),
+                probes=probes,
             )
+        if bad_count and self.metrics is not None:
+            self.metrics.att_isolated_batches.inc()
         if bad_count == 0:
             # the batch verdict said "invalid" yet bisection cleared
             # every item: a wrong-verdict device — file the fault kind
@@ -473,19 +473,22 @@ class AttestationVerifier:
 
     # ------------------------------------------------------------ pipeline
 
-    def _device_dispatch(self, prepared):
+    def _device_dispatch(self, prepared, parent=None):
         """Host prep + async device dispatch for one prepared batch.
         Returns a zero-arg settle callable producing the batch verdict, or
-        None when the backend lacks the async seam (foreign backends keep
-        the synchronous `_batch_check` path)."""
+        None when the backend lacks the async seam (`_batch_check` then
+        answers from the host anchor). `parent` is the failed batch that
+        `prepared` is a part of, when the call is a probe of its descent
+        (`_isolate`): the call pads to the parent's bucket, so it runs
+        the parent's own executable, and is counted as a probe."""
         backend = self._ensure_backend()
         if not _health.has_async_seam(backend):
             return None
         messages = [p[0] for p in prepared]
         try:
             # decompress WITHOUT the per-signature host subgroup
-            # scalar-mul; the device checks the whole batch in one ψ
-            # ladder (see _batch_check for the rationale)
+            # scalar-mul (~9 ms each — it dominated batch latency); the
+            # device checks the whole batch in one ψ ladder
             with self._stage("host_prep", op="g2_decompress",
                              items=len(prepared)):
                 points = [
@@ -503,7 +506,8 @@ class AttestationVerifier:
         # back-to-back on the device. Verifying a not-yet-subgroup-
         # checked (but on-curve) point is safe either way — if the
         # membership check fails the batch verdict is False and the
-        # items fall to bisection, whose singular path is fully checked.
+        # items fall to bisection, every probe of which comes through
+        # here again and carries the same membership check.
         fused = getattr(backend, "fuse_subgroup", False)
         sub_settle = (
             None if fused else backend.g2_subgroup_check_batch_async(points)
@@ -511,14 +515,22 @@ class AttestationVerifier:
         sigs = [A.Signature(p) for p in points]
         if self.metrics is not None:
             self.metrics.device_batch_sigs.inc(len(sigs))
+        # a probe names its parent's sizes as the bucket's floor; a first
+        # pass passes nothing, so a foreign backend's seam stays as it was
+        pin = {}
+        if parent is not None:
+            pin["bucket_floor"] = (
+                len(parent), max(len(p[5]) for p in parent)
+            )
+            self._count_probe(len(prepared), len(parent))
         registry = self._sync_registry(prepared)
         if registry is not None:
             ver_settle = backend.fast_aggregate_verify_batch_indexed_async(
-                messages, sigs, [p[5] for p in prepared], registry
+                messages, sigs, [p[5] for p in prepared], registry, **pin
             )
         else:
             ver_settle = backend.fast_aggregate_verify_batch_async(
-                messages, sigs, [p[2] for p in prepared]
+                messages, sigs, [p[2] for p in prepared], **pin
             )
 
         def settle() -> bool:
@@ -695,52 +707,66 @@ class AttestationVerifier:
                 self.stats.get("settle_errors", 0) + 1
             )
         t0 = time.perf_counter()
-        ok = self._batch_check(
-            [p[0] for p in prepared],
-            [p[1] for p in prepared],
-            [p[2] for p in prepared],
-        )
+        ok = self._batch_check(prepared)
         if fl is not None:
             fl.note_host(time.perf_counter() - t0)
         self._resolve_batch(prepared, ok, fl)
 
     def _isolate(self, prepared):
-        """Recursive bisection over a FAILED batch: re-check halves as
-        batches, descend only into failing halves. Returns
-        (good_items, bad_count)."""
+        """Bisection over a FAILED batch, inside the batch's own bucket:
+        halves are re-checked as batches padded to the parent's shape
+        (`_device_dispatch(parent=)`: the executable the batch itself
+        ran, over the resident registry — no second kernel, no second
+        shape, nothing to compile on the settle path), and only failing
+        halves are descended into. A half of ONE item that its probe
+        refused is bad: the probe was that item's own check, so one bad
+        item in 2^k costs 2k probes. A batch of one has no halves: one
+        re-check stands in for the descent. Returns (good_items,
+        bad_count, probes)."""
         if len(prepared) == 1:
-            try:
-                ok = bool(
-                    self._batch_check(
-                        [prepared[0][0]], [prepared[0][1]], [prepared[0][2]]
-                    )
-                )
-            except ValueError:
-                ok = False  # malformed signature (BlsError): drop the item
-            return (list(prepared), 0) if ok else ([], 1)
-        mid = len(prepared) // 2
-        good, bad = [], 0
-        for half in (prepared[:mid], prepared[mid:]):
-            # non-crypto errors (device/runtime faults) PROPAGATE — honest
-            # votes must not be silently rejected on a backend hiccup; the
-            # pool's task catch surfaces the failure like the old fallback
-            try:
-                half_ok = bool(
-                    self._batch_check(
-                        [p[0] for p in half],
-                        [p[1] for p in half],
-                        [p[2] for p in half],
-                    )
-                )
-            except ValueError:
-                half_ok = False  # a malformed signature inside: descend
-            if half_ok:
+            ok = self._probe(prepared, prepared, 1)
+            return (list(prepared), 0, 1) if ok else ([], 1, 1)
+        return self._bisect(prepared, prepared, 1)
+
+    def _bisect(self, items, parent, depth: int):
+        mid = len(items) // 2
+        good, bad, probes = [], 0, 0
+        for half in (items[:mid], items[mid:]):
+            probes += 1
+            if self._probe(half, parent, depth):
                 good.extend(half)
+            elif len(half) == 1:
+                bad += 1
             else:
-                g, b = self._isolate(half)
+                g, b, n = self._bisect(half, parent, depth + 1)
                 good.extend(g)
                 bad += b
-        return good, bad
+                probes += n
+        return good, bad, probes
+
+    def _probe(self, items, parent, depth: int) -> bool:
+        """One re-check of a part of a failed batch: a `probe` span under
+        the `fallback` stage (a plain span: the stage's seconds stay the
+        whole descent's, counted once). Non-crypto errors (device/runtime
+        faults) PROPAGATE — honest votes must not be silently rejected on
+        a backend hiccup; the pool's task catch surfaces the failure like
+        the old fallback."""
+        with self.tracer.span("probe", {
+            "op": "probe", "items": len(items),
+            "bucket": _flight.bucket_of(len(parent)), "depth": depth,
+        }):
+            try:
+                return bool(self._batch_check(items, parent))
+            except ValueError:
+                return False  # a malformed signature inside: descend
+
+    def _count_probe(self, items: int, parent_items: int) -> None:
+        if self.metrics is not None:
+            self.metrics.att_isolation_probes.inc()
+            self.metrics.att_isolation_probe_items.inc(items)
+            self.metrics.att_isolation_probe_slots.inc(
+                _flight.bucket_of(parent_items)
+            )
 
     def _prevalidate(self, state, attestation):
         """Committee lookup + fork-choice windows; returns
@@ -915,59 +941,41 @@ class AttestationVerifier:
                 )
         return set(prev_indices) & set(indices)
 
-    def _batch_check(self, messages, signatures, members) -> bool:
+    def _batch_check(self, prepared, parent=None) -> bool:
+        """One synchronous verdict over `prepared`: through the entry the
+        pipelined first pass takes (`_device_dispatch`: the indexed
+        kernel over the resident registry, else the upload entry; padded
+        to `parent`'s bucket for a probe) while the device is allowed,
+        else from the host anchor."""
         if self.use_device and self.health.allow_device():
             try:
-                ok = self._device_batch_check(messages, signatures, members)
+                settle = self._device_dispatch(prepared, parent)
+                ok = None if settle is None else bool(settle())
             except ValueError:
                 # crypto-malformed input (BlsError): the item's problem,
                 # not the device's — no breaker fault
                 raise
             except Exception:
                 # device/runtime fault: feed the breaker, then PROPAGATE
-                # (see _isolate — honest votes are not silently rejected)
+                # (see _probe — honest votes are not silently rejected)
                 self.health.record_fault("settle")
                 raise
-            self.health.record_success()
-            return ok
-        # host anchor path (small batches / tests / breaker OPEN): all
-        # host work, so the whole check is the "execute" stage
-        with self._stage("execute", path="host", items=len(messages)):
+            if ok is not None:
+                self.health.record_success()
+                return ok
+        # host anchor path (breaker OPEN / a backend without the async
+        # seam / tests): all host work, so the whole check is the
+        # "execute" stage
+        with self._stage("execute", path="host", items=len(prepared)):
             try:
                 return all(
-                    A.Signature.from_bytes(sig).fast_aggregate_verify(msg, mems)
-                    for msg, sig, mems in zip(messages, signatures, members)
+                    A.Signature.from_bytes(p[1]).fast_aggregate_verify(
+                        p[0], p[2]
+                    )
+                    for p in prepared
                 )
             except A.BlsError:
                 return False
-
-    def _device_batch_check(self, messages, signatures, members) -> bool:
-        backend = self._ensure_backend()
-        try:
-            # decompress WITHOUT the per-signature host subgroup
-            # scalar-mul (~9 ms each — it dominated batch latency);
-            # the device checks the whole batch in one ψ ladder.
-            # A failed batch falls to the singular path, which uses
-            # the fully-checked from_bytes and isolates the item.
-            with self._stage("host_prep", op="g2_decompress",
-                             items=len(signatures)):
-                points = [
-                    A.g2_from_bytes(bytes(s), subgroup_check=False)
-                    for s in signatures
-                ]
-        except A.BlsError:
-            return False
-        if any(p.is_infinity() for p in points):
-            return False
-        # fused backends check membership inside the verify kernel —
-        # no separate subgroup dispatch
-        if not getattr(backend, "fuse_subgroup", False):
-            if not bool(backend.g2_subgroup_check_batch(points).all()):
-                return False
-        sigs = [A.Signature(p) for p in points]
-        if self.metrics is not None:
-            self.metrics.device_batch_sigs.inc(len(sigs))
-        return backend.fast_aggregate_verify_batch(messages, sigs, members)
 
     # ------------------------------------------------------------ control
 

@@ -130,7 +130,8 @@ class BatchRecord:
     __slots__ = (
         "seq", "t", "kind", "lane", "kernel", "items", "bucket", "fill",
         "queue_wait_s", "device_s", "host_s", "bisect_s", "verdict",
-        "fault", "retries", "bisect_depth", "breaker_state", "recompile",
+        "fault", "retries", "bisect_depth", "probes", "breaker_state",
+        "recompile",
         "slo_miss", "slo_cause", "origin", "note", "devices",
         "quarantined", "brownout", "trace_id", "collect_wait_s",
         "pool_wait_s", "dispatch_wait_s", "settle_wait_s", "settle_s",
@@ -180,6 +181,8 @@ class BatchRecord:
         self.fault: "Optional[str]" = None
         self.retries = 0
         self.bisect_depth = 0
+        #: re-checks the batch's descent made (0: the batch passed)
+        self.probes = 0
         self.breaker_state = ""
         self.recompile = False
         self.slo_miss = False
@@ -219,6 +222,7 @@ class BatchRecord:
             "fault": self.fault,
             "retries": self.retries,
             "bisect_depth": self.bisect_depth,
+            "probes": self.probes,
             "breaker_state": self.breaker_state,
             "recompile": self.recompile,
             "slo_miss": self.slo_miss,
@@ -268,9 +272,11 @@ class BatchFlight:
     def note_host(self, seconds: float) -> None:
         self.record.host_s += max(0.0, seconds)
 
-    def note_bisect(self, seconds: float, depth: int = 0) -> None:
+    def note_bisect(self, seconds: float, depth: int = 0,
+                    probes: int = 0) -> None:
         self.record.bisect_s += max(0.0, seconds)
         self.record.bisect_depth = max(self.record.bisect_depth, int(depth))
+        self.record.probes += int(probes)
 
     def note_retry(self) -> None:
         self.record.retries += 1

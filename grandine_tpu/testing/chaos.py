@@ -136,13 +136,13 @@ class ChaosBackend:
 
     # ------------------------------------------------------ seam wrapping
 
-    def _wrap(self, method: str, invert, args):
+    def _wrap(self, method: str, invert, args, kw=None):
         with self._lock:
             self.dispatches += 1
         kind = self.plan.next_fault()
         if kind == "raise_dispatch":
             raise ChaosFault(f"injected dispatch fault on {method}")
-        inner_settle = getattr(self.inner, method)(*args)
+        inner_settle = getattr(self.inner, method)(*args, **(kw or {}))
 
         def settle():
             if kind == "raise_settle":
@@ -162,19 +162,22 @@ class ChaosBackend:
 
         return settle
 
-    def fast_aggregate_verify_batch_async(self, messages, signatures, keys):
+    # `kw`: the `bucket_floor` a probe of the firehose's descent names
+
+    def fast_aggregate_verify_batch_async(self, messages, signatures, keys,
+                                          **kw):
         return self._wrap(
             "fast_aggregate_verify_batch_async",
             lambda v: not v,
-            (messages, signatures, keys),
+            (messages, signatures, keys), kw,
         )
 
     def fast_aggregate_verify_batch_indexed_async(self, messages, signatures,
-                                                  indices, registry):
+                                                  indices, registry, **kw):
         return self._wrap(
             "fast_aggregate_verify_batch_indexed_async",
             lambda v: not v,
-            (messages, signatures, indices, registry),
+            (messages, signatures, indices, registry), kw,
         )
 
     def g2_subgroup_check_batch_async(self, points):
@@ -268,7 +271,8 @@ class KnownAnswerBackend:
         self.batches.append(len(prep))
         return lambda: all(self.truth.get(m, False) for m in prep)
 
-    def fast_aggregate_verify_batch_async(self, messages, signatures, keys):
+    def fast_aggregate_verify_batch_async(self, messages, signatures, keys,
+                                          bucket_floor=None):
         self.batches.append(len(messages))
         msgs = [bytes(m) for m in messages]
         return lambda: all(self.truth.get(m, False) for m in msgs)

@@ -2117,10 +2117,11 @@ class TpuBlsBackend:
         member_keys: Sequence[Sequence["A.PublicKey"]],
         dst: bytes = constants.DST_SIGNATURE,
         rng=secrets,
+        bucket_floor: "Optional[tuple[int, int]]" = None,
     ) -> bool:
         """M aggregates, each over its own committee (the gossip firehose)."""
         return self.fast_aggregate_verify_batch_async(
-            messages, signatures, member_keys, dst, rng
+            messages, signatures, member_keys, dst, rng, bucket_floor
         )()
 
     def fast_aggregate_verify_batch_async(
@@ -2130,11 +2131,19 @@ class TpuBlsBackend:
         member_keys: Sequence[Sequence["A.PublicKey"]],
         dst: bytes = constants.DST_SIGNATURE,
         rng=secrets,
+        bucket_floor: "Optional[tuple[int, int]]" = None,
     ):
         """Async firehose verify: host prep + dispatch now, a zero-arg
         settle callable forces the device result. This is the seam the
         pipelined AttestationVerifier uses to overlap batch N+1's host
-        prep with batch N's device execute."""
+        prep with batch N's device execute.
+
+        `bucket_floor` is the (items, widest committee) — or the bucket
+        itself — of the batch this call is a PART of: the call then pads
+        to that batch's bucket, so a half of a failed batch runs the
+        executable its parent ran (the kernel masks padding slots, and
+        the MSM plan's shapes are a function of the bucket alone), never
+        a smaller one nobody warmed."""
         m = len(messages)
         if not (m == len(signatures) == len(member_keys)):
             return lambda: False
@@ -2152,6 +2161,7 @@ class TpuBlsBackend:
                     member_keys[i : i + MAX_BUCKET],
                     dst,
                     rng,
+                    bucket_floor,
                 )
 
             first = chunk(0)
@@ -2176,8 +2186,11 @@ class TpuBlsBackend:
                     ks if len(ks) <= MAX_BUCKET else [A.PublicKey.aggregate(ks)]
                     for ks in member_keys
                 ]
-            bm = _bucket(m)
-            bk = _bucket(max(len(ks) for ks in member_keys), lo=4)
+            floor_m, floor_k = bucket_floor or (0, 0)
+            bm = _bucket(max(m, floor_m))
+            bk = _bucket(
+                max(max(len(ks) for ks in member_keys), floor_k), lo=4
+            )
             mem_x = np.zeros((bm, bk, L.NLIMBS), np.int32)
             mem_y = np.zeros((bm, bk, L.NLIMBS), np.int32)
             mem_inf = np.ones((bm, bk), bool)
@@ -2228,9 +2241,11 @@ class TpuBlsBackend:
         registry,
         dst: bytes = constants.DST_SIGNATURE,
         rng=secrets,
+        bucket_floor: "Optional[tuple[int, int]]" = None,
     ) -> bool:
         return self.fast_aggregate_verify_batch_indexed_async(
-            messages, signatures, member_indices, registry, dst, rng
+            messages, signatures, member_indices, registry, dst, rng,
+            bucket_floor,
         )()
 
     def fast_aggregate_verify_batch_indexed_async(
@@ -2241,6 +2256,7 @@ class TpuBlsBackend:
         registry,
         dst: bytes = constants.DST_SIGNATURE,
         rng=secrets,
+        bucket_floor: "Optional[tuple[int, int]]" = None,
     ):
         """Registry firehose verify: committee pubkeys stay device-resident
         (tpu/registry.py), gathered on-device by validator index — the
@@ -2251,7 +2267,8 @@ class TpuBlsBackend:
         upload path through the registry's host mirror; an index the
         registry does not cover (cold registry, out-of-range) is a
         verification failure — it names a validator outside the set the
-        caller synced the registry to."""
+        caller synced the registry to. `bucket_floor`: as in
+        `fast_aggregate_verify_batch_async`."""
         m = len(messages)
         if not (m == len(signatures) == len(member_indices)):
             return lambda: False
@@ -2268,6 +2285,7 @@ class TpuBlsBackend:
                     registry,
                     dst,
                     rng,
+                    bucket_floor,
                 )
 
             first = chunk(0)
@@ -2300,10 +2318,12 @@ class TpuBlsBackend:
                 [registry.public_keys(ix) for ix in member_indices],
                 dst,
                 rng,
+                bucket_floor,
             )
         with self._stage("host_prep", op="pack_aggregate_idx", items=m):
-            bm = _bucket(m)
-            bk = _bucket(widest, lo=4)
+            floor_m, floor_k = bucket_floor or (0, 0)
+            bm = _bucket(max(m, floor_m))
+            bk = _bucket(max(widest, floor_k), lo=4)
             mem_idx = np.zeros((bm, bk), np.int32)
             mem_inf = np.ones((bm, bk), bool)  # True = padding slot
             slot_pad = np.arange(bm) >= m

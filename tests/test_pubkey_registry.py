@@ -13,7 +13,7 @@ Three tiers in one module:
 """
 
 import random
-import time
+import threading
 
 import numpy as np
 import pytest
@@ -223,26 +223,35 @@ def test_verifier_wires_registry_staleness_hook():
 # ------------------------------------------------------ pipeline overlap
 
 
-class _SlowSettleBackend:
-    """Async-seam stub: dispatch returns instantly; settle sleeps inside a
-    `readback` span, so overlap between one batch's readback and the next
-    batch's host_prep is visible on the span timeline."""
+class _HandshakeBackend:
+    """Async-seam stub: dispatch returns instantly; the settle of batch k
+    (inside a `readback` span) does not return before batch k+1 has been
+    DISPATCHED, which only a pipeline at least two deep lets happen. No
+    sleep decides anything: `waited[k]` says whether the next dispatch
+    came while settle k was held (the timeout only ends a run whose
+    pipeline does not overlap)."""
 
-    def __init__(self, tracer, settle_s: float = 0.25) -> None:
+    def __init__(self, tracer, batches: int, timeout_s: float = 20.0) -> None:
         self.tracer = tracer
-        self.settle_s = settle_s
+        self.timeout_s = timeout_s
         self.dispatches = 0
+        self.dispatched = [threading.Event() for _ in range(batches + 1)]
+        self.dispatched[batches].set()  # the last batch has no successor
+        self.waited: "list[bool]" = []
 
     def g2_subgroup_check_batch_async(self, points):
         n = len(points)
         return lambda: np.ones((n,), bool)
 
     def fast_aggregate_verify_batch_async(self, messages, sigs, members):
+        k = self.dispatches
         self.dispatches += 1
+        self.dispatched[k].set()
 
         def settle() -> bool:
             with self.tracer.span("readback", {"stub": True}):
-                time.sleep(self.settle_s)
+                self.waited.append(
+                    self.dispatched[k + 1].wait(self.timeout_s))
             return True
 
         return settle
@@ -250,8 +259,12 @@ class _SlowSettleBackend:
 
 def test_pipelined_dispatch_overlaps_prep_with_readback():
     """Acceptance: with max_active=1 (no task-level parallelism), batch
-    N+1's host_prep must START before batch N's readback ENDS — only the
-    two-deep dispatch queue makes that possible."""
+    N+1's host_prep and dispatch must happen before batch N's readback
+    ENDS — only the two-deep dispatch queue makes that possible. Judged by
+    the ORDER of events (the stub's settle waits for the next dispatch),
+    not by how two sleeping threads happen to share a loaded machine: the
+    version that compared wall-clock span windows around a 0.25 s sleep
+    failed under six xdist workers with the pipeline sound (ROADMAP D0)."""
     from grandine_tpu.consensus.verifier import NullVerifier
     from grandine_tpu.fork_choice.store import Tick, TickKind
     from grandine_tpu.runtime import AttestationVerifier, Controller
@@ -264,7 +277,7 @@ def test_pipelined_dispatch_overlaps_prep_with_readback():
     genesis = interop_genesis_state(32, cfg)
     tracer = Tracer()
     ctrl = Controller(genesis, cfg, verifier_factory=NullVerifier)
-    stub = _SlowSettleBackend(tracer, settle_s=0.25)
+    stub = _HandshakeBackend(tracer, batches=4)
     verifier = AttestationVerifier(
         ctrl,
         backend=stub,
@@ -274,6 +287,8 @@ def test_pipelined_dispatch_overlaps_prep_with_readback():
         max_active=1,
         deadline_s=0.005,
         tracer=tracer,
+        # the settle watchdog must outlast the handshake's own timeout
+        settle_timeout_s=60.0,
     )
     try:
         blk, post = produce_block(
@@ -285,26 +300,34 @@ def test_pipelined_dispatch_overlaps_prep_with_readback():
         att = produce_attestations(post, cfg, slot=1)[0]
         # four copies → four single-item batches through the pipeline
         verifier.submit_many([att, att, att, att])
-        verifier.flush(timeout=30.0)
+        verifier.flush(timeout=120.0)
         assert verifier.stats["accepted"] == 4
         assert stub.dispatches == 4
     finally:
         verifier.stop()
         ctrl.stop()
 
+    # every settle saw the next batch dispatched while it was held
+    assert stub.waited == [True] * 4, (
+        "a batch's settle timed out waiting for the next dispatch — the "
+        "dispatch queue is not pipelining"
+    )
+    # and the spans say the same, by construction and not by the clock:
+    # the settles run in dispatch order on one completion thread, and
+    # batch k+1's host_prep precedes its dispatch, which precedes the
+    # end of batch k's readback
     spans = tracer.finished_spans()
-    readbacks = [s for s in spans if s.name == "readback"]
-    preps = [s for s in spans if s.name == "host_prep"]
+    readbacks = sorted((s for s in spans if s.name == "readback"),
+                       key=lambda s: s.start)
     assert len(readbacks) == 4
-    overlapped = any(
-        h.trace_id != r.trace_id and r.start < h.start < r.end
-        for r in readbacks
-        for h in preps
-    )
-    assert overlapped, (
-        "no host_prep span of a later batch started inside an earlier "
-        "batch's readback window — the dispatch queue is not pipelining"
-    )
+    first_prep = {}
+    for s in spans:
+        if s.name == "host_prep":
+            first_prep[s.trace_id] = min(
+                s.start, first_prep.get(s.trace_id, s.start))
+    for held, nxt in zip(readbacks, readbacks[1:]):
+        assert held.trace_id != nxt.trace_id
+        assert first_prep[nxt.trace_id] < held.end
 
 
 # ----------------------------------------------------- kernel differential
