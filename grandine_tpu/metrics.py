@@ -385,6 +385,17 @@ class Metrics:
         self.att_isolated_batches = Counter(
             "attestation_isolated_batches_total",
             "failed batches whose descent named at least one bad item")
+        # how often the descent's schedule engages: a suspect set taken
+        # on without a probe of its own, and the one call that clears
+        # every half the walk passed over
+        self.att_isolation_inferred = Counter(
+            "attestation_isolation_inferred_total",
+            "suspect sets a descent entered without a probe of their own")
+        self.att_isolation_union_probes = LabeledCounter(
+            "attestation_isolation_union_probes_total",
+            "probes of all of a descent's deferred halves at once",
+            ("verdict",),
+        )
         # device plane
         self.device_batch_sigs = Counter(
             "device_batch_signatures_total",

@@ -69,6 +69,18 @@ class _BatchLife:
         self.pool_span = tracer.span("pool_wait", parent=self.root)
 
 
+class _Descent:
+    """What one descent over a failed batch (`_isolate`) has found so far:
+    the batch, the positions of the items named bad, the probes made."""
+
+    __slots__ = ("parent", "bad", "probes")
+
+    def __init__(self, parent) -> None:
+        self.parent = parent
+        self.bad: "set[int]" = set()
+        self.probes = 0
+
+
 class AttestationVerifier:
     """Accumulate → deadline/size-bound batch → device verify → feedback.
 
@@ -713,52 +725,101 @@ class AttestationVerifier:
         self._resolve_batch(prepared, ok, fl)
 
     def _isolate(self, prepared):
-        """Bisection over a FAILED batch, inside the batch's own bucket:
-        halves are re-checked as batches padded to the parent's shape
-        (`_device_dispatch(parent=)`: the executable the batch itself
-        ran, over the resident registry — no second kernel, no second
-        shape, nothing to compile on the settle path), and only failing
-        halves are descended into. A half of ONE item that its probe
-        refused is bad: the probe was that item's own check, so one bad
-        item in 2^k costs 2k probes. A batch of one has no halves: one
-        re-check stands in for the descent. Returns (good_items,
-        bad_count, probes)."""
-        if len(prepared) == 1:
-            ok = self._probe(prepared, prepared, 1)
-            return (list(prepared), 0, 1) if ok else ([], 1, 1)
-        return self._bisect(prepared, prepared, 1)
+        """The descent over a FAILED batch, inside the batch's own bucket:
+        a probe re-checks a part of the batch as a batch padded to the
+        parent's shape (`_device_dispatch(parent=)`: the executable the
+        batch itself ran, over the resident registry: no second kernel, no
+        second shape, nothing to compile on the settle path). A call costs
+        the same whatever its width, so the schedule (`_descend`) spends
+        ONE probe a level and clears what it passed over in one call: one
+        bad item in 2^k costs k + 1 or k + 2 probes. Every item delivered
+        lay in a probe that verified; every item rejected was refused by a
+        probe of that item alone, so nothing is rejected on inference.
+        Returns (good_items in the batch's order, bad_count, probes)."""
+        descent = _Descent(prepared)
+        self._descend(descent, 0, len(prepared), False, 0)
+        bad = descent.bad
+        good = [p for i, p in enumerate(prepared) if i not in bad]
+        return good, len(bad), descent.probes
 
-    def _bisect(self, items, parent, depth: int):
-        mid = len(items) // 2
-        good, bad, probes = [], 0, 0
-        for half in (items[:mid], items[mid:]):
-            probes += 1
-            if self._probe(half, parent, depth):
-                good.extend(half)
-            elif len(half) == 1:
-                bad += 1
+    def _descend(self, descent: "_Descent", lo: int, hi: int, refused: bool,
+                 depth: int) -> None:
+        """The schedule over the suspect set parent[lo:hi], a set known to
+        hold a bad item: by a probe of exactly this set that was `refused`,
+        else by inference (a set that holds no bad item always verifies,
+        so a suspect set whose first half verified has its bad item in the
+        second; the failed batch itself counts as inferred, so a batch of
+        one is re-checked). `depth` is the halvings that gave the set.
+        Names the bad items' positions into `descent.bad`.
+
+        1. Probe the first half only. Verified: it is good, go on in the
+           second half by inference. Refused: go on in the first half, the
+           second is DEFERRED (verdict unknown).
+        2. One item left: refused by its own probe, it is bad; reached by
+           inference, it gets the confirming probe.
+        3. The deferred halves lie side by side behind the item (each was
+           cut off the end of the set), so ONE probe clears their union.
+           If that is refused: a single deferred half is the next suspect
+           set (this was its probe); several get a probe each, smallest
+           first (the batch's order), the last by inference if the others
+           all verified, and the schedule again inside each refused one."""
+        deferred = []  # (lo, hi, depth), the largest first
+        while hi - lo > 1:
+            mid = lo + (hi - lo) // 2
+            depth += 1
+            if self._probe(descent, lo, mid, depth, "first_half"):
+                lo, refused = mid, False
+                self._count_inferred()
             else:
-                g, b, n = self._bisect(half, parent, depth + 1)
-                good.extend(g)
-                bad += b
-                probes += n
-        return good, bad, probes
+                deferred.append((mid, hi, depth))
+                hi, refused = mid, True
+        if refused or not self._probe(descent, lo, hi, depth, "confirm"):
+            descent.bad.add(lo)
+        if not deferred:
+            return
+        _, top, shallowest = deferred[0]
+        cleared = self._probe(descent, hi, top, shallowest, "union")
+        if self.metrics is not None:
+            self.metrics.att_isolation_union_probes.inc(
+                "ok" if cleared else "refused"
+            )
+        if cleared:
+            return
+        if len(deferred) == 1:
+            self._descend(descent, hi, top, True, shallowest)
+            return
+        others_verified = True
+        for a, b, d in reversed(deferred):
+            if b == top and others_verified:
+                self._count_inferred()
+                self._descend(descent, a, b, False, d)
+            elif not self._probe(descent, a, b, d, "piece"):
+                others_verified = False
+                self._descend(descent, a, b, True, d)
 
-    def _probe(self, items, parent, depth: int) -> bool:
-        """One re-check of a part of a failed batch: a `probe` span under
-        the `fallback` stage (a plain span: the stage's seconds stay the
-        whole descent's, counted once). Non-crypto errors (device/runtime
-        faults) PROPAGATE — honest votes must not be silently rejected on
-        a backend hiccup; the pool's task catch surfaces the failure like
-        the old fallback."""
+    def _probe(self, descent: "_Descent", lo: int, hi: int, depth: int,
+               why: str) -> bool:
+        """One re-check of parent[lo:hi], a part of a failed batch: a
+        `probe` span under the `fallback` stage (a plain span: the stage's
+        seconds stay the whole descent's, counted once). Non-crypto errors
+        (device/runtime faults) PROPAGATE: honest votes must not be
+        silently rejected on a backend hiccup; the pool's task catch
+        surfaces the failure like the old fallback."""
+        parent = descent.parent
+        descent.probes += 1
         with self.tracer.span("probe", {
-            "op": "probe", "items": len(items),
+            "op": "probe", "items": hi - lo,
             "bucket": _flight.bucket_of(len(parent)), "depth": depth,
+            "why": why,
         }):
             try:
-                return bool(self._batch_check(items, parent))
+                return bool(self._batch_check(parent[lo:hi], parent))
             except ValueError:
-                return False  # a malformed signature inside: descend
+                return False  # a malformed signature inside: refused
+
+    def _count_inferred(self) -> None:
+        if self.metrics is not None:
+            self.metrics.att_isolation_inferred.inc()
 
     def _count_probe(self, items: int, parent_items: int) -> None:
         if self.metrics is not None:

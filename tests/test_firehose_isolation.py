@@ -1,5 +1,6 @@
 """The firehose's descent over a failed batch (`AttestationVerifier.
-_isolate`): bisection inside the batch's own padded bucket and executable.
+_isolate`): one probe a level inside the batch's own padded bucket and
+executable, the halves it passes over cleared in one call.
 
 Single votes of one slot of a 512-validator minimal-preset chain (64 a
 slot), made by the benchmark's generator and forged as the hostile cell
@@ -9,7 +10,10 @@ refuses it). The backend is a recording stub of the device seam: it
 answers from a per-item verdict (the program's host anchor for the small
 batches that are held against the benchmark's plain reference, the forged
 labels for the batch of 64) and writes down every call's kernel and
-padded shape.
+padded shape. `run_batch` also writes down every probe as the descent
+asked for it (positions in the batch, verdict), and every case is held to
+the schedule's two invariants: a delivered item lay in a probe that
+verified, a rejected item was refused by a probe of that item alone.
 """
 
 import dataclasses
@@ -130,11 +134,41 @@ def wire(ns, item):
     )
 
 
+def held(items, delivered, probes) -> None:
+    """The two invariants, on the probes as the descent made them, and
+    the batch's own order in what was delivered."""
+    place = {it.members[0]: i for i, it in enumerate(items)}
+    got = [place[v] for v in delivered]
+    assert got == sorted(set(got))
+    if not probes:  # the first pass verified: no descent
+        assert len(got) == len(items)
+        return
+    verified = {i for part, ok in probes if ok for i in part}
+    assert set(got) <= verified
+    for i in set(range(len(items))) - set(got):
+        assert ((i,), False) in probes
+        assert i not in verified
+
+
+def todays_probes(n, bad) -> int:
+    """Probes of the schedule before PR 27 (both halves at every level),
+    which the new one may exceed by at most one probe a bad item."""
+    def descend(lo, hi):
+        mid = lo + (hi - lo) // 2
+        return sum(
+            1 + (descend(a, b) if b - a > 1 and any(a <= i < b for i in bad)
+                 else 0)
+            for a, b in ((lo, mid), (mid, hi)))
+    return 1 if n == 1 else descend(0, n)
+
+
 def run_batch(genesis, items, verdict):
     """`items` as ONE batch through a verifier over the recording backend,
     registry in sync. Returns what was delivered (validator index), the
-    verifier's stats, the backend, the metrics, spans and flight rows, and
-    the growth of the compile scope's count over the batch."""
+    verifier's stats, the backend, the metrics, spans and flight rows, the
+    descent's probes as (positions, verdict), and the growth of the
+    compile scope's count over the batch. Asserts the invariants
+    (`held`)."""
     from grandine_tpu.consensus import accessors
     from grandine_tpu.transition.fork_upgrade import state_phase
     from grandine_tpu.types.containers import spec_types
@@ -155,6 +189,20 @@ def run_batch(genesis, items, verdict):
         return inner(valids)
 
     ctrl.on_valid_attestation_batch = deliver
+    probes = []
+    where = {it.signature: i for i, it in enumerate(items)}
+    check = verifier._batch_check
+
+    def recorded_check(prepared, parent=None):
+        ok = False  # a ValueError inside is a refusal (`_probe`)
+        try:
+            ok = check(prepared, parent)
+        finally:
+            if parent is not None:
+                probes.append((tuple(where[p[1]] for p in prepared), ok))
+        return ok
+
+    verifier._batch_check = recorded_check
     try:
         state = ctrl.snapshot().head_state
         assert verifier.registry.ensure(
@@ -170,8 +218,9 @@ def run_batch(genesis, items, verdict):
         rows = [r.as_dict()
                 for r in verifier.flight.snapshot(lane="attestation")
                 if r.kind == BATCH]
+        held(items, delivered, probes)
         return {"delivered": delivered, "stats": dict(verifier.stats),
-                "backend": backend, "metrics": metrics,
+                "backend": backend, "metrics": metrics, "probes": probes,
                 "spans": tracer.finished_spans(), "rows": rows,
                 "compiled": compiled, "breaker": verifier.health.state}
     finally:
@@ -206,12 +255,19 @@ def reference_says(keys, item) -> bool:
     return _REFERENCE[key]
 
 
-@pytest.mark.parametrize("forged", [0, 1, 2, 8])
-def test_delivered_and_rejected_are_the_plain_references(chain, forged):
+@pytest.mark.parametrize("positions", [
+    (), (0,), (7,), (3,), "two drawn", tuple(range(8)),
+], ids=["none", "first", "last", "middle", "two", "all"])
+def test_delivered_and_rejected_are_the_plain_references(chain, positions):
+    """The forged vote first (every level defers, the union clears), last
+    (every level infers, the confirming probe rejects) and in the middle
+    (both): real crypto on both sides."""
     keys, genesis, items = chain
-    rng = random.Random(f"forged|{SEED}|{forged}")
+    if positions == "two drawn":
+        rng = random.Random(f"forged|{SEED}|2")
+        positions = tuple(sorted(rng.sample(range(8), 2)))
+    forged = len(positions)
     batch = list(items[:8])
-    positions = sorted(rng.sample(range(8), forged))
     for pos in positions:
         batch[pos] = forge(keys, batch[pos])
     out = run_batch(genesis, batch, anchor_verdict(keys))
@@ -219,7 +275,7 @@ def test_delivered_and_rejected_are_the_plain_references(chain, forged):
     # the forged ones are exactly those the reference refuses
     assert sorted(set(range(8)) - set(positions)) == [
         i for i, it in enumerate(batch) if reference_says(keys, it)]
-    assert sorted(out["delivered"]) == sorted(want)
+    assert out["delivered"] == want
     assert out["stats"]["accepted"] == 8 - forged
     assert out["stats"]["rejected"] == forged
     assert out["stats"]["fallbacks"] == (1 if forged else 0)
@@ -233,35 +289,54 @@ def test_delivered_and_rejected_are_the_plain_references(chain, forged):
     assert isolated == (1 if forged else 0)
     (row,) = out["rows"]
     assert row["probes"] == len(out["backend"].calls) - 1
+    assert row["probes"] <= todays_probes(8, positions) + forged
+    if forged == 1:
+        (pos,) = positions
+        assert row["probes"] == 3 + (pos & 1) + (pos != 7)
     assert row["verdict"] is (forged == 0)
 
 
 # -- one forged vote in 64 ------------------------------------------------
 
+def stub_batch(chain, n, positions):
+    """The first `n` votes of the slot with those at `positions` forged,
+    through a backend that refuses exactly the forged signatures."""
+    keys, genesis, items = chain
+    batch = list(items[:n])
+    for pos in positions:
+        batch[pos] = forge(keys, batch[pos])
+    bad = {batch[pos].signature for pos in positions}
+    out = run_batch(genesis, batch,
+                    lambda message, sig_bytes, indices: sig_bytes not in bad)
+    return batch, out
+
+
+def one_forged_costs(pos: int, levels: int) -> int:
+    """One probe a level, the confirming probe where the last level was
+    inferred, the union probe where any level deferred."""
+    return levels + (pos & 1) + (pos != 2 ** levels - 1)
+
+
 @pytest.fixture(scope="module")
 def one_in_64(chain):
-    keys, genesis, items = chain
     pos = random.Random(f"one-in-64|{SEED}").randrange(64)
-    batch = list(items)
-    batch[pos] = forge(keys, batch[pos])
-    bad = batch[pos].signature
-    out = run_batch(genesis, batch,
-                    lambda message, sig_bytes, indices: sig_bytes != bad)
+    batch, out = stub_batch(chain, 64, [pos])
     return batch, pos, out
 
 
-def test_one_forged_in_64_makes_twelve_probes(one_in_64):
-    """Both halves at each of six levels (32, 16, 8, 4, 2, 1), whatever
-    the position; the item a probe of one has just refused is not checked
-    a second time."""
+def test_one_forged_in_64_makes_a_probe_a_level(one_in_64):
+    """First halves of 32, 16, 8, 4, 2, 1 items, whatever the position;
+    the confirming probe of the forged vote where its last level was
+    inferred, one probe of everything behind it where a level deferred."""
     batch, pos, out = one_in_64
     calls = out["backend"].calls
-    assert len(calls) == 1 + 12
+    assert len(calls) == 1 + one_forged_costs(pos, 6)
     assert calls[0][2] == 64
+    rest = [1] * (pos & 1) + [63 - pos] * (pos != 63)
     assert sorted(n for _k, _s, n in calls[1:]) == sorted(
-        n for n in (32, 16, 8, 4, 2, 1) for _ in range(2))
-    assert sorted(out["delivered"]) == sorted(
-        it.members[0] for i, it in enumerate(batch) if i != pos)
+        [32, 16, 8, 4, 2, 1] + rest)
+    assert out["delivered"] == [
+        it.members[0] for i, it in enumerate(batch) if i != pos]
     assert out["stats"]["accepted"] == 63 and out["stats"]["rejected"] == 1
 
 
@@ -275,45 +350,173 @@ def test_the_descent_stays_in_the_batchs_own_executable(one_in_64):
 
 
 def test_the_descents_counters_and_flight_row(one_in_64):
-    _batch, _pos, out = one_in_64
+    _batch, pos, out = one_in_64
     m = out["metrics"]
-    assert m.att_isolation_probes.value == 12
-    assert m.att_isolation_probe_items.value == 2 * (32 + 16 + 8 + 4 + 2 + 1)
-    assert m.att_isolation_probe_slots.value == 12 * 64
+    probes = one_forged_costs(pos, 6)
+    items = 63 + (pos & 1) + (63 - pos)
+    assert m.att_isolation_probes.value == probes
+    assert m.att_isolation_probe_items.value == items
+    assert m.att_isolation_probe_slots.value == probes * 64
     assert m.att_isolated_batches.value == 1
     assert m.att_fallbacks.value == 1
+    # a level whose first half verified is entered by inference: the set
+    # bits of the position
+    assert m.att_isolation_inferred.value == bin(pos).count("1")
+    assert m.att_isolation_union_probes.value("ok") == (pos != 63)
+    assert m.att_isolation_union_probes.value("refused") == 0
     text = m.expose()
-    for name in ("attestation_isolation_probes_total 12",
-                 "attestation_isolation_probe_items_total 126",
-                 "attestation_isolation_probe_slots_total 768",
+    for name in (f"attestation_isolation_probes_total {probes}",
+                 f"attestation_isolation_probe_items_total {items}",
+                 f"attestation_isolation_probe_slots_total {probes * 64}",
+                 f"attestation_isolation_inferred_total {bin(pos).count('1')}",
                  "attestation_isolated_batches_total 1"):
         assert name in text
+    if pos != 63:
+        assert ('attestation_isolation_union_probes_total{verdict="ok"} 1'
+                in text)
     (row,) = out["rows"]
-    assert row["probes"] == 12 and row["bisect_s"] > 0
+    assert row["probes"] == probes and row["bisect_s"] > 0
+    assert row["bisect_depth"] == 7
     assert row["verdict"] is False and row["items"] == 64
 
 
 def test_the_descents_probe_spans(one_in_64):
     """One `probe` span a probe, all children of the batch's `fallback`
-    stage; the stage's seconds are observed once (the probes are plain
-    spans), so `verify_stage_seconds_sum{stage="fallback"}` is the whole
-    descent and nothing twice."""
-    _batch, _pos, out = one_in_64
+    stage, each saying `why` it was made; the stage's seconds are observed
+    once (the probes are plain spans), so
+    `verify_stage_seconds_sum{stage="fallback"}` is the whole descent and
+    nothing twice."""
+    _batch, pos, out = one_in_64
     spans = out["spans"]
     (fallback,) = [s for s in spans if s.name == "fallback"]
     probes = [s for s in spans if s.name == "probe"]
-    assert len(probes) == 12
+    assert len(probes) == one_forged_costs(pos, 6)
     assert all(s.parent_id == fallback.span_id for s in probes)
     assert all(s.attrs["op"] == "probe" and s.attrs["bucket"] == 64
                for s in probes)
-    assert sorted((s.attrs["depth"], s.attrs["items"]) for s in probes) == [
-        (d, 64 >> d) for d in range(1, 7) for _ in range(2)]
+    want = [("first_half", d, 64 >> d) for d in range(1, 7)]
+    if pos & 1:
+        want.append(("confirm", 6, 1))
+    if pos != 63:
+        # the union's depth is that of its largest piece: the first level
+        # that deferred, the position's highest clear bit
+        first_clear = next(d for d in range(1, 7) if not pos >> (6 - d) & 1)
+        want.append(("union", first_clear, 63 - pos))
+    assert [(s.attrs["why"], s.attrs["depth"], s.attrs["items"])
+            for s in sorted(probes, key=lambda s: s.start)] == want
     assert all(fallback.start <= s.start and s.end <= fallback.end
                for s in probes)
     family = out["metrics"].verify_stage_seconds
     observed = [labels for labels in family.children()
                 if labels[0] == "fallback"]
     assert observed == [("fallback", "attestation", "")]
+
+
+@pytest.mark.parametrize("pos", range(64))
+def test_every_position_of_the_forged_vote(chain, pos):
+    """6 probes (one a level) + 1 if the last bit is set (the confirming
+    probe) + 1 if any bit is clear (the union probe): 7 or 8."""
+    batch, out = stub_batch(chain, 64, [pos])
+    assert len(out["probes"]) == one_forged_costs(pos, 6)
+    assert 7 <= len(out["probes"]) <= 8
+    assert out["delivered"] == [
+        it.members[0] for i, it in enumerate(batch) if i != pos]
+    assert out["stats"]["accepted"] == 63 and out["stats"]["rejected"] == 1
+    (row,) = out["rows"]
+    assert row["probes"] == len(out["backend"].calls) - 1 == len(
+        out["probes"])
+    assert {(k, s) for k, s, _n in out["backend"].calls} == {(IDX, (64, 4))}
+
+
+@pytest.mark.parametrize("k", [2, 3, 8, 64])
+def test_several_forged_votes(chain, k):
+    """The verdicts of the former schedule, in at most its probes + k."""
+    positions = sorted(
+        random.Random(f"several|{SEED}|{k}").sample(range(64), k))
+    batch, out = stub_batch(chain, 64, positions)
+    assert out["delivered"] == [
+        it.members[0] for i, it in enumerate(batch) if i not in positions]
+    assert out["stats"]["accepted"] == 64 - k
+    assert out["stats"]["rejected"] == k
+    assert len(out["probes"]) <= todays_probes(64, positions) + k
+    (row,) = out["rows"]
+    assert row["probes"] == len(out["probes"])
+    assert out["metrics"].att_isolation_probes.value == len(out["probes"])
+    assert {(k_, s) for k_, s, _n in out["backend"].calls} == {(IDX, (64, 4))}
+    assert out["compiled"] == 0
+    assert out["breaker"] == "closed"
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 5, 63])
+def test_batches_of_other_sizes(chain, n):
+    """Sizes that are no power of two (the first half is the smaller) and
+    the smallest: one forged vote at a seed-drawn place, then two."""
+    for k in {1, min(2, n)}:
+        positions = sorted(
+            random.Random(f"sizes|{SEED}|{n}|{k}").sample(range(n), k))
+        batch, out = stub_batch(chain, n, positions)
+        assert out["delivered"] == [
+            it.members[0] for i, it in enumerate(batch)
+            if i not in positions]
+        assert out["stats"]["rejected"] == k
+        assert len(out["probes"]) <= todays_probes(n, positions) + k
+        assert {(k_, s) for k_, s, _n in out["backend"].calls} == {
+            (IDX, (bucket(n), 4))}
+        (row,) = out["rows"]
+        assert row["probes"] == len(out["probes"])
+
+
+def test_a_device_that_refuses_the_batch_and_then_clears_all_of_it(chain):
+    """Every first half verifies, so every level is entered by inference,
+    down to one item: nothing is rejected on inference, the confirming
+    probe clears it, all 64 are delivered and the `verdict` fault is
+    filed."""
+    keys, genesis, items = chain
+    # the first item asked about sinks the first pass; nothing after it
+    answers = iter([False])
+    out = run_batch(genesis, list(items), lambda *a: next(answers, True))
+    assert [n for _k, _s, n in out["backend"].calls] == [
+        64, 32, 16, 8, 4, 2, 1, 1]
+    assert [ok for _part, ok in out["probes"]] == [True] * 7
+    assert out["probes"][-1][0] == (63,)
+    whys = [s.attrs["why"] for s in sorted(
+        (s for s in out["spans"] if s.name == "probe"),
+        key=lambda s: s.start)]
+    assert whys == ["first_half"] * 6 + ["confirm"]
+    assert out["delivered"] == [it.members[0] for it in items]
+    assert out["stats"]["accepted"] == 64 and out["stats"]["rejected"] == 0
+    assert out["metrics"].att_isolated_batches.value == 0
+    assert out["metrics"].att_isolation_inferred.value == 6
+    (row,) = out["rows"]
+    assert row["fault"] == "verdict" and row["probes"] == 7
+
+
+def test_a_malformed_signature_inside_a_half(chain):
+    """A signature that does not decompress refuses every probe that holds
+    it before any device call (`ValueError` inside a probe = refused), and
+    is rejected by the probe of itself alone; the other seven are
+    delivered."""
+    keys, genesis, items = chain
+    batch = list(items[:8])
+    batch[5] = dataclasses.replace(
+        batch[5], signature=b"\x9f" + b"\xff" * 95)
+    with pytest.raises(A.BlsError):
+        A.g2_from_bytes(batch[5].signature, subgroup_check=False)
+    out = run_batch(genesis, batch, lambda *a: True)
+    assert out["delivered"] == [
+        it.members[0] for i, it in enumerate(batch) if i != 5]
+    assert out["stats"]["accepted"] == 7 and out["stats"]["rejected"] == 1
+    # 0-3 verify; 4-5 refused, 6-7 deferred; 4 verifies; 5 confirmed bad;
+    # the union 6-7 verifies
+    assert out["probes"] == [
+        ((0, 1, 2, 3), True), ((4, 5), False), ((4,), True), ((5,), False),
+        ((6, 7), True)]
+    # the refused ones never reached the device
+    assert [n for _k, _s, n in out["backend"].calls] == [4, 1, 2]
+    assert out["metrics"].att_isolation_probes.value == 3
+    assert out["breaker"] == "closed"
+    (row,) = out["rows"]
+    assert row["probes"] == 5 and row["fault"] is None
 
 
 @pytest.mark.parametrize("first_pass", [True, False],
