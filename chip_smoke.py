@@ -44,8 +44,8 @@ import tempfile
 import time
 import urllib.request
 
-#: phase `plane`, the width: the backend's widest bucket over the message
-#: count of bench.py's headline shape
+#: phase `plane`, the width: the backend's widest bucket over 256 distinct
+#: messages (BASELINE.md config 2's shape)
 PLANE_N = 1 << 14
 PLANE_MSGS = 256
 #: phase `plane`, the firehose's shape: the reference's operator size
@@ -187,8 +187,8 @@ def check(cond, what: str) -> None:
 
 # ------------------------------------------------------------------ data
 #
-# Keys in arithmetic progression, as bench.py's build_batch makes them:
-# sk_i = a + b*i, so N valid (pk, sig) pairs cost N point ADDS on the host
+# Keys in arithmetic progression (as benchmark/generators/keys.py makes
+# them): sk_i = a + b*i, so N valid (pk, sig) pairs cost N point ADDS on the host
 # and no device work. (a, b) come from --seed.
 
 
@@ -275,7 +275,7 @@ def build_later_sections(seed: int, n: int, hs, n_keys: int,
 
 
 def _runtime_claims(jax) -> dict:
-    """Comments in bench.py and tools/ once justified host fetches and
+    """Comments in the pre-chip scripts once justified host fetches and
     fresh arguments by a runtime whose block_until_ready "did not wait"
     and which "deduped" identical executions. Read both on THIS runtime:
     a result fetched after block_until_ready should cost ~nothing more,

@@ -441,8 +441,8 @@ class Metrics:
             ("kernel",),
         )
         # host→device transfer accounting, per kernel variant: the basis
-        # of the no-per-batch-pubkey-upload guard
-        # (tools/check_no_per_batch_upload.py) — registry uploads land
+        # of the no-per-batch-pubkey-upload guard (the lint rule
+        # tools/lint/rules/no_per_batch_upload.py) — registry uploads land
         # under kernel="pubkey_registry", per-batch uploads under the
         # dispatching kernel's name
         self.device_upload_bytes = LabeledCounter(

@@ -382,7 +382,7 @@ class Network:
     # --------------------------------------------- signature dispatching
 
     def _eager_verify_items(self, items) -> bool:
-        """WHITELISTED eager fallback (tools/check_no_inline_gossip_verify
+        """WHITELISTED eager fallback (the lint rule no-inline-gossip-verify
         audits that gossip handlers hold no other verification calls):
         SingleVerifier-equivalent per-item host checks, used when no
         verify scheduler is wired so handler semantics stay synchronous."""

@@ -347,8 +347,8 @@ def verify_block_batch(anchor_state, blocks, cfg, use_device: bool = False,
     bulk=True (default) routes through the BulkReplayPipeline: ONE
     cross-block batch per window instead of one dispatch per block.
     bulk=False keeps the legacy shape — a fresh verifier and one RLC
-    batch PER BLOCK — as the per-block baseline (`bench.py --replay`
-    measures the two against each other)."""
+    batch PER BLOCK — as the per-block baseline
+    (tests/test_replay.py holds the two to the same post-states)."""
     if bulk:
         from grandine_tpu.runtime.replay import (
             DEFAULT_WINDOW_BLOCKS,

@@ -7,8 +7,8 @@ verification so a single bad signature can't stall the stream :231-239,
 :377-386).
 
 TPU shape: each batch becomes ONE `fast_aggregate_verify_batch` launch
-(M aggregates × K committee members — the aggregate_fast_verify_kernel's
-native geometry). The deadline keeps latency bounded when gossip is slow;
+(M aggregates × K committee members — the firehose kernels' native
+geometry, tpu/bls.py aggregate_fast_verify_msm_idx_kernel). The deadline keeps latency bounded when gossip is slow;
 the batch bound keeps device launches dense when it's fast.
 """
 

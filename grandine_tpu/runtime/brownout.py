@@ -65,9 +65,9 @@ NORMAL, B1, B2, B3, CRITICAL = LEVELS
 
 
 class BrownoutController:
-    """The hysteretic ladder walker. One per node (runtime/node.py); the
-    bench (`bench.py --overload`) drives `evaluate()` directly at a
-    fixed cadence for determinism, production uses `start()`."""
+    """The hysteretic ladder walker. One per node (runtime/node.py);
+    tests drive `evaluate()` directly under a fake clock for
+    determinism, production uses `start()`."""
 
     def __init__(
         self,

@@ -38,18 +38,14 @@ statically by the `profiler-scope` check in tools/shapes.
 
 Import discipline: stdlib only at module scope. jax is reached through
 `sys.modules` on the estimator paths (never imported — a host-only node
-must not pay the import) and imported lazily only inside the capture /
-timing helpers the tools/ shims call.
+must not pay the import) and imported lazily only inside a capture
+session.
 """
 
 from __future__ import annotations
 
 import contextlib
-import glob
-import gzip
-import json
 import os
-import shutil
 import sys
 import threading
 import time
@@ -465,81 +461,6 @@ def capturing() -> bool:
     return prof is not None and prof._capturing
 
 
-# ------------------------------- shared helpers for the tools/ shims
-
-
-def time_jit(name: str, fn, *args, iters: int = 5, jit: bool = True,
-             stream=None) -> dict:
-    """The stage-timing primitive the tools/profile_* scripts share:
-    jit the callable, time compile+first-run, then `iters` warm runs,
-    each ended by a host fetch of the result (which waits for the
-    device, as block_until_ready does). Prints one aligned line and
-    returns the numbers."""
-    import jax
-    import numpy as np
-
-    f = jax.jit(fn) if jit else fn
-    t0 = time.time()
-    out = f(*args)
-    np.asarray(jax.tree.leaves(out)[0])  # force execution
-    compile_s = time.time() - t0
-    t0 = time.time()
-    for _ in range(max(1, iters)):
-        out = f(*args)
-        np.asarray(jax.tree.leaves(out)[0])
-    run_s = (time.time() - t0) / max(1, iters)
-    print(
-        f"{name:26s} compile={compile_s:7.1f}s run={run_s * 1000:9.2f}ms",
-        file=stream if stream is not None else sys.stderr,
-    )
-    return {"name": name, "compile_s": compile_s, "run_s": run_s}
-
-
-def capture_trace(fn, trace_dir: str, runs: int = 2) -> str:
-    """Run `fn()` `runs` times under a KernelProfiler capture session
-    writing a device trace into `trace_dir` (recreated), forcing the
-    last result. The capture path the tools/trace_kernel shim rides."""
-    import jax
-
-    shutil.rmtree(trace_dir, ignore_errors=True)
-    prof = KernelProfiler()
-    prof.start(trace_dir=trace_dir)
-    try:
-        out = None
-        for _ in range(max(1, runs)):
-            out = fn()
-        jax.block_until_ready(out)
-    finally:
-        prof.stop()
-    return trace_dir
-
-
-def summarize_trace(trace_dir: str, top: int = 40):
-    """Aggregate the Chrome-trace JSON the jax profiler emitted under
-    `trace_dir`: total complete-event ("X" phase) op time plus the top
-    ops by self time. Returns (total_seconds, [(name, seconds, count)]);
-    (0.0, []) when no trace file exists."""
-    files = glob.glob(f"{trace_dir}/**/*.trace.json.gz", recursive=True)
-    if not files:
-        return 0.0, []
-    with gzip.open(files[0], "rt") as f:
-        trace = json.load(f)
-    durations: "dict[str, float]" = {}
-    counts: "dict[str, int]" = {}
-    for ev in trace.get("traceEvents", []):
-        if ev.get("ph") != "X":
-            continue
-        name = ev.get("name", "")
-        durations[name] = durations.get(name, 0.0) + ev.get("dur", 0)
-        counts[name] = counts.get(name, 0) + 1
-    total = sum(durations.values()) / 1e6
-    rows = [
-        (name, dur / 1e6, counts[name])
-        for name, dur in sorted(durations.items(), key=lambda kv: -kv[1])
-    ]
-    return total, rows[:top]
-
-
 __all__ = [
     "KernelProfiler",
     "KERNEL_SCHEMES",
@@ -550,7 +471,4 @@ __all__ = [
     "set_profiler",
     "capturing",
     "stage_annotation",
-    "time_jit",
-    "capture_trace",
-    "summarize_trace",
 ]

@@ -127,7 +127,7 @@ def jit_cache_dir() -> str:
     """Where compiled kernels persist: `JAX_COMPILATION_CACHE_DIR` when
     the environment sets it, else `<checkout>/.jax_cache` (git-ignored).
     A fixed path, because the path is part of the cache key: a directory
-    that moves never hits. Imports no JAX (bench.py's parents call it)."""
+    that moves never hits. Imports no JAX."""
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
         _repo_root(), ".jax_cache"
     )

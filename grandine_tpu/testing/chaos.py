@@ -25,7 +25,7 @@ supervisor defends against:
       gate can catch before a caller publishes it
 
 `KnownAnswerBackend` is the truth-table stub used underneath the chaos
-wrapper by tests and `bench.py --chaos`: verdicts come from a dict
+wrapper by tests: verdicts come from a dict
 keyed by message bytes, so the fault-free expectation is known exactly.
 """
 

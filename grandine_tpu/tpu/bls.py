@@ -8,13 +8,14 @@ bls/src/secret_key.rs:82-86 `sign`) re-designed for the accelerator:
     N (message, signature, pubkey) triples are checked with batched Miller
     loops, a log-depth Fp12 product tree, and ONE shared final
     exponentiation:  e(g1, Σ rᵢ·sigᵢ) == ∏ e(rᵢ·pkᵢ, H(mᵢ)).
-  - `grouped_multi_verify_kernel` — triples grouped by message, so Miller
-    loops collapse from N to the number of distinct messages.
-  - `aggregate_fast_verify_kernel` — the gossip-attestation firehose shape:
-    M attestations × K committee members; pubkey aggregation is a log-depth
-    complete-addition tree over the k-major flat batch, then the RLC check.
-  - `batch_sign_kernel` / `batch_pubkey_kernel` — G2/G1 fixed-base scalar
-    multiplications for multi-validator signing (signer/src/signer.rs:173-229).
+  - `grouped_multi_verify_msm_kernel` — triples grouped by message, so
+    Miller loops collapse from N to the number of distinct messages.
+  - `aggregate_fast_verify_msm_kernel` (and `_idx`, over the resident
+    registry) — the gossip-attestation firehose shape: M attestations × K
+    committee members; pubkey aggregation is a log-depth complete-addition
+    tree over the k-major flat batch, then the RLC check.
+  - `batch_sign_kernel` — G2 scalar multiplications for multi-validator
+    signing (signer/src/signer.rs:173-229).
 
 Kernel boundary: hosts speak the REST FORMAT — numpy arrays with a trailing
 limb axis (pk (N, 26), G2 coords (N, 2, 26), bool masks (N,), scalar bit
@@ -122,7 +123,7 @@ def rlc_bits_host(pairs, pad_to: int) -> np.ndarray:
 
 def sign_bits_host(scalars, pad_to: int):
     """Secret scalars → GLV-decomposed ((pad_to, 256) bits, (pad_to, 2) neg
-    masks) for batch_sign_kernel / batch_pubkey_kernel."""
+    masks) for batch_sign_kernel."""
     decs = [decompose_glv(int(k)) for k in scalars]
     decs += [(1, 1, 0, 1)] * (pad_to - len(decs))
     lo = C.scalars_to_bits_msb([d[0] for d in decs], 128)
@@ -316,40 +317,6 @@ def rlc_partition_verify_kernel(
     return ok
 
 
-def grouped_multi_verify_kernel(
-    pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, msg_x, msg_y, msg_inf, r_bits
-):
-    """RLC batch verify with triples GROUPED BY MESSAGE: pk/sig/r have
-    rest-format shape (M, K, …) — M distinct messages × up to K triples each
-    (padding slots all-infinity) — msg has shape (M, …).
-
-    Algebraic identity:  ∏ᵢ e(rᵢ·pkᵢ, H(mᵢ)) = ∏ⱼ e(Σᵢ∈ⱼ rᵢ·pkᵢ, H(mⱼ)),
-    so only M (+1) Miller loops run instead of N (+1) while every triple
-    keeps its own 64-bit randomizer (soundness unchanged — cancellation
-    inside a group needs a collision against rᵢ). This is the shape of the
-    real workloads: gossip batches and block replays carry few distinct
-    AttestationData values per many signatures (BASELINE configs 2–4).
-    """
-    m, k = pk_inf.shape
-    pk = _g1_in(_flat_km(pk_x, m, k), _flat_km(pk_y, m, k))
-    sig = _g2_in(_flat_km(sig_x, m, k), _flat_km(sig_y, m, k))
-    msg = _g2_in(msg_x, msg_y)
-    pk_inf_f = _flat_km(pk_inf, m, k)
-    sig_inf_f = _flat_km(sig_inf, m, k)
-    msg_inf = jnp.asarray(msg_inf)
-    lo, hi = _rlc_ladders(_flat_km(r_bits, m, k))
-    rpk = C.scalar_mul_glv(
-        pk[0], pk[1], pk_inf_f, lo, hi, _g1_endo(m * k), C.FP_OPS
-    )
-    rsig = C.scalar_mul_glv(
-        sig[0], sig[1], sig_inf_f, lo, hi, _g2_endo(m * k), C.FP2_OPS
-    )
-    sig_acc = C.sum_points(rsig, C.FP2_OPS)
-    gpk = C.sum_points_grouped(rpk, k, C.FP_OPS)  # (M,) Jacobian, m-order
-    pair_inf = L.is_zero_val(gpk[2]) | msg_inf
-    return _rlc_pairing_check(gpk, pair_inf, msg[0], msg[1], sig_acc)
-
-
 # --- MSM window autotune table ----------------------------------------------
 #
 # A measured calibration sweep (tools.shapes --autotune → tpu/autotune.py)
@@ -434,8 +401,8 @@ def pick_msm_window(n_points: int, n_groups: int = 1) -> int:
     the model; lookup keys quantize up to powers of two, as the dispatch
     plane's buckets do, so a table built from the calibration sweep
     covers every shape the warmed kernels can see. The key has no upper
-    bound: kernel-level callers (bench.py) plan shapes above MAX_BUCKET,
-    which simply miss the table."""
+    bound: a kernel-level caller may plan shapes above MAX_BUCKET, which
+    simply miss the table."""
     table = load_msm_tuning()
     if table:
         key = "%d:%d" % (
@@ -497,9 +464,18 @@ def grouped_multi_verify_msm_kernel(
     """Message-grouped RLC batch verify with BOTH scalar planes as device
     Pippenger MSMs (msm.py) instead of per-signature ladders: per-group
     Σᵢ∈ⱼ rᵢ·pkᵢ (M-group MSM) and the global Σᵢ rᵢ·sigᵢ (1-group MSM).
-    Point layouts as grouped_multi_verify_kernel; the RLC scalars travel as
+    Triples arrive GROUPED BY MESSAGE: pk/sig have rest-format shape
+    (M, K, …) — M distinct messages × up to K triples each (padding slots
+    all-infinity) — msg has shape (M, …); the RLC scalars travel as
     MsmPlan index arrays (flat k-major point order, group of point f =
     f mod M) built by the host, which draws the randomizers.
+
+    Algebraic identity:  ∏ᵢ e(rᵢ·pkᵢ, H(mᵢ)) = ∏ⱼ e(Σᵢ∈ⱼ rᵢ·pkᵢ, H(mⱼ)),
+    so only M (+1) Miller loops run instead of N (+1) while every triple
+    keeps its own 64-bit randomizer (soundness unchanged — cancellation
+    inside a group needs a collision against rᵢ). This is the shape of the
+    real workloads: gossip batches and block replays carry few distinct
+    AttestationData values per many signatures (BASELINE configs 2–4).
 
     Replaces the ladder plane per VERDICT r3 #1; matches blst's
     Pippenger-backed multi_verify (bls/src/signature.rs:96-129)."""
@@ -507,47 +483,6 @@ def grouped_multi_verify_msm_kernel(
     return _grouped_msm_verify_tail(
         _g1_in(_flat_km(pk_x, m, k), _flat_km(pk_y, m, k)),
         _g2_in(_flat_km(sig_x, m, k), _flat_km(sig_y, m, k)),
-        _g2_in(msg_x, msg_y),
-        jnp.asarray(_flat_km(pk_inf, m, k)),
-        jnp.asarray(_flat_km(sig_inf, m, k)),
-        jnp.asarray(msg_inf), m, k,
-        g1_pidx, g1_valid, g1_flush, g1_gidx, g1_gvalid,
-        g2_pidx, g2_valid, g2_flush, g2_gidx, g2_gvalid,
-        g1_windows=g1_windows, g1_wbits=g1_wbits,
-        g2_windows=g2_windows, g2_wbits=g2_wbits,
-        check_subgroup=check_subgroup,
-    )
-
-
-def _g2_packed_in(sig_words, m: int, k: int):
-    """(M, K, 4, 13) uint32 packed canonical coords → k-major flat Fp2
-    (x, y) limb-list pairs in Montgomery form (limbs.py packed transfer
-    format; ONE fused montmul lifts all four coordinates)."""
-    w = _flat_km(sig_words, m, k)  # (KM, 4, 13)
-    canon = L.unpack_words(w)  # (26, KM, 4)
-    mont = L.to_mont_dev(canon)
-    x = (mont[:, :, 0], mont[:, :, 1])
-    y = (mont[:, :, 2], mont[:, :, 3])
-    return x, y
-
-
-def grouped_multi_verify_msm_packed_kernel(
-    pk_x, pk_y, pk_inf, sig_words, sig_inf, msg_x, msg_y, msg_inf,
-    g1_pidx, g1_valid, g1_flush, g1_gidx, g1_gvalid,
-    g2_pidx, g2_valid, g2_flush, g2_gidx, g2_gvalid,
-    g1_windows: int, g1_wbits: int, g2_windows: int, g2_wbits: int,
-    check_subgroup: int = 0,
-):
-    """grouped_multi_verify_msm_kernel with the SIGNATURE plane arriving
-    as packed canonical words ((M, K, 4, 13) uint32 — 52 B/coord instead
-    of 104 B): signatures are the one per-batch upload a production
-    verifier cannot avoid, and host→device transfer serializes with
-    execution on the per-batch clock, so halving sig bytes cuts batch
-    latency directly (bench.py pipeline notes)."""
-    m, k = pk_inf.shape
-    return _grouped_msm_verify_tail(
-        _g1_in(_flat_km(pk_x, m, k), _flat_km(pk_y, m, k)),
-        _g2_packed_in(sig_words, m, k),
         _g2_in(msg_x, msg_y),
         jnp.asarray(_flat_km(pk_inf, m, k)),
         jnp.asarray(_flat_km(sig_inf, m, k)),
@@ -640,53 +575,6 @@ def multi_verify_msm_idx_kernel(
     )
 
 
-def aggregate_fast_verify_kernel(
-    mem_x, mem_y, mem_inf, slot_pad,
-    sig_x, sig_y, sig_inf, msg_x, msg_y, msg_inf, r_bits,
-):
-    """Firehose kernel: M aggregates (gossip attestations), each signed by up
-    to K committee members over one message. Rest-format shapes: mem_x/mem_y
-    (M, K, L) affine member pubkeys with mem_inf (M, K) padding mask;
-    slot_pad (M,) marks batch-padding slots; sig/msg per aggregate as in
-    multi_verify_kernel; r_bits (M, 64).
-
-    Computes pkᵢ = Σₖ memᵢₖ (complete-add tree over the k-major flat batch),
-    then the RLC check. A REAL slot whose members sum to the identity is
-    rejected (matching the anchor's fast_aggregate_verify: an adversary
-    could pair a [P, −P] committee with an infinity signature to fake
-    participation); padding slots stay algebraically neutral.
-    Reference shape: attestation_batch_triples + MultiVerifier::finish
-    (p2p/src/attestation_verifier.rs:431-457, helper_functions verifier.rs:302).
-    """
-    m, k = mem_inf.shape
-    mem = _g1_in(_flat_km(mem_x, m, k), _flat_km(mem_y, m, k))
-    mem_inf_f = _flat_km(mem_inf, m, k)
-    one = C.FP_OPS.one_like(mem[0])
-    zero = C.FP_OPS.zeros_like(mem[0])
-    mem_jac = (
-        C.FP_OPS.select(mem_inf_f, one, mem[0]),
-        C.FP_OPS.select(mem_inf_f, one, mem[1]),
-        C.FP_OPS.select(mem_inf_f, zero, one),
-    )
-    agg_pk = C.sum_points_grouped(mem_jac, k, C.FP_OPS)  # (M,) Jacobian G1
-    agg_inf = L.is_zero_val(agg_pk[2])
-    slot_pad = jnp.asarray(slot_pad)
-    forged = jnp.any(jnp.logical_and(jnp.logical_not(slot_pad), agg_inf))
-    sig = _g2_in(sig_x, sig_y)
-    msg = _g2_in(msg_x, msg_y)
-    sig_inf = jnp.asarray(sig_inf)
-    msg_inf = jnp.asarray(msg_inf)
-    lo, hi = _rlc_ladders(r_bits)
-    rpk = C.scalar_mul_jac_glv(agg_pk, agg_inf, lo, hi, _g1_endo(m), C.FP_OPS)
-    rsig = C.scalar_mul_glv(
-        sig[0], sig[1], sig_inf, lo, hi, _g2_endo(m), C.FP2_OPS
-    )
-    sig_acc = C.sum_points(rsig, C.FP2_OPS)
-    pair_inf = agg_inf | msg_inf
-    ok = _rlc_pairing_check(rpk, pair_inf, msg[0], msg[1], sig_acc)
-    return jnp.logical_and(ok, jnp.logical_not(forged))
-
-
 def _aggregate_msm_verify_tail(
     mem, mem_inf_f, m, k, slot_pad,
     sig, sig_inf, msg_x, msg_y, msg_inf, r_bits,
@@ -698,7 +586,16 @@ def _aggregate_msm_verify_tail(
     MSM, then the RLC pairing check. `mem` arrives as a k-major flat
     limb-list pair — built either from uploaded coords or a registry
     gather; `sig` as a split Fp2 (x, y) pair — from uploaded coords or
-    the on-device decompressor."""
+    the on-device decompressor.
+
+    Computes pkᵢ = Σₖ memᵢₖ (complete-add tree over the k-major flat
+    batch), then the RLC check. A REAL slot whose members sum to the
+    identity is rejected (matching the anchor's fast_aggregate_verify: an
+    adversary could pair a [P, −P] committee with an infinity signature to
+    fake participation); padding slots stay algebraically neutral.
+    Reference shape: attestation_batch_triples + MultiVerifier::finish
+    (p2p/src/attestation_verifier.rs:431-457, helper_functions verifier.rs:302).
+    """
     one = C.FP_OPS.one_like(mem[0])
     zero = C.FP_OPS.zeros_like(mem[0])
     mem_jac = (
@@ -737,10 +634,13 @@ def aggregate_fast_verify_msm_kernel(
     g2_pidx, g2_valid, g2_flush, g2_gidx, g2_gvalid,
     g2_windows: int, g2_wbits: int, check_subgroup: int = 0,
 ):
-    """Firehose kernel with the Σ rᵢ·sigᵢ side as a device MSM. The G1 side
-    keeps the per-aggregate Jacobian GLV ladder — each rᵢ·(Σ memᵢₖ) is
-    needed individually for its Miller loop. Layouts and rejection
-    semantics identical to aggregate_fast_verify_kernel."""
+    """Firehose kernel: M aggregates (gossip attestations), each signed by
+    up to K committee members over one message, the Σ rᵢ·sigᵢ side as a
+    device MSM. The G1 side keeps the per-aggregate Jacobian GLV ladder —
+    each rᵢ·(Σ memᵢₖ) is needed individually for its Miller loop.
+    Rest-format shapes: mem_x/mem_y (M, K, L) affine member pubkeys with
+    mem_inf (M, K) padding mask; slot_pad (M,) marks batch-padding slots;
+    sig/msg per aggregate as in multi_verify_kernel; r_bits (M, 64)."""
     m, k = mem_inf.shape
     mem = _g1_in(_flat_km(mem_x, m, k), _flat_km(mem_y, m, k))
     return _aggregate_msm_verify_tail(
@@ -940,48 +840,6 @@ def g2_subgroup_check_kernel(sx, sy, s_inf, x_bits):
     return _psi_ladder_check(
         _g2_in(sx, sy), jnp.asarray(s_inf), jnp.asarray(x_bits)
     )
-
-
-def g1_normalize_kernel(X, Y, Z):
-    """Batched Jacobian → affine on device (one Fermat inversion scan for
-    the whole batch): (x, y, inf) in rest format. Infinity rows return
-    garbage coords under a True mask."""
-    Xl, Yl, Zl = L.split(jnp.asarray(X)), L.split(jnp.asarray(Y)), L.split(jnp.asarray(Z))
-    zinv = L.inv_mod(Zl)
-    zinv2 = L.montmul(zinv, zinv)
-    zinv3 = L.montmul(zinv2, zinv)
-    x = L.montmul(Xl, zinv2)
-    y = L.montmul(Yl, zinv3)
-    return L.merge(x), L.merge(y), L.is_zero_val(Zl)
-
-
-def g2_normalize_kernel(X, Y, Z):
-    Xl, Yl, Zl = (F.fp2_split(jnp.asarray(c)) for c in (X, Y, Z))
-    zinv = F.fp2_inv(Zl)
-    zinv2 = F.fp2_sq(zinv)
-    zinv3 = F.fp2_mul(zinv2, zinv)
-    x = F.fp2_mul(Xl, zinv2)
-    y = F.fp2_mul(Yl, zinv3)
-    return F.fp2_merge(x), F.fp2_merge(y), F.fp2_is_zero(Zl)
-
-
-def batch_pubkey_kernel(sk_bits, sk_neg):
-    """N public keys: [skᵢ]·g1. sk_bits (N, 256) packed GLV halves with
-    sk_neg (N, 2) sign masks (sign_bits_host); rest-format out."""
-    gx, gy, _ = C.g1_point_to_dev(G1)
-    n = sk_bits.shape[0]
-    qx = L.const_fp([int(d) for d in gx], (n,))
-    qy = L.const_fp([int(d) for d in gy], (n,))
-    q_inf = jnp.zeros((n,), bool)
-    b = jnp.asarray(sk_bits)
-    neg = jnp.asarray(sk_neg)
-    X, Y, Z = C.scalar_mul_glv(
-        qx, qy, q_inf,
-        jnp.transpose(b[:, :128]), jnp.transpose(b[:, 128:]),
-        _g1_endo(n), C.FP_OPS,
-        neg_lo=neg[:, 0], neg_hi=neg[:, 1],
-    )
-    return L.merge(X), L.merge(Y), L.merge(Z)
 
 
 def g2_aggregate_kernel(sig_x, sig_y, sig_inf, group_tag):
@@ -1765,7 +1623,7 @@ class TpuBlsBackend:
         identical transfer implicitly). Device-resident operands — the
         pubkey registry arrays — must bypass this seam: the per-kernel
         `device_upload_bytes_total` counter is the accounting that
-        tools/check_no_per_batch_upload.py audits. No-op when unobserved."""
+        the lint rule no-per-batch-upload audits. No-op when unobserved."""
         if not self._observed():
             return args
         nbytes = sum(int(getattr(a, "nbytes", 0)) for a in args)
@@ -2349,7 +2207,7 @@ class TpuBlsBackend:
             g2_plan = self._g2_plan(pairs, bm, sig_inf)
         # registry arrays are already device-resident: they are passed to
         # the kernel directly, NOT through _upload, so the per-batch
-        # upload accounting stays honest (check_no_per_batch_upload.py)
+        # upload accounting stays honest (lint rule no-per-batch-upload)
         args = self._upload((
             mem_idx, mem_inf, slot_pad, sig_x, sig_y, sig_inf,
             msg_x, msg_y, msg_inf, r_bits, *g2_plan.arrays,
@@ -3006,16 +2864,10 @@ __all__ = [
     "g1_aggregate_kernel",
     "g2_aggregate_groups",
     "g1_aggregate_groups",
-    "grouped_multi_verify_kernel",
     "grouped_multi_verify_msm_kernel",
-    "grouped_multi_verify_msm_packed_kernel",
-    "aggregate_fast_verify_kernel",
     "aggregate_fast_verify_msm_kernel",
     "aggregate_fast_verify_msm_idx_kernel",
     "batch_sign_kernel",
-    "batch_pubkey_kernel",
-    "g1_normalize_kernel",
-    "g2_normalize_kernel",
     "make_sharded_multi_verify",
     "make_sharded_multi_verify_msm",
     "sharded_multi_verify",

@@ -548,46 +548,6 @@ def g2_points_to_dev(points):
     return limbs[:, 0], limbs[:, 1], inf
 
 
-def g2_points_to_packed(points):
-    """Anchor G2 points → ((N, 4, 13) uint32 packed canonical affine
-    coords [x.c0, x.c1, y.c0, y.c1], (N,) inf). Half the bytes of the
-    Montgomery limb REST format — for transfer-bound upload paths; the
-    device unpacks (limbs.unpack_words + one montmul by R²)."""
-    n = len(points)
-    inf = np.zeros(n, dtype=bool)
-    norms = []
-    for i, pt in enumerate(points):
-        if pt.is_infinity():
-            inf[i] = True
-            norms.append(0)
-        else:
-            z = pt.z
-            norms.append((z.c0.n * z.c0.n + z.c1.n * z.c1.n) % _P)
-    ninv = _batch_inv_mod_p(norms)
-    coords = []
-    for pt, nv in zip(points, ninv):
-        if nv == 0:
-            coords.extend((0, 0, 0, 0))
-            continue
-        z = pt.z
-        zi0 = z.c0.n * nv % _P
-        zi1 = (-z.c1.n) % _P * nv % _P
-        zi2_0 = (zi0 * zi0 - zi1 * zi1) % _P
-        zi2_1 = 2 * zi0 * zi1 % _P
-        zi3_0 = (zi2_0 * zi0 - zi2_1 * zi1) % _P
-        zi3_1 = (zi2_0 * zi1 + zi2_1 * zi0) % _P
-        x0, x1 = pt.x.c0.n, pt.x.c1.n
-        y0, y1 = pt.y.c0.n, pt.y.c1.n
-        coords.extend((
-            (x0 * zi2_0 - x1 * zi2_1) % _P,
-            (x0 * zi2_1 + x1 * zi2_0) % _P,
-            (y0 * zi3_0 - y1 * zi3_1) % _P,
-            (y0 * zi3_1 + y1 * zi3_0) % _P,
-        ))
-    packed = L.pack_fp_words_host(coords).reshape(n, 4, L.NWORDS)
-    return packed, inf
-
-
 def scalar_mul_glv(
     qx, qy, q_inf, bits_lo, bits_hi, endo, ops: FieldOps,
     neg_lo=None, neg_hi=None,

@@ -6,8 +6,8 @@ LEADING axis, Montgomery form (value·R mod p, R = 2³⁹⁰). Digits are redund
 and signed: |digit| ≤ LMAX = 2¹⁵ + 256; values are only canonical modulo p at
 explicit canonicalization points (equality tests, host export).
 
-Why the limb axis is LEADING (three designs were measured on v5e — see
-tools/kernel_microbench.py):
+Why the limb axis is LEADING (three designs were measured on v5e, by a
+microbenchmark that predates PERF_LEDGER.jsonl and is gone):
   - Trailing limb axis (batch, 26): the minor axis maps to the 128 vector
     lanes, so 26/128 lanes do work AND every shifted-column accumulation in
     the Montgomery product is a cross-lane concatenate (a relayout of the
@@ -267,29 +267,16 @@ def montsq(a) -> jnp.ndarray:
 
 # --- packed transfer format -------------------------------------------------
 #
-# Canonical Fp values travel host→device as 13 little-endian uint32 words
+# Canonical Fp values reach the device as 13 little-endian uint32 words
 # (the 13th is always zero padding) — 52 bytes instead of the 104-byte
-# int32 limb form. The device unpacks to 15-bit limbs with static
-# shifts/gathers and one montmul by R² lifts the batch into Montgomery
-# form. Halving upload bytes matters because host→device transfers
-# serialize with execution on the per-batch clock (bench.py pipeline).
+# int32 limb form; the compressed-ingest path (curve.py
+# _bytes_to_canonical) builds them from 48-byte wire payloads. The device
+# unpacks to 15-bit limbs with static shifts/gathers and one montmul by
+# R² lifts the batch into Montgomery form.
 
-NWORDS = 13
 _UNPACK_J = np.array([(15 * i) >> 5 for i in range(NLIMBS)], np.int32)
 _UNPACK_OFF = np.array([(15 * i) & 31 for i in range(NLIMBS)], np.int32)
 R2_DIGITS = [int(x) for x in int_to_limbs(R2)]
-
-
-def pack_fp_words_host(values) -> np.ndarray:
-    """Canonical ints → (N, 13) uint32 little-endian words."""
-    n = len(values)
-    out = np.zeros((n, NWORDS), np.uint32)
-    for i, v in enumerate(values):
-        v = int(v)
-        assert 0 <= v < (1 << 384)
-        for j in range(12):
-            out[i, j] = (v >> (32 * j)) & 0xFFFFFFFF
-    return out
 
 
 def unpack_words(w) -> jnp.ndarray:

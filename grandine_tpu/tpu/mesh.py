@@ -20,8 +20,8 @@ exists so that
 The mesh is 1-D on purpose. The workload's only cross-chip reduction is
 the pairing-product all-gather (a few KB per chip — see
 `tpu/bls.py make_sharded_multi_verify`); a second mesh axis buys nothing
-until single-axis scaling saturates ICI, which the `bench.py --devices`
-sweep exists to detect.
+until single-axis scaling saturates ICI (no chip reading yet: ROADMAP
+R-B5).
 """
 
 from __future__ import annotations
@@ -120,8 +120,8 @@ class VerifyMesh:
         On the CPU platform the visible device count comes from
         `XLA_FLAGS=--xla_force_host_platform_device_count=N`, which XLA
         parses once per process BEFORE the first backend call — callers
-        wanting an N-device CPU mesh must set it pre-import (bench.py's
-        `--devices` sweep runs each count in a fresh subprocess for
+        wanting an N-device CPU mesh must set it pre-import
+        (`__graft_entry__.dryrun_multichip` asks for a fresh process for
         exactly this reason).
         """
         import jax

@@ -51,8 +51,8 @@ from grandine_tpu.tpu import curve as C
 #: sequential scan steps (S = ceil(2NW / T)), BUT the montmul inner scan
 #: carries 27 column accumulators of width (products × T) that must live
 #: in VMEM: at T=32768 with ~8 stacked products that carry is ~28 MB and
-#: SPILLS (measured 5× slower end-to-end on v5e via
-#: device_residency_probe variant C: 391 ms at 8192 vs 2100 ms at 32768).
+#: SPILLS (measured 5× slower end-to-end on v5e: 391 ms at 8192 vs
+#: 2100 ms at 32768, by a probe script that predates PERF_LEDGER.jsonl).
 #: 8192 keeps the carry ~5 MB — comfortably resident.
 MSM_LANES = int(os.environ.get("GT_MSM_LANES", "8192"))
 

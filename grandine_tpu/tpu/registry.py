@@ -166,7 +166,7 @@ class DevicePubkeyRegistry:
         if self.metrics is not None:
             # labeled apart from the per-batch verify kernels: registry
             # uploads are amortized over the set's lifetime, not charged
-            # to any batch (tools/check_no_per_batch_upload.py relies on
+            # to any batch (the lint rule no-per-batch-upload relies on
             # this separation)
             self.metrics.device_upload_bytes.labels("pubkey_registry").inc(
                 nbytes

@@ -23,14 +23,14 @@ jax.config.update("jax_platforms", "cpu")
 
 # Persistent XLA compilation cache (runtime/warmup.py jit_cache_dir():
 # JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache — the one directory
-# bench.py, the node and chip_smoke.py share; CPU and TPU entries coexist
-# under different keys).
+# the node, the benchmark and chip_smoke.py share; CPU and TPU entries
+# coexist under different keys).
 import sys as _sys  # noqa: E402
 
 _sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import bench  # noqa: E402
+from grandine_tpu.runtime.warmup import enable_persistent_cache  # noqa: E402
 
-bench._enable_compilation_cache()
+enable_persistent_cache()
 
 import pytest  # noqa: E402
 

@@ -142,6 +142,51 @@ def test_unfused_backend_keeps_two_pass():
         s.stop()
 
 
+@pytest.fixture(scope="module")
+def soak_items():
+    return _real_items(4, tag=b"soak-good"), _real_items(
+        2, valid=False, tag=b"soak-bad"
+    )
+
+
+@pytest.mark.parametrize(
+    "lane", [ln.name for ln in vs.DEFAULT_LANES if ln.scheme == "bls"]
+)
+def test_fused_lane_soak_no_subgroup_dispatch_no_recompile(
+    lane, soak_items, monkeypatch
+):
+    """Every BLS lane of the node's own lane table, through the fused
+    path with the shape ledger sealed: valid and forged jobs settle to
+    their exact verdicts with ZERO standalone subgroup dispatches, zero
+    post-warm-up recompiles and nothing but the fused kernel label on
+    the flight timeline."""
+    from grandine_tpu.runtime.flight import BATCH
+    from grandine_tpu.tpu import bls as B
+
+    good, bad = soak_items
+    truth = {it.message: True for it in good}
+    truth.update({it.message: False for it in bad})
+    monkeypatch.setattr(vs, "host_check_item", lambda it: truth[it.message])
+    backend = _CountingBackend(fused=True, truth=truth)
+    B.reset_shape_tracking()
+    B.declare_warmup_complete()
+    s = VerifyScheduler(backend=backend, use_device=True, metrics=Metrics())
+    try:
+        t_good = [s.submit(lane, good[i:i + 2]) for i in (0, 2)]
+        t_bad = s.submit(lane, bad)
+        s.flush(60.0)
+        assert all(t.done() and t.ok for t in t_good)
+        assert t_bad.done() and t_bad.ok is False and not t_bad.dropped
+        assert backend.verify_batches  # the device seam was driven
+        assert backend.subgroup_batches == []
+        assert B.post_warmup_recompiles() == 0
+        labels = {r.kernel for r in s.flight.snapshot(kind=BATCH)}
+        assert labels == {"fast_aggregate_fused"}
+    finally:
+        s.stop()
+        B.reset_shape_tracking()
+
+
 # --------------------------------------------------- cross-lane merging
 
 
@@ -396,7 +441,7 @@ def test_fused_aggregate_and_partition_differential(fused_backend,
     "n_points,n_groups", [(32768, 256), (1 << 20, 1), (16384, 256)]
 )
 def test_pick_msm_window_above_max_bucket(n_points, n_groups):
-    """bench.py's default shape (32,768 x 256) crashed here: the table
+    """A shape above MAX_BUCKET (32,768 x 256) crashed here: the table
     key was quantized with the dispatch plane's `_bucket`, which raises
     above MAX_BUCKET, as soon as ANY table was loaded."""
     from grandine_tpu.tpu import bls as B

@@ -245,7 +245,7 @@ def test_rule_registered_in_default_suite():
     rules = {r.name: r for r in all_rules()}
     assert "limb-range" in rules
     rule = rules["limb-range"]
-    assert rule.kind == "ast"  # rides the default (and bench-preflight) run
+    assert rule.kind == "ast"  # rides the default run
     assert tuple(rule.default_paths) == tuple(ranges.DEFAULT_FILES)
 
 

@@ -36,21 +36,10 @@ def lint(tmp_path, source: str, rule: str, *extra: str) -> int:
 
 def test_lint_clean_on_repo():
     """`python -m tools.lint` exits 0 on the repo: every finding fixed,
-    suppressed with a reason, or baselined. This is the test-suite
-    wiring that replaced the direct tools/check_*.py invocations."""
+    suppressed with a reason, or baselined."""
     proc = subprocess.run(
         [sys.executable, "-m", "tools.lint"], cwd=REPO,
         capture_output=True, text=True,
-    )
-    assert proc.returncode == 0, proc.stderr + proc.stdout
-
-
-def test_guard_shim_still_works():
-    """tools/check_no_inline_gossip_verify.py stays a working entry
-    point (CI wiring calls it directly)."""
-    proc = subprocess.run(
-        [sys.executable, "tools/check_no_inline_gossip_verify.py"],
-        cwd=REPO, capture_output=True, text=True,
     )
     assert proc.returncode == 0, proc.stderr + proc.stdout
 

@@ -260,6 +260,52 @@ def test_bisection_admits_good_items_of_poisoned_batch():
         s.stop()
 
 
+def test_open_merge_window_halves_two_lane_dispatches():
+    """Two lanes with the same workload in the same compile bucket: with
+    the merge window closed every lane batch is a device dispatch of its
+    own, with it open each dispatch carries a batch of BOTH lanes — half
+    the dispatches, every verdict unchanged."""
+    key = _interop_keys(0)
+    rounds = 4
+    msgs = [bytes([0x40 + i]) * 32 for i in range(2 * rounds)]
+    items = [
+        VerifyItem(m, key.sign(m).to_bytes(), public_keys=(key.public_key(),))
+        for m in msgs
+    ]
+    dispatches = {}
+    for window_s in (0.0, 5.0):
+        backend = _FakeAsyncBackend(truth={m: True for m in msgs})
+        # jobs of 2 items under max_batch 2: a lane batch is one job, so
+        # a merged pair (4 items) and the dispatch count depend on the
+        # window alone
+        lanes = (
+            LaneConfig("attestation", Priority.LOW, 2, 0.05, 4096, False),
+            LaneConfig("sync_message", Priority.LOW, 2, 0.08, 4096, False),
+        )
+        s = VerifyScheduler(
+            backend=backend, lanes=lanes, use_device=True,
+            merge_window_s=window_s,
+        )
+        try:
+            # the condition's lock is re-entrant: holding it parks the
+            # dispatcher until both lanes hold their whole workload
+            with s._cond:
+                tickets = [
+                    s.submit(lane, items[2 * j:2 * j + 2])
+                    for j in range(rounds)
+                    for lane in ("attestation", "sync_message")
+                ]
+            s.flush(60.0)
+            assert all(t.done() and t.ok for t in tickets)
+            dispatches[window_s] = list(backend.batches)
+            merged = sum(st["merged"] for st in s.stats.values())
+            assert merged == (2 * rounds if window_s else 0)
+        finally:
+            s.stop()
+    assert dispatches[0.0] == [2] * (2 * rounds)
+    assert dispatches[5.0] == [4] * rounds
+
+
 # --------------------------------------------------- fault degradation
 
 

@@ -90,10 +90,9 @@ def test_sharded_matches_single_device(mesh, sharded_fn, valid_batch):
 def _grouped_batch(m=8, k=16, n_real=40):
     """(M, K) grouped batch with n_real valid triples (k-major fill),
     padding all-infinity. Returns grouped arrays + kmajor (r_lo, r_hi)."""
-    import bench as B
-
-    flat = B.build_batch(n_real, m)
-    # place the n_real triples into the (m, k) grid in k-major order
+    from grandine_tpu.crypto import bls as A
+    from grandine_tpu.crypto.hash_to_curve import hash_to_g2
+    from grandine_tpu.tpu import curve as C
     from grandine_tpu.tpu import limbs as L
 
     pk_x = np.zeros((m, k, L.NLIMBS), np.int32)
@@ -105,17 +104,19 @@ def _grouped_batch(m=8, k=16, n_real=40):
     msg_x = np.zeros((m, 2, L.NLIMBS), np.int32)
     msg_y = np.zeros((m, 2, L.NLIMBS), np.int32)
     msg_inf = np.ones((m,), bool)
-    (fpk_x, fpk_y, fpk_inf, fsig_x, fsig_y, fsig_inf,
-     fmsg_x, fmsg_y, fmsg_inf) = flat
+    msgs = [b"sharded-msm-%d" % j for j in range(m)]
+    for j in range(min(m, n_real)):
+        msg_x[j], msg_y[j], msg_inf[j] = C.g2_point_to_dev(hash_to_g2(msgs[j]))
+    # triple i signs message i mod m: k-major order over the (m, k) grid
     for i in range(n_real):
         j, kk = i % m, i // m
-        pk_x[j, kk], pk_y[j, kk], pk_inf[j, kk] = (
-            fpk_x[i], fpk_y[i], fpk_inf[i]
+        sk = A.SecretKey.keygen(bytes([i + 1]) * 32)
+        pk_x[j, kk], pk_y[j, kk], pk_inf[j, kk] = C.g1_point_to_dev(
+            sk.public_key().point
         )
-        sig_x[j, kk], sig_y[j, kk], sig_inf[j, kk] = (
-            fsig_x[i], fsig_y[i], fsig_inf[i]
+        sig_x[j, kk], sig_y[j, kk], sig_inf[j, kk] = C.g2_point_to_dev(
+            sk.sign(msgs[j]).point
         )
-        msg_x[j], msg_y[j], msg_inf[j] = fmsg_x[i], fmsg_y[i], fmsg_inf[i]
     rng = np.random.default_rng(7)
     r_lo = rng.integers(1, 1 << 32, size=m * k, dtype=np.uint64)
     r_hi = rng.integers(0, 1 << 32, size=m * k, dtype=np.uint64)

@@ -1,5 +1,5 @@
 """Grouped (message-deduplicated) batch verification: the
-grouped_multi_verify_kernel and the backend's automatic grouping path.
+grouped_multi_verify_msm_kernel and the backend's automatic grouping path.
 
 The grouping identity ∏ᵢ e(rᵢ·pkᵢ, H(mᵢ)) = ∏ⱼ e(Σᵢ∈ⱼ rᵢ·pkᵢ, H(mⱼ))
 collapses Miller loops to the distinct-message count — this suite pins its

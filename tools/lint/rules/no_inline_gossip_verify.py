@@ -1,6 +1,5 @@
 """Rule: gossip handlers route signature checks through the verify
-scheduler, never inline (absorbed from
-tools/check_no_inline_gossip_verify.py).
+scheduler, never inline.
 
 No `_on_gossip_*` method may call `.verify(...)` /
 `.fast_aggregate_verify(...)` / `.aggregate_verify(...)` or reference

@@ -1,5 +1,5 @@
 """Runtime rule: the warm-registry verify path must not re-upload the
-pubkey plane per batch (absorbed from tools/check_no_per_batch_upload.py).
+pubkey plane per batch.
 
 Unlike the AST rules this one EXECUTES the backend: it builds a small
 device pubkey registry, runs the indexed verify path twice, and audits
@@ -10,7 +10,7 @@ kernels and needs a working JAX, so it only runs under
 in a process of its own: it takes whatever device JAX finds (the CPU,
 unless the environment says otherwise), and a chip belongs to one process
 at a time — never import it into a process that later needs the chip
-(chip_smoke.py and the bench parents do not).
+(chip_smoke.py does not).
 
 Checks:
   1. The second warm verify uploads zero registry bytes (identity hit).
@@ -56,9 +56,9 @@ class NoPerBatchUploadRule(Rule):
         if ctx.root not in sys.path:
             sys.path.insert(0, ctx.root)
 
-        import bench
+        from grandine_tpu.runtime.warmup import enable_persistent_cache
 
-        bench._enable_compilation_cache()  # pairing compiles are slow cold
+        enable_persistent_cache()  # pairing compiles are slow cold
 
         from grandine_tpu.crypto import bls as A
         from grandine_tpu.metrics import Metrics

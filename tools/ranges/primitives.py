@@ -492,13 +492,13 @@ def make_field_transfers(fp):
         return j.with_layout(shape, ax)
 
     def t_unpack_words(w):
-        # Input assumption: packed words hold a value < 2^384
-        # (pack_fp_words_host asserts it; wire payloads are masked to
-        # 381 bits before reaching this point).
+        # Input assumption: packed words hold a value < 2^384 (twelve
+        # uint32 words and a zero thirteenth; wire payloads are masked
+        # to 381 bits before reaching this point).
         engine.CURRENT.recorder.assume(
             f"unpack_words ({fp.name}): packed uint32 words hold a "
-            f"non-negative value < 2^384 (asserted by "
-            f"pack_fp_words_host; wire payloads are masked to 381 bits)"
+            f"non-negative value < 2^384 (twelve words and a zero "
+            f"thirteenth; wire payloads are masked to 381 bits)"
         )
         batch = _shape_tail(w)
         hi = Fraction((1 << 384) - 1, fp.p)

@@ -385,7 +385,7 @@ ROOTS = (
 COVER_EXEMPT = {
     "limbs": {
         "int_to_limbs", "limbs_to_int", "to_mont", "from_mont",
-        "merge_np", "pack_fp_words_host",
+        "merge_np",
     },
     "field": {
         "fq2_to_dev", "fq6_to_dev", "fq12_to_dev", "fp2_merge_np",
@@ -396,7 +396,7 @@ COVER_EXEMPT = {
         "scalars_to_bits_msb", "g1_point_to_dev", "g2_point_to_dev",
         "dev_to_g1_point", "dev_to_g2_point", "ints_to_mont_limbs",
         "_batch_inv_mod_p", "g1_points_to_dev", "g2_points_to_dev",
-        "g2_points_to_packed", "compressed_rows",
+        "compressed_rows",
         "compressed_infinity_flags",
     },
     "msm": {"_next_pow2"},

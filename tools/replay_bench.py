@@ -1,43 +1,25 @@
-"""BASELINE configs 3 & 4: mainnet-shaped block replay + gossip firehose.
+"""Mainnet-shaped chain synthesis (BASELINE configs 3 & 4): the generators.
 
-Reference harnesses being mirrored:
-  - config 3: ad_hoc_bench/src/main.rs:27-148 (cached-chain block replay,
-    wall time per block) over eth2_cache_utils chains — here the chain is
-    SYNTHESIZED at the same operating point (50k validators, mainnet
-    preset, full committees, one aggregate per committee, full sync
-    aggregate) because no cached real-chain data ships offline.
-  - config 4: p2p/src/attestation_verifier.rs:37,114-163 (the ≤64-item
-    accumulate→deadline→batch verify loop) driven at gossip arrival rates.
+What a replay cell needs to make its chain (PERF.md Open question 1), at
+the reference harness's operating point (ad_hoc_bench/src/main.rs:27-148:
+50k validators, mainnet preset, full committees, one aggregate per
+committee, full sync aggregate), SYNTHESIZED because no cached real-chain
+data ships offline. Nothing here writes BENCH_CONFIG3.json /
+BENCH_CONFIG4.json: those records of this operating point stay as they are.
 
-Synthesis trick (same family as bench.py): validator i's secret key is the
-arithmetic progression sk_i = (A + B·i) mod r, so
+Synthesis trick: validator i's secret key is the arithmetic progression
+sk_i = (A + B·i) mod r, so
   - the 50k pubkeys cost one host G1 ADD each (pk_{i+1} = pk_i + [B]G);
   - a full-committee aggregate signature is [Σ_{i∈C} sk_i]·H(m) — the
     scalar is a closed-form integer sum, ONE G2 scalar-mul per aggregate
     (device batch_sign when available, host anchor otherwise).
 The verified workload is identical to real traffic: every aggregate is a
-distinct valid signature set over real committee pubkeys, and the
-verifying side draws fresh randomizers per batch.
-
-Usage:
-  [N_VALIDATORS=50000] [REPLAY_SLOTS=16] [REPLAY_DEVICE=1] \
-      python tools/replay_bench.py [config3|config4|both]
-
-Writes BENCH_CONFIG3.json / BENCH_CONFIG4.json at the repo root and
-prints one JSON line per config (bench.py conventions).
+distinct valid signature set over real committee pubkeys.
 """
 
-import json
-import os
-import sys
 import time
 
 import numpy as np
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-REPO = os.path.join(os.path.dirname(__file__), "..")
-
 
 # ------------------------------------------------------------ AP key plane
 
@@ -246,342 +228,3 @@ def synthesize_chain(state, cfg, ap, signer, n_slots: int):
             flush=True,
         )
     return blocks, set_counts
-
-
-# ---------------------------------------------------------------- config 3
-
-
-def run_config3(n: int, n_slots: int, use_device: bool) -> dict:
-    from grandine_tpu.consensus.verifier import MultiVerifier, TpuVerifier
-    from grandine_tpu.runtime import Controller
-
-    cfg = build_config(n)
-    ap = ApKeys(n)
-    signer = FastSigner(use_device)
-    t_prep0 = time.time()
-    genesis = build_genesis(n, cfg, ap)
-    blocks, set_counts = synthesize_chain(genesis, cfg, ap, signer, n_slots)
-    prep_s = time.time() - t_prep0
-
-    verifier_factory = TpuVerifier if use_device else MultiVerifier
-    ctrl = Controller(genesis, cfg, verifier_factory=verifier_factory)
-    try:
-        from grandine_tpu.fork_choice.store import Tick, TickKind
-
-        # warm the verify kernels on the first block shape so compile time
-        # stays out of the replay measurement (ad_hoc_bench reports steady
-        # state; compile cost is reported separately)
-        t_warm0 = time.time()
-        for i, blk in enumerate(blocks[:2], start=1):
-            # block 1 (3 sets) and block 2 (full, 3+atts sets) hit the
-            # two verify-kernel bucket shapes the replay uses — both
-            # compiles land in warmup, not the measurement
-            ctrl.on_tick(Tick(i, TickKind.PROPOSE))
-            ctrl.on_requested_block(blk)
-            ctrl.wait(timeout=1200)
-        warm_s = time.time() - t_warm0
-        assert not ctrl.rejected(), ctrl.rejected()[:1]
-
-        lat = []
-        t0 = time.time()
-        for i, blk in enumerate(blocks[2:], start=3):
-            tb = time.time()
-            ctrl.on_tick(Tick(i, TickKind.PROPOSE))
-            ctrl.on_requested_block(blk)
-            ctrl.wait(timeout=600)
-            lat.append(time.time() - tb)
-            print(f"  replay block {i}: {lat[-1]*1000:.0f} ms", flush=True)
-        wall = time.time() - t0
-        assert not ctrl.rejected(), ctrl.rejected()[:1]
-        head = ctrl.snapshot()
-        assert int(head.head_state.slot) == n_slots
-    finally:
-        ctrl.stop()
-
-    n_blocks = len(blocks) - 2
-    n_sets = sum(set_counts[2:])
-    sigs_per_sec = n_sets / wall if wall > 0 else 0.0
-    result = {
-        "metric": "block_replay_signature_sets_per_s",
-        "value": round(sigs_per_sec, 1),
-        "unit": "sets/s",
-        "config": 3,
-        "n_validators": n,
-        "n_blocks": n_blocks,
-        "signature_sets": n_sets,
-        "blocks_per_s": round(n_blocks / wall, 3),
-        "p50_block_ms": round(float(np.percentile(lat, 50)) * 1000, 1),
-        "p99_block_ms": round(float(np.percentile(lat, 99)) * 1000, 1),
-        # per-block wall times (ms) for tail diagnosis: index 0 is the
-        # chain's slot 3; epoch boundaries fall where slot % 32 == 0
-        "block_ms": [round(x * 1000, 1) for x in lat],
-        "prep_s": round(prep_s, 1),
-        "warmup_first_block_s": round(warm_s, 1),
-        "device": use_device,
-        "note": (
-            "synthetic mainnet-shaped chain: full committees, one "
-            "aggregate per committee, full sync aggregate; sets/block = "
-            "proposer + randao + sync + per-aggregate"
-        ),
-    }
-    return result
-
-
-# ---------------------------------------------------------------- config 4
-
-
-def run_config4(
-    n: int,
-    use_device: bool,
-    arrival_rate: float = 0.0,
-    max_batch: int = 64,
-    bad_rate: float = 0.0,
-) -> dict:
-    """Firehose: unaggregated gossip attestations through the
-    AttestationVerifier at the dispatch shapes it actually forms.
-
-    `max_batch` defaults to the reference's 64
-    (attestation_verifier.rs:37) but device verify latency is nearly
-    FLAT in batch size (0.23–0.28 s from 1→64 items, crossover_probe),
-    so the TPU-first operating point uses larger batches — set
-    FIREHOSE_MAX_BATCH to measure."""
-    from grandine_tpu.consensus import accessors, signing
-    from grandine_tpu.consensus.verifier import NullVerifier
-    from grandine_tpu.fork_choice.store import Tick, TickKind
-    from grandine_tpu.runtime import Controller
-    from grandine_tpu.runtime.attestation_verifier import AttestationVerifier
-    from grandine_tpu.transition.fork_upgrade import state_phase
-    from grandine_tpu.types.containers import spec_types
-
-    cfg = build_config(n)
-    ap = ApKeys(n)
-    signer = FastSigner(use_device)
-    genesis = build_genesis(n, cfg, ap)
-
-    # gossip traffic for slot 1 duties against the genesis head: every
-    # committee member's SINGLE attestation (the subnet firehose shape)
-    p = cfg.preset
-    ns = getattr(spec_types(p), state_phase(genesis, cfg).key)
-    header = genesis.latest_block_header.replace(
-        state_root=genesis.hash_tree_root()
-    )
-    head_root = header.hash_tree_root()
-    slot = 0
-    epoch = 0
-    count = accessors.get_committee_count_per_slot(genesis, epoch, p)
-    singles = []
-    t_prep0 = time.time()
-    msgs, scalars, metas = [], [], []
-    for index in range(count):
-        committee = accessors.get_beacon_committee(genesis, slot, index, p)
-        data = ns.AttestationData(
-            slot=slot, index=index, beacon_block_root=head_root,
-            source=genesis.current_justified_checkpoint,
-            target=ns.Checkpoint(epoch=epoch, root=head_root),
-        )
-        root = signing.attestation_signing_root(genesis, data, cfg)
-        for pos, vi in enumerate(committee):
-            msgs.append(root)
-            scalars.append(ap.sk_int(int(vi)))
-            metas.append((data, len(committee), pos))
-    sigs = signer.sign_batch(msgs, scalars)
-    # adversarial scenario: a fraction of signatures are VALID points for
-    # the WRONG message (passes prevalidation and decompression; only the
-    # pairing check catches it) — the exact attack that forces the
-    # batch-fail → singular-fallback path (attestation_verifier.rs:231-239)
-    n_bad = int(len(sigs) * bad_rate)
-    if n_bad:
-        bad_every = len(sigs) // n_bad
-        for i in range(0, n_bad * bad_every, bad_every):
-            sigs[i] = sigs[(i + 1) % len(sigs)]
-    for (data, clen, pos), sig in zip(metas, sigs):
-        bits = np.zeros(clen, dtype=bool)
-        bits[pos] = True
-        singles.append(
-            ns.Attestation(aggregation_bits=bits, data=data, signature=sig)
-        )
-    prep_s = time.time() - t_prep0
-    print(f"firehose prep: {len(singles)} singles in {prep_s:.1f}s", flush=True)
-
-    ctrl = Controller(genesis, cfg, verifier_factory=NullVerifier)
-    batch_log = []
-    item_lat = []
-
-    class InstrumentedVerifier(AttestationVerifier):
-        def _verify_batch(self, batch):
-            t0 = time.time()
-            super()._verify_batch(batch)
-            dt = time.time() - t0
-            batch_log.append((len(batch), dt))
-            now = time.time()
-            item_lat.extend(now - it.received_at for it in batch)
-
-    verifier = InstrumentedVerifier(
-        ctrl, use_device=use_device, max_batch=max_batch
-    )
-    try:
-        ctrl.on_tick(Tick(1, TickKind.ATTEST))
-        ctrl.wait()
-        # warm EVERY power-of-two bucket up to max_batch: paced arrivals
-        # form odd-size batches (deadline-bounded), and an uncompiled
-        # bucket mid-run stalls the queue for the compile duration.
-        # Compiles land in the persistent XLA cache, so this is a
-        # one-time cost per kernel change.
-        size = 4
-        while size <= verifier.max_batch:
-            verifier.submit_many(singles[: min(size, len(singles))])
-            verifier.flush(timeout=1200)
-            size *= 2
-        warm = verifier.stats.copy()
-        batch_log.clear()
-        item_lat.clear()
-
-        # measured phase re-submits the FULL single set (fresh
-        # received_at per item; the warm pass only primed kernel shapes)
-        work = singles
-        t0 = time.time()
-        if arrival_rate > 0:
-            # paced arrivals (gossip-shaped): submit in 50ms buckets
-            bucket = max(1, int(arrival_rate * 0.05))
-            for i in range(0, len(work), bucket):
-                verifier.submit_many(work[i : i + bucket])
-                sleep_until = t0 + (i + bucket) / arrival_rate
-                now = time.time()
-                if sleep_until > now:
-                    time.sleep(sleep_until - now)
-        else:
-            verifier.submit_many(work)  # saturation
-        verifier.flush(timeout=1800)
-        wall = time.time() - t0
-        ctrl.wait()
-    finally:
-        verifier.stop()
-        ctrl.stop()
-
-    accepted = verifier.stats["accepted"] - warm["accepted"]
-    sizes = np.array([b[0] for b in batch_log])
-    times = np.array([b[1] for b in batch_log])
-    lat_arr = np.array(item_lat)
-    result = {
-        "metric": "firehose_attestations_per_s",
-        "value": round(accepted / wall, 1) if wall > 0 else 0.0,
-        "unit": "atts/s",
-        "config": 4,
-        "n_validators": n,
-        "submitted": len(singles),
-        "accepted": int(accepted),
-        "rejected": int(verifier.stats["rejected"] - warm["rejected"]),
-        "fallbacks": int(verifier.stats["fallbacks"] - warm["fallbacks"]),
-        "arrival_rate": arrival_rate or "saturation",
-        "batches": len(batch_log),
-        "batch_size_p50": float(np.percentile(sizes, 50)) if len(sizes) else 0,
-        "batch_verify_p50_ms": round(
-            float(np.percentile(times, 50)) * 1000, 1
-        ) if len(times) else 0,
-        "batch_verify_p99_ms": round(
-            float(np.percentile(times, 99)) * 1000, 1
-        ) if len(times) else 0,
-        "item_latency_p50_ms": round(
-            float(np.percentile(lat_arr, 50)) * 1000, 1
-        ) if len(lat_arr) else 0,
-        "item_latency_p99_ms": round(
-            float(np.percentile(lat_arr, 99)) * 1000, 1
-        ) if len(lat_arr) else 0,
-        "deadline_budget_ms": 4000,
-        "clears_deadline": bool(
-            len(lat_arr) and float(np.percentile(lat_arr, 99)) < 4.0
-        ),
-        "max_batch": max_batch,
-        "bad_signatures": n_bad,
-        "prep_s": round(prep_s, 1),
-        "device": use_device,
-    }
-    return result
-
-
-def crossover_probe(use_device: bool) -> dict:
-    """Device-vs-host verify latency at small batch sizes: where does the
-    device win? (the CPU-fallback crossover, SURVEY §7 risk)."""
-    from grandine_tpu.crypto import bls as A
-
-    sizes = [1, 2, 4, 8, 16, 32, 64]
-    sk = [A.SecretKey.keygen(bytes([i + 1]) * 32) for i in range(8)]
-    msgs = [b"crossover-%d" % i for i in range(64)]
-    rows = {}
-    host_t = {}
-    for s in sizes[:4]:  # host anchor is ~0.7s/verify; keep it short
-        triple = [
-            (msgs[i], sk[i % 8].sign(msgs[i]), [sk[i % 8].public_key()])
-            for i in range(s)
-        ]
-        t0 = time.time()
-        for m, sig, pks in triple:
-            sig.fast_aggregate_verify(m, pks)
-        host_t[s] = time.time() - t0
-    if use_device:
-        from grandine_tpu.tpu.bls import TpuBlsBackend
-
-        backend = TpuBlsBackend()
-        for s in sizes:
-            ms = [msgs[i] for i in range(s)]
-            sigs = [sk[i % 8].sign(msgs[i]) for i in range(s)]
-            mems = [[sk[i % 8].public_key()] for i in range(s)]
-            backend.fast_aggregate_verify_batch(ms, sigs, mems)  # warm
-            t0 = time.time()
-            backend.fast_aggregate_verify_batch(ms, sigs, mems)
-            rows[s] = time.time() - t0
-    return {
-        "host_anchor_s": {str(k): round(v, 3) for k, v in host_t.items()},
-        "device_batch_s": {str(k): round(v, 3) for k, v in rows.items()},
-    }
-
-
-def main() -> int:
-    which = sys.argv[1] if len(sys.argv) > 1 else "both"
-    n = int(os.environ.get("N_VALIDATORS", "50000"))
-    n_slots = int(os.environ.get("REPLAY_SLOTS", "16"))
-    use_device = os.environ.get("REPLAY_DEVICE", "1") != "0"
-    rate = float(os.environ.get("FIREHOSE_RATE", "0"))
-
-    if use_device:
-        sys.path.insert(0, REPO)
-        import bench
-
-        bench._enable_compilation_cache()
-
-    if which in ("config3", "both"):
-        r3 = run_config3(n, n_slots, use_device)
-        with open(os.path.join(REPO, "BENCH_CONFIG3.json"), "w") as f:
-            json.dump(r3, f, indent=1)
-        print(json.dumps({k: r3[k] for k in
-                          ("metric", "value", "unit")} | {
-                              "p50_block_ms": r3["p50_block_ms"]}))
-    if which in ("config4", "both"):
-        r4 = run_config4(
-            n,
-            use_device,
-            arrival_rate=rate,
-            max_batch=int(os.environ.get("FIREHOSE_MAX_BATCH", "64")),
-        )
-        # adversarial pass: ~1 bad signature per max_batch-sized batch —
-        # the DoS surface of batch verification; the deadline must still
-        # clear with the fallback cost on the clock
-        r4["adversarial"] = run_config4(
-            n,
-            use_device,
-            arrival_rate=rate,
-            max_batch=int(os.environ.get("FIREHOSE_MAX_BATCH", "64")),
-            bad_rate=float(os.environ.get("FIREHOSE_BAD_RATE", "0.016")),
-        )
-        r4["crossover"] = crossover_probe(use_device)
-        with open(os.path.join(REPO, "BENCH_CONFIG4.json"), "w") as f:
-            json.dump(r4, f, indent=1)
-        print(json.dumps({k: r4[k] for k in
-                          ("metric", "value", "unit")} | {
-                              "item_latency_p99_ms": r4["item_latency_p99_ms"],
-                              "clears_deadline": r4["clears_deadline"]}))
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())
