@@ -26,6 +26,7 @@ import secrets
 import threading
 from typing import Iterable, Optional, Sequence
 
+from grandine_tpu import native
 from grandine_tpu.crypto import constants
 from grandine_tpu.crypto.curves import (
     B1,
@@ -135,6 +136,61 @@ def g2_from_bytes(data: bytes, subgroup_check: bool = True) -> Point[Fq2]:
     if subgroup_check and not point.in_subgroup():
         raise BlsError("G2 point not in subgroup")
     return point
+
+
+#: gt_g2_decompress_batch's status bytes above 1, as the texts
+#: `g2_from_bytes` raises for the same inputs (0 decoded, 1 infinity)
+_G2_BATCH_ERRORS = {
+    2: "uncompressed G2 encoding not supported",
+    3: "malformed G2 infinity encoding",
+    4: "G2 x-coordinate out of range",
+    5: "G2 point not on curve",
+}
+
+
+def g2_batch_path() -> str:
+    """Which decoder `g2_from_bytes_batch` runs in this process: "native"
+    where the runtime library loaded, else "python"."""
+    return "python" if native.lib is None else "native"
+
+
+def g2_from_bytes_batch(
+    datas: Sequence[bytes], subgroup_check: bool = False
+) -> "list[Point[Fq2]]":
+    """`[g2_from_bytes(d, subgroup_check) for d in datas]`, point for
+    point and error for error (the first bad item's BlsError), decoded by
+    one native call that holds no GIL where the runtime library loaded.
+    `g2_from_bytes` is that call's differential reference."""
+    lib = native.lib
+    if lib is None:
+        return [g2_from_bytes(d, subgroup_check) for d in datas]
+    # a wrong length is that item's error, after those of the items before
+    n = next((i for i, d in enumerate(datas) if len(d) != 96), len(datas))
+    out = native.out_buf(n * 192)
+    status = native.out_buf(n)
+    lib.gt_g2_decompress_batch(b"".join(datas[:n]), n, out, status)
+    raw, status = out.raw, status.raw
+    from_be = int.from_bytes
+    points = []
+    for i in range(n):
+        if status[i] == 1:
+            points.append(g2_infinity())
+            continue
+        if status[i]:
+            raise BlsError(_G2_BATCH_ERRORS[status[i]])
+        x0, x1, y0, y1 = (
+            from_be(raw[o:o + 48], "big")
+            for o in range(192 * i, 192 * i + 192, 48)
+        )
+        point = Point.from_affine(
+            Fq2.from_ints(x0, x1), Fq2.from_ints(y0, y1), B2
+        )
+        if subgroup_check and not point.in_subgroup():
+            raise BlsError("G2 point not in subgroup")
+        points.append(point)
+    if n < len(datas):
+        raise BlsError("G2 compressed point must be 96 bytes")
+    return points
 
 
 # --- key and signature types ----------------------------------------------
@@ -394,4 +450,6 @@ __all__ = [
     "g1_from_bytes",
     "g2_to_bytes",
     "g2_from_bytes",
+    "g2_from_bytes_batch",
+    "g2_batch_path",
 ]

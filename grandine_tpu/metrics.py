@@ -400,6 +400,14 @@ class Metrics:
         self.device_batch_sigs = Counter(
             "device_batch_signatures_total",
             "signatures shipped to the accelerator")
+        # which decoder the firehose's g2_decompress stage ran: "native"
+        # (one call a batch, no GIL held) where the runtime library
+        # loaded, else "python" (crypto.bls.g2_from_bytes an item)
+        self.signature_decompress_items = LabeledCounter(
+            "signature_decompress_items_total",
+            "signatures handed to the batch decoder, by the path it took",
+            ("path",),
+        )
         self.block_processing_times = Histogram(
             "block_processing_seconds", "state-transition duration")
         self.head_slot = Gauge("head_slot", "current head slot")

@@ -2,8 +2,9 @@
 
 Compiles the shared library on first import (g++, cached next to the
 source), then binds it via ctypes. If no toolchain is available the
-package still works: `lib` is None and callers (grandine_tpu.core.hashing)
-fall back to hashlib-based pure-Python paths.
+package still works: `lib` is None and callers (grandine_tpu.core.hashing,
+grandine_tpu.crypto.bls.g2_from_bytes_batch) fall back to their
+pure-Python paths.
 """
 
 from __future__ import annotations
@@ -90,6 +91,8 @@ def _bind():
             L.gt_zero_hash.argtypes = [ctypes.c_int, cp]
             L.gt_crc32c.argtypes = [cp, ctypes.c_uint64]
             L.gt_crc32c.restype = ctypes.c_uint32
+            L.gt_g2_decompress_batch.argtypes = [cp, ctypes.c_uint64, cp, cp]
+            L.gt_g2_decompress_batch.restype = None
             shani = bool(L.gt_init())
         except (OSError, AttributeError):
             # missing/stale-ABI cached .so: degrade to hashlib fallback

@@ -16,6 +16,10 @@
 //   gt_merkleize(chunks, n, depth, out32)
 //   gt_merkleize_many(chunks, n_items, cpi, depth, out)
 //   gt_zero_hash(level, out32)
+//   gt_mix_in_length(root32, value, out32)
+//   gt_crc32c(data, len)
+//   gt_g2_decompress_batch(in, n, out, status)  -- n 96-byte compressed
+//                                  G2 points -> n affine (x.c0 x.c1 y.c0 y.c1)
 
 #include <cstdint>
 #include <cstring>
@@ -385,6 +389,321 @@ uint32_t gt_crc32c(const uint8_t* data, uint64_t len) {
 #endif
   if (!g_crc_table_built) build_crc_table();
   return crc32c_portable(crc, data, len) ^ 0xFFFFFFFFu;
+}
+
+}  // extern "C"
+
+// -------------------------------------------------------- G2 decompression
+// The firehose's `host_prep op=g2_decompress` stage: one call decodes a
+// whole batch of compressed signatures (the reference does this in blst).
+// Item for item it is crypto/bls.py `g2_from_bytes(data,
+// subgroup_check=False)` — same flag rules, same range checks, the same
+// y of the two roots — and that function stays the differential reference
+// (tests/test_native_g2_decompress.py). Called through ctypes it holds no
+// GIL, which a Python `pow(n, e, P)` does for its whole length. The data
+// is public: nothing here is constant time.
+//
+// Fp is 6 x 64-bit limbs, little-endian, in Montgomery form (R = 2^384);
+// Fp2 = Fp[u] / (u^2 + 1). Every constant but P itself is derived from P
+// once, so there is no second table to keep in step with it.
+
+namespace {
+
+typedef unsigned __int128 u128;
+
+struct Fp {
+  uint64_t l[6];
+};
+struct Fp2 {
+  Fp c0, c1;
+};
+
+const Fp FP_P = {{0xb9feffffffffaaabULL, 0x1eabfffeb153ffffULL,
+                  0x6730d2a0f6b0f624ULL, 0x64774b84f38512bfULL,
+                  0x4b1ba7b6434bacd7ULL, 0x1a0111ea397fe69aULL}};
+
+inline bool fp_is_zero(const Fp& a) {
+  return (a.l[0] | a.l[1] | a.l[2] | a.l[3] | a.l[4] | a.l[5]) == 0;
+}
+
+inline bool fp_eq(const Fp& a, const Fp& b) {
+  uint64_t d = 0;
+  for (int i = 0; i < 6; i++) d |= a.l[i] ^ b.l[i];
+  return d == 0;
+}
+
+// a > b, a == b or a < b as plain 384-bit integers: 1, 0, -1
+inline int fp_cmp(const Fp& a, const Fp& b) {
+  for (int i = 5; i >= 0; i--) {
+    if (a.l[i] != b.l[i]) return a.l[i] > b.l[i] ? 1 : -1;
+  }
+  return 0;
+}
+
+// r = a - b as integers; returns the borrow
+inline uint64_t raw_sub(Fp& r, const Fp& a, const Fp& b) {
+  uint64_t borrow = 0;
+  for (int i = 0; i < 6; i++) {
+    u128 d = (u128)a.l[i] - b.l[i] - borrow;
+    r.l[i] = (uint64_t)d;
+    borrow = (uint64_t)(d >> 64) & 1;
+  }
+  return borrow;
+}
+
+// r = a + b as integers (operands below 2^383: no carry out)
+inline void raw_add(Fp& r, const Fp& a, const Fp& b) {
+  uint64_t carry = 0;
+  for (int i = 0; i < 6; i++) {
+    u128 s = (u128)a.l[i] + b.l[i] + carry;
+    r.l[i] = (uint64_t)s;
+    carry = (uint64_t)(s >> 64);
+  }
+}
+
+inline Fp fp_add(const Fp& a, const Fp& b) {
+  Fp r;
+  raw_add(r, a, b);
+  if (fp_cmp(r, FP_P) >= 0) raw_sub(r, r, FP_P);
+  return r;
+}
+
+inline Fp fp_sub(const Fp& a, const Fp& b) {
+  Fp r;
+  if (raw_sub(r, a, b)) raw_add(r, r, FP_P);  // wraps back into [0, P)
+  return r;
+}
+
+inline Fp fp_neg(const Fp& a) {
+  Fp r = a;
+  if (!fp_is_zero(a)) raw_sub(r, FP_P, a);
+  return r;
+}
+
+uint64_t g_fp_n0;  // -P^-1 mod 2^64
+
+// Montgomery product a * b / R mod P (CIOS); operands and result in [0, P)
+Fp fp_mul(const Fp& a, const Fp& b) {
+  uint64_t t[8] = {0, 0, 0, 0, 0, 0, 0, 0};
+#pragma GCC unroll 6
+  for (int i = 0; i < 6; i++) {
+    uint64_t carry = 0;
+#pragma GCC unroll 6
+    for (int j = 0; j < 6; j++) {
+      u128 x = (u128)a.l[j] * b.l[i] + t[j] + carry;
+      t[j] = (uint64_t)x;
+      carry = (uint64_t)(x >> 64);
+    }
+    u128 x = (u128)t[6] + carry;
+    t[6] = (uint64_t)x;
+    t[7] = (uint64_t)(x >> 64);
+    uint64_t m = t[0] * g_fp_n0;
+    x = (u128)m * FP_P.l[0] + t[0];
+    carry = (uint64_t)(x >> 64);
+#pragma GCC unroll 6
+    for (int j = 1; j < 6; j++) {
+      x = (u128)m * FP_P.l[j] + t[j] + carry;
+      t[j - 1] = (uint64_t)x;
+      carry = (uint64_t)(x >> 64);
+    }
+    x = (u128)t[6] + carry;
+    t[5] = (uint64_t)x;
+    t[6] = t[7] + (uint64_t)(x >> 64);
+  }
+  Fp r;  // below 2P < 2^382: t[6] is 0
+  for (int i = 0; i < 6; i++) r.l[i] = t[i];
+  if (fp_cmp(r, FP_P) >= 0) raw_sub(r, r, FP_P);
+  return r;
+}
+
+inline Fp fp_sqr(const Fp& a) { return fp_mul(a, a); }
+
+// a^e for a 384-bit exponent of plain limbs
+Fp fp_pow(const Fp& a, const Fp& e, const Fp& one) {
+  Fp r = one;
+  for (int i = 383; i >= 0; i--) {
+    r = fp_sqr(r);
+    if ((e.l[i >> 6] >> (i & 63)) & 1) r = fp_mul(r, a);
+  }
+  return r;
+}
+
+struct FpConsts {
+  Fp r2;         // R^2 mod P: into Montgomery form
+  Fp one;        // R mod P
+  Fp one_plain;  // the integer 1: out of Montgomery form
+  Fp half;       // 1/2
+  Fp four;       // 4: the curve's b is 4 + 4u
+  Fp exp_sqrt;   // (P + 1) / 4: a root of a residue, as P = 3 mod 4
+  Fp exp_isqrt;  // (P - 3) / 4: the inverse of that root
+
+  FpConsts() {
+    // Newton: each step doubles the correct low bits of P^-1 mod 2^64
+    uint64_t inv = 1;
+    for (int i = 0; i < 6; i++) inv *= 2 - FP_P.l[0] * inv;
+    g_fp_n0 = 0 - inv;
+    // 2^k mod P by doubling: k = 384 is R, k = 768 is R^2
+    Fp x = {{1, 0, 0, 0, 0, 0}};
+    one_plain = x;
+    for (int k = 1; k <= 768; k++) {
+      x = fp_add(x, x);
+      if (k == 384) one = x;
+    }
+    r2 = x;
+    four = fp_add(fp_add(one, one), fp_add(one, one));
+    Fp three = {{3, 0, 0, 0, 0, 0}};
+    raw_add(exp_sqrt, FP_P, one_plain);
+    raw_sub(exp_isqrt, FP_P, three);
+    Fp half_plain = exp_sqrt;  // (P + 1) / 2, before the second shift
+    shr(half_plain, 1);
+    half = fp_mul(half_plain, r2);
+    shr(exp_sqrt, 2);
+    shr(exp_isqrt, 2);
+  }
+
+  static void shr(Fp& a, int bits) {
+    for (int i = 0; i < 6; i++) {
+      uint64_t hi = i < 5 ? a.l[i + 1] : 0;
+      a.l[i] = (a.l[i] >> bits) | (hi << (64 - bits));
+    }
+  }
+};
+
+const FpConsts& fp_consts() {
+  static const FpConsts c;  // built once, by whichever thread is first
+  return c;
+}
+
+inline Fp fp_from_be48(const uint8_t* p) {
+  Fp r;
+  for (int i = 0; i < 6; i++) {
+    uint64_t v = 0;
+    for (int j = 0; j < 8; j++) v = (v << 8) | p[(5 - i) * 8 + j];
+    r.l[i] = v;
+  }
+  return r;
+}
+
+inline void fp_to_be48(const Fp& a, uint8_t* p) {
+  for (int i = 0; i < 6; i++)
+    for (int j = 0; j < 8; j++)
+      p[(5 - i) * 8 + j] = uint8_t(a.l[i] >> (8 * (7 - j)));
+}
+
+inline Fp2 fp2_sqr(const Fp2& a) {
+  Fp t = fp_mul(a.c0, a.c1);
+  return {fp_mul(fp_add(a.c0, a.c1), fp_sub(a.c0, a.c1)), fp_add(t, t)};
+}
+
+inline Fp2 fp2_mul(const Fp2& a, const Fp2& b) {
+  Fp v0 = fp_mul(a.c0, b.c0), v1 = fp_mul(a.c1, b.c1);
+  Fp cross = fp_mul(fp_add(a.c0, a.c1), fp_add(b.c0, b.c1));
+  return {fp_sub(v0, v1), fp_sub(fp_sub(cross, v0), v1)};
+}
+
+// A square root of z = a + b*u, or false where z is no square. Two
+// exponentiations: s = sqrt(a^2 + b^2), then w = t2^((P-3)/4) for
+// t2 = (a + s) / 2 gives t = w * t2 with t^2 = +-t2 and t * w = +-1, so
+// the root and the inverse the other coordinate needs come from one
+// power: t^2 = t2 -> (t, b*w/2); t^2 = -t2 -> (-b*w/2, t). Which of the
+// two roots comes out does not matter: the caller picks by the sign bit.
+bool fp2_sqrt(const Fp2& z, Fp2& y, const FpConsts& k) {
+  const Fp zero = {{0, 0, 0, 0, 0, 0}};
+  if (fp_is_zero(z.c1)) {
+    // a or -a is a residue (-1 is none): w^2 = +-a
+    Fp w = fp_pow(z.c0, k.exp_sqrt, k.one);
+    if (fp_eq(fp_sqr(w), z.c0)) {
+      y = {w, zero};
+    } else {
+      y = {zero, w};
+    }
+    return true;
+  }
+  Fp norm = fp_add(fp_sqr(z.c0), fp_sqr(z.c1));
+  Fp s = fp_pow(norm, k.exp_sqrt, k.one);
+  if (!fp_eq(fp_sqr(s), norm)) return false;
+  // t2 = 0 would need a = -s, that is b = 0: handled above
+  Fp t2 = fp_mul(fp_add(z.c0, s), k.half);
+  Fp w = fp_pow(t2, k.exp_isqrt, k.one);
+  Fp t = fp_mul(w, t2);
+  Fp other = fp_mul(fp_mul(z.c1, w), k.half);
+  if (fp_eq(fp_sqr(t), t2)) {
+    y = {t, other};
+  } else {
+    y = {fp_neg(other), t};
+  }
+  Fp2 check = fp2_sqr(y);
+  return fp_eq(check.c0, z.c0) && fp_eq(check.c1, z.c1);
+}
+
+// crypto/bls.py `_fq2_lex_larger`: y above -y, ordered by (c1, c0) as
+// plain integers
+bool fp2_lex_larger(const Fp& c0_plain, const Fp& c1_plain) {
+  const Fp& v = fp_is_zero(c1_plain) ? c0_plain : c1_plain;
+  if (fp_is_zero(v)) return false;
+  Fp neg;
+  raw_sub(neg, FP_P, v);
+  return fp_cmp(v, neg) > 0;
+}
+
+enum G2Status : uint8_t {
+  G2_OK = 0,
+  G2_INFINITY = 1,
+  G2_NOT_COMPRESSED = 2,
+  G2_BAD_INFINITY = 3,
+  G2_X_OUT_OF_RANGE = 4,
+  G2_NOT_ON_CURVE = 5,
+};
+
+uint8_t g2_decompress_one(const uint8_t* in, uint8_t* out,
+                          const FpConsts& k) {
+  uint8_t flags = in[0];
+  if (!(flags & 0x80)) return G2_NOT_COMPRESSED;
+  if (flags & 0x40) {
+    uint8_t rest = flags & 0x3f;
+    for (int i = 1; i < 96; i++) rest |= in[i];
+    return rest ? G2_BAD_INFINITY : G2_INFINITY;
+  }
+  uint8_t head[48];
+  std::memcpy(head, in, 48);
+  head[0] &= 0x1f;
+  Fp x1 = fp_from_be48(head), x0 = fp_from_be48(in + 48);
+  if (fp_cmp(x0, FP_P) >= 0 || fp_cmp(x1, FP_P) >= 0)
+    return G2_X_OUT_OF_RANGE;
+  Fp2 x = {fp_mul(x0, k.r2), fp_mul(x1, k.r2)};
+  Fp2 rhs = fp2_mul(fp2_sqr(x), x);
+  rhs.c0 = fp_add(rhs.c0, k.four);
+  rhs.c1 = fp_add(rhs.c1, k.four);
+  Fp2 y;
+  if (!fp2_sqrt(rhs, y, k)) return G2_NOT_ON_CURVE;
+  Fp y0 = fp_mul(y.c0, k.one_plain), y1 = fp_mul(y.c1, k.one_plain);
+  if (bool(flags & 0x20) != fp2_lex_larger(y0, y1)) {
+    y0 = fp_neg(y0);
+    y1 = fp_neg(y1);
+  }
+  fp_to_be48(x0, out);
+  fp_to_be48(x1, out + 48);
+  fp_to_be48(y0, out + 96);
+  fp_to_be48(y1, out + 144);
+  return G2_OK;
+}
+
+}  // namespace
+
+extern "C" {
+
+// n compressed G2 points of 96 bytes -> n affine points of 192 bytes
+// (x.c0, x.c1, y.c0, y.c1, 48 bytes big-endian each) and one status byte
+// an item: 0 decoded, 1 the point at infinity, 2.. the reason it is
+// malformed (G2Status; crypto/bls.py maps them to its BlsError texts).
+// Where the status is not 0 the item's 192 bytes are zero. No subgroup
+// check, as in the Python decoder with subgroup_check=False.
+void gt_g2_decompress_batch(const uint8_t* in, uint64_t n, uint8_t* out,
+                            uint8_t* status) {
+  const FpConsts& k = fp_consts();
+  std::memset(out, 0, n * 192);
+  for (uint64_t i = 0; i < n; i++)
+    status[i] = g2_decompress_one(in + 96 * i, out + 192 * i, k);
 }
 
 }  // extern "C"

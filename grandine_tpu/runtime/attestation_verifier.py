@@ -497,16 +497,20 @@ class AttestationVerifier:
         if not _health.has_async_seam(backend):
             return None
         messages = [p[0] for p in prepared]
+        if self.metrics is not None:
+            self.metrics.signature_decompress_items.inc(
+                A.g2_batch_path(), amount=len(prepared)
+            )
         try:
             # decompress WITHOUT the per-signature host subgroup
             # scalar-mul (~9 ms each — it dominated batch latency); the
-            # device checks the whole batch in one ψ ladder
+            # device checks the whole batch in one ψ ladder. One call a
+            # batch: native and off the GIL where the library loaded
             with self._stage("host_prep", op="g2_decompress",
                              items=len(prepared)):
-                points = [
-                    A.g2_from_bytes(bytes(p[1]), subgroup_check=False)
-                    for p in prepared
-                ]
+                points = A.g2_from_bytes_batch(
+                    [bytes(p[1]) for p in prepared]
+                )
         except A.BlsError:
             return lambda: False
         if any(p.is_infinity() for p in points):

@@ -13,7 +13,7 @@ import time
 import pytest
 
 from benchmark import trace_reduce
-from grandine_tpu import tracing
+from grandine_tpu import native, tracing
 from grandine_tpu.consensus.verifier import NullVerifier
 from grandine_tpu.fork_choice.store import Tick, TickKind
 from grandine_tpu.metrics import Metrics
@@ -55,7 +55,8 @@ class SeamBackend:
     def g2_subgroup_check_batch_async(self, points):
         raise AssertionError("fused: never called")
 
-    def fast_aggregate_verify_batch_async(self, messages, sigs, members):
+    def fast_aggregate_verify_batch_async(self, messages, sigs, members,
+                                          **_pin):
         with self._stage("host_prep", op="pack_aggregate",
                          items=len(messages)):
             time.sleep(self.work_s)
@@ -71,17 +72,28 @@ class SeamBackend:
         return settle
 
 
-def drive(genesis, metrics, tracer, device: bool):
+def drive(genesis, metrics, tracer, device: bool, then=None):
     """One slot's attestations through the firehose, as
     tests/test_observability.py `_run_firehose_batch` drives them; with
     `device` through the pipelined path over SeamBackend. Returns the
-    verifier's flight rows (its spans are in `tracer`)."""
+    verifier's flight rows (its spans are in `tracer`). `then(verifier)`
+    runs once the slot is through, before the verifier stops; what
+    `_device_dispatch` was given, call by call, is in
+    `verifier.dispatched`."""
     ctrl = Controller(genesis, CFG, verifier_factory=NullVerifier,
                       metrics=metrics, tracer=tracer)
     verifier = AttestationVerifier(
         ctrl, use_device=device, use_registry=False, deadline_s=0.01,
         backend=SeamBackend(metrics, tracer) if device else None,
     )
+    verifier.dispatched = []
+    dispatch = verifier._device_dispatch
+
+    def recording(prepared, parent=None):
+        verifier.dispatched.append(prepared)
+        return dispatch(prepared, parent)
+
+    verifier._device_dispatch = recording
     try:
         blk, post = produce_block(genesis, 1, CFG,
                                   full_sync_participation=False)
@@ -93,6 +105,8 @@ def drive(genesis, metrics, tracer, device: bool):
         verifier.flush()
         ctrl.wait()
         assert verifier.stats["accepted"] == len(atts)
+        if then is not None:
+            then(verifier)
         return [r for r in verifier.flight.snapshot(lane="attestation")
                 if r.kind == BATCH]
     finally:
@@ -276,6 +290,47 @@ def test_stages_reach_the_profilers_clock_only_in_a_session(
     Recorder.names = []
     drive(genesis, Metrics(), Tracer(), device=True)
     assert Recorder.names == [], "written after the session closed"
+
+
+@pytest.mark.parametrize("path", ["native", "python"])
+def test_one_decompress_stage_a_call_by_the_path_the_library_gives(
+        genesis, monkeypatch, path):
+    """`_device_dispatch` decodes its signatures in ONE `host_prep` /
+    `g2_decompress` stage a call, first pass or probe, with the call's
+    items on it, and the counter names the decoder that ran: the native
+    one where the runtime library loaded, the Python loop where it did
+    not. Nothing but the library chooses."""
+    if path == "python":
+        monkeypatch.setattr(native, "lib", None)
+    elif native.lib is None:
+        pytest.skip("no toolchain built the runtime library")
+    other = {"native": "python", "python": "native"}[path]
+    metrics, tracer = Metrics(), Tracer()
+
+    def decompress_stages():
+        return [s.attrs["items"] for s in tracer.finished_spans()
+                if s.name == "host_prep"
+                and s.attrs.get("op") == "g2_decompress"]
+
+    def probe(verifier):
+        first_passes = [len(p) for p in verifier.dispatched]
+        assert first_passes and decompress_stages() == first_passes
+        counter = metrics.signature_decompress_items
+        assert counter.value(path) == sum(first_passes)
+        batch = verifier.dispatched[0]
+        half = batch[:max(1, len(batch) // 2)]
+        settle = verifier._device_dispatch(half, parent=batch)
+        assert settle() is True
+        assert decompress_stages() == first_passes + [len(half)]
+        assert counter.value(path) == sum(first_passes) + len(half)
+        assert counter.value(other) == 0
+        # an undecodable signature is the batch's verdict, in that stage
+        bad = list(half[0])
+        bad[1] = b"\x00" * 96
+        assert verifier._device_dispatch([tuple(bad)])() is False
+        assert decompress_stages() == first_passes + [len(half), 1]
+
+    drive(genesis, metrics, tracer, device=True, then=probe)
 
 
 def test_compile_scope_reads_by_phase():

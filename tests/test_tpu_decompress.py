@@ -21,11 +21,12 @@ import numpy as np
 
 from grandine_tpu.crypto import bls as A
 from grandine_tpu.crypto.constants import P
-from grandine_tpu.crypto.curves import G1, g1_infinity, g2_infinity
-from grandine_tpu.crypto.fields import Fq2
+from grandine_tpu.crypto.curves import G1, g1_infinity
 from grandine_tpu.crypto.hash_to_curve import hash_to_g2
 from grandine_tpu.tpu import curve as C
 from grandine_tpu.tpu import limbs as L
+
+from g2_corpus import g2_corpus
 
 rng = random.Random(0xDEC0)
 
@@ -85,46 +86,6 @@ def _g1_corpus():
     return blobs + bad
 
 
-def _g2_corpus():
-    blobs = [A.g2_to_bytes(hash_to_g2(b"corpus-%d" % i)) for i in range(4)]
-    # opposite sqrt branch in Fq2
-    flip = bytearray(blobs[0])
-    flip[0] ^= C.SIGN_FLAG
-    blobs.append(bytes(flip))
-    blobs.append(A.g2_to_bytes(g2_infinity()))
-    bad = []
-    b = bytearray(blobs[0])
-    b[0] &= 0x7F
-    bad.append(bytes(b))
-    # non-canonical c1 (leading half) and c0 (trailing half)
-    c1_ge = bytearray(96)
-    c1_ge[:48] = (P + 2).to_bytes(48, "big")
-    c1_ge[0] |= C.COMPRESSED_FLAG
-    bad.append(bytes(c1_ge))
-    c0_ge = bytearray(96)
-    c0_ge[48:] = (P + 2).to_bytes(48, "big")
-    c0_ge[0] |= C.COMPRESSED_FLAG
-    bad.append(bytes(c0_ge))
-    # x whose rhs = x^3 + 4(1+i) is a non-residue in Fq2
-    c0v = 0
-    found = None
-    while found is None:
-        c0v += 1
-        xx = Fq2.from_ints(c0v, 3)
-        rhs = xx * xx * xx + Fq2.from_ints(4, 4)
-        if rhs.sqrt() is None:
-            found = xx
-    nr = bytearray(
-        found.c1.n.to_bytes(48, "big") + found.c0.n.to_bytes(48, "big")
-    )
-    nr[0] |= C.COMPRESSED_FLAG
-    bad.append(bytes(nr))
-    ip = bytearray(blobs[0])
-    ip[0] |= C.INFINITY_FLAG
-    bad.append(bytes(ip))
-    return blobs + bad
-
-
 def test_g1_decompress_matches_host_on_edge_corpus():
     blobs = _g1_corpus()
     rows = C.compressed_rows(blobs, 48)
@@ -148,7 +109,7 @@ def test_g1_decompress_matches_host_on_edge_corpus():
 
 
 def test_g2_decompress_matches_host_on_edge_corpus():
-    blobs = _g2_corpus()
+    blobs = g2_corpus()
     rows = C.compressed_rows(blobs, 96)
     x_d, y_d, inf, ok, bad_enc, bad_curve, bad_inf = _g2_jit(rows)
     for i, blob in enumerate(blobs):
