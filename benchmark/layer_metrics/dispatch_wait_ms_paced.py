@@ -1,0 +1,13 @@
+"""`dispatch_wait_ms` in the gossip cell (subnets-paced), under a base
+name of its own: tests/benchmark_harness/test_span_readers.py pins the
+manifest's entries of base `dispatch_wait_ms` to the two clean cells', and
+a PR that adds a cell may not edit that file. The same reading as
+benchmark/layer_metrics/dispatch_wait_ms.py: here it is what a short batch
+waits for a turn in the two-deep pipeline."""
+from benchmark import span_metrics
+
+LAYER, UNIT = "firehose batching", "ms"
+
+
+def read(run):
+    return span_metrics.flight_median_ms(run, "dispatch_wait_s")

@@ -19,11 +19,12 @@ prints one JSON line when it ends and any failure exits non-zero at once:
           slot, device batches > 0, no fallback, breaker never left closed.
 
 It compiles three verify executables (grouped MSM verify at 256x64,
-indexed aggregate verify at 64x256 over 65,536 registry rows and at 4x4
-over 64 rows) and the registry's g1_decompress at two capacities — one at
-a time, host memory trimmed after each (tpu/compile_scope.py). Before each
-compile it prints its resident memory and refuses to start one that the
-host cannot hold. CHANGES.md (PR 22) has the budget these were sized by.
+indexed aggregate verify at 64x256 over 65,536 registry rows and at 64x4
+over 64 rows: the node's batch of two, padded into its one batch bucket)
+and the registry's g1_decompress at two capacities — one at a time, host
+memory trimmed after each (tpu/compile_scope.py). Before each compile it
+prints its resident memory and refuses to start one that the host cannot
+hold. CHANGES.md (PR 22) has the budget these were sized by.
 
 The last line of a chip run is `{"ok": true, "device": {...}}`. A
 rehearsal never prints it, and without `--rehearse` a missing chip is a
@@ -57,7 +58,8 @@ AGG_ITEMS = 64
 AGG_WIDTH = 130
 #: phase `node`: minimal preset (8 slots/epoch, target committee size 4) —
 #: 64 validators give 2 committees of 4 every slot, so every slot's
-#: attestations form one device batch of one shape
+#: attestations form one device batch, padded like any batch into the
+#: verifier's one batch bucket (AGG_ITEMS) at committee width 4
 NODE_VALIDATORS = 64
 NODE_SLOTS = 8
 NODE_COMMITTEES_PER_SLOT = 2
@@ -548,7 +550,7 @@ def phase_node(dev, validators: int, slots: int, data_dir: str,
         before_compile(
             f"g1_decompress {registry_capacity(validators)} + "
             "agg_fast_verify_msm_idx "
-            f"{NODE_COMMITTEES_PER_SLOT}x{NODE_COMMITTEE_SIZE}"
+            f"{AGG_ITEMS}x{NODE_COMMITTEE_SIZE}"
         )
         tee = _SlotTee(sys.stdout, port)
         with contextlib.redirect_stdout(tee):

@@ -60,7 +60,13 @@ def build_parser() -> argparse.ArgumentParser:
              "launching; --devices only selects from what is visible")
     parser.add_argument(
         "--no-warm", action="store_true",
-        help="skip the startup kernel-bucket precompile warmer")
+        help="skip the startup precompile warmer. With --use-device, "
+             "`run` warms before slot 1 what its firehose can dispatch: "
+             "one batch bucket (a batch of any size, a single vote as a "
+             "full batch, runs padded in it) per committee width: the "
+             "head state's own width(s), or every width bucket up to the "
+             "widest committee's for a networked node (--listen-port / "
+             "--peer)")
     parser.add_argument(
         "--no-isolation", action="store_true",
         help="disable on-device fault localization of failed verify "
@@ -84,7 +90,12 @@ def build_parser() -> argparse.ArgumentParser:
 
     sub = parser.add_subparsers(dest="command")
 
-    run = sub.add_parser("run", help="run an in-process devnet node")
+    run = sub.add_parser(
+        "run", help="run an in-process devnet node",
+        description="Run an in-process devnet node. With the global "
+                    "--use-device the attestation firehose verifies on the "
+                    "accelerator and its shapes are warmed before slot 1: "
+                    "one batch bucket per committee width (see --no-warm).")
     run.add_argument("--validators", type=int, default=32)
     run.add_argument("--slots", type=int, default=32,
                      help="stop after this many slots (0 = run forever)")
@@ -473,18 +484,21 @@ def _node_once(args, cfg) -> int:
     return 0
 
 
-def _firehose_warm_plan(state, cfg, max_batch: int, networked: bool):
+def _firehose_warm_plan(state, cfg, batch_bucket: int, networked: bool):
     """[(batch bucket, committee width)] — every shape the attestation
     firehose of THIS node can dispatch to the indexed aggregate kernel,
-    read from the head state instead of the whole manifest (~80 pairs,
-    minutes of compile and gigabytes of host memory each when cold).
+    read from the head state instead of the whole manifest (dozens of
+    pairs, minutes of compile and gigabytes of host memory each when
+    cold).
 
-    Both axes of the kernel are bucketed: the batch (1..max_batch
-    aggregates) and the member axis (widest committee in the batch). A
-    node without gossip ingress only ever sees its own duty loop — one
-    batch of every committee of the slot, each a full aggregate — so it
-    needs one batch bucket and the committee-size bucket(s). A networked
-    node can be handed anything from a single vote to a full batch."""
+    The batch axis has ONE bucket: the verifier pads every call, a single
+    vote as a full batch, into `AttestationVerifier.batch_bucket` (the
+    kernel's time is flat in that axis). Only the member axis (widest
+    committee in the batch) still has a ladder. A node without gossip
+    ingress only ever sees its own duty loop — every committee of the
+    slot a full aggregate — so it needs the committee-size bucket(s). A
+    networked node can be handed anything from a single vote to a full
+    aggregate: every width bucket up to the widest committee's."""
     from grandine_tpu.consensus import accessors
     from grandine_tpu.tpu.bls import _bucket
 
@@ -494,17 +508,12 @@ def _firehose_warm_plan(state, cfg, max_batch: int, networked: bool):
     per_slot = accessors.committee_count_per_slot(active, p)
     committees = p.SLOTS_PER_EPOCH * per_slot
     narrowest, widest = max(1, active // committees), -(-active // committees)
-
-    def ladder(lo: int, hi: int) -> "list[int]":
-        return [b for b in (4 << i for i in range(16)) if lo <= b <= hi]
-
-    if networked or per_slot > max_batch:
-        batches = ladder(4, _bucket(max_batch))
-        widths = ladder(4, _bucket(widest))
+    if networked:
+        widths = [w for w in (4 << i for i in range(16))
+                  if w <= _bucket(widest)]
     else:
-        batches = [_bucket(per_slot)]
         widths = sorted({_bucket(narrowest), _bucket(widest)})
-    return [(b, w) for w in widths for b in batches]
+    return [(batch_bucket, w) for w in widths]
 
 
 def _warm_firehose(node, cfg, metrics, networked: bool) -> None:
@@ -520,11 +529,11 @@ def _warm_firehose(node, cfg, metrics, networked: bool) -> None:
 
     verifier = node.attestation_verifier
     state = node.controller.snapshot().head_state
-    plan = _firehose_warm_plan(state, cfg, verifier.max_batch, networked)
+    plan = _firehose_warm_plan(state, cfg, verifier.batch_bucket, networked)
     widths = sorted({w for _, w in plan})
     print(
-        f"[warmup] {len(plan)} entries: aggregate_idx batch buckets "
-        f"{sorted({b for b, _ in plan})} x committee widths {widths}, one "
+        f"[warmup] {len(plan)} entries: aggregate_idx batch bucket "
+        f"{verifier.batch_bucket} x committee widths {widths}, one "
         f"at a time (cold: minutes each; cache {jit_cache_dir()})",
         flush=True,
     )

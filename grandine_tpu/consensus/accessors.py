@@ -130,9 +130,13 @@ def _active_digest(active: np.ndarray) -> bytes:
 
 # ------------------------------------------------------------ shuffle caches
 
-# (seed, active-digest) -> shuffled active indices / committee partition.
-# Structurally keyed: reusable across every state that shares the seed and
-# active set (all states of an epoch, across forks with a common mix).
+# (seed, active-digest, the preset's constants that shape the result) ->
+# shuffled active indices / committee partition. Structurally keyed:
+# reusable across every state that shares the seed and active set (all
+# states of an epoch, across forks with a common mix); the preset is in
+# the key because one process can hold states of two presets with the
+# same mix and active set (the tests do), and a minimal-preset partition
+# handed to a mainnet-preset state has too few committees.
 _SHUFFLE_CACHE: OrderedDict = OrderedDict()
 _PARTITION_CACHE: OrderedDict = OrderedDict()
 
@@ -140,7 +144,7 @@ _PARTITION_CACHE: OrderedDict = OrderedDict()
 def shuffled_active_indices(
     seed: bytes, active: np.ndarray, p: Preset
 ) -> np.ndarray:
-    key = (seed, _active_digest(active))
+    key = (seed, _active_digest(active), p.SHUFFLE_ROUND_COUNT)
     with _CACHE_LOCK:
         hit = _SHUFFLE_CACHE.get(key)
         if hit is not None:
@@ -160,7 +164,9 @@ def committee_partition(
 ) -> "list[np.ndarray]":
     """All committees of the epoch with shuffle seed `seed`, flat-indexed
     k = (slot % SLOTS_PER_EPOCH) * committees_per_slot + committee_index."""
-    key = (seed, _active_digest(active))
+    key = (seed, _active_digest(active), p.SHUFFLE_ROUND_COUNT,
+           p.SLOTS_PER_EPOCH, p.MAX_COMMITTEES_PER_SLOT,
+           p.TARGET_COMMITTEE_SIZE)
     with _CACHE_LOCK:
         hit = _PARTITION_CACHE.get(key)
         if hit is not None:

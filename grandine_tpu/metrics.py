@@ -370,6 +370,22 @@ class Metrics:
         self.att_fallbacks = Counter(
             "attestation_verifier_fallbacks_total",
             "batches degraded to singular verification")
+        # how the collector closed each batch: at the batch bound, at
+        # the deadline after its first item, or at stop
+        self.att_batches_closed = LabeledCounter(
+            "attestation_batches_closed_total",
+            "gossip batches formed, by what closed them",
+            ("by",),
+        )
+        # a first pass of any size runs in the node's one batch bucket:
+        # items / slots is what padding a partial batch costs (twins of
+        # the probe pair below)
+        self.att_first_pass_items = Counter(
+            "attestation_first_pass_items_total",
+            "real items in first-pass device calls")
+        self.att_first_pass_slots = Counter(
+            "attestation_first_pass_slots_total",
+            "padded batch slots of those calls")
         # the descent over a failed batch (_isolate): its probes are
         # calls of the batch's own executable, padded to the batch's
         # bucket — items / slots is what that padding costs

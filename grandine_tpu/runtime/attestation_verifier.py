@@ -9,7 +9,11 @@ verification so a single bad signature can't stall the stream :231-239,
 TPU shape: each batch becomes ONE `fast_aggregate_verify_batch` launch
 (M aggregates × K committee members — the firehose kernels' native
 geometry, tpu/bls.py aggregate_fast_verify_msm_idx_kernel). The deadline keeps latency bounded when gossip is slow;
-the batch bound keeps device launches dense when it's fast.
+the batch bound keeps device launches dense when it's fast. A batch of any
+size 1..max_batch is dispatched padded into ONE batch bucket
+(`AttestationVerifier.batch_bucket`): the kernel's time is flat in its
+batch axis, so a smaller native bucket buys nothing and costs an
+executable (minutes to compile, ~80 s to load).
 """
 
 from __future__ import annotations
@@ -54,17 +58,20 @@ class _BatchLife:
     """What travels with one batch from the collector to its verdict: the
     root span of its chain and the stamps its waits are measured from."""
 
-    __slots__ = ("root", "arrived", "popped", "pool_span")
+    __slots__ = ("root", "arrived", "popped", "pool_span", "closed_by")
 
-    def __init__(self, tracer, batch, popped: float) -> None:
+    def __init__(self, tracer, batch, popped: float, closed_by: str) -> None:
         #: arrival of the batch's oldest item: where its life begins
         self.arrived = min(it.arrived for it in batch)
         self.popped = popped
+        #: what closed the batch: "full", "deadline" or "stop"
+        self.closed_by = closed_by
         self.root = tracer.span(
             "verify_batch", {"batch": len(batch)}, start=self.arrived
         )
         tracer.span(
-            "collect_wait", parent=self.root, start=self.arrived
+            "collect_wait", {"closed_by": closed_by, "items": len(batch)},
+            parent=self.root, start=self.arrived,
         ).finish()
         self.pool_span = tracer.span("pool_wait", parent=self.root)
 
@@ -221,6 +228,14 @@ class AttestationVerifier:
             self._completion_thread.start()
         self._collector.start()
 
+    @property
+    def batch_bucket(self) -> int:
+        """The ONE batch bucket every device call of this verifier runs
+        in, whatever it holds (a first pass of 1..max_batch items, a probe
+        of a failed batch): what the warm-up compiles per committee width
+        and what the flight row records."""
+        return _flight.bucket_of(self.max_batch)
+
     # ----------------------------------------------------------- ingestion
 
     def submit(self, attestation, origin: "Optional[str]" = None) -> None:
@@ -290,9 +305,18 @@ class AttestationVerifier:
             if not batch:
                 return False
             self._active += 1
+            # what bounded the batch as it leaves: a batch that met its
+            # deadline short and filled while it waited for an active slot
+            # leaves full
+            closed_by = (
+                "full" if len(batch) >= self.max_batch
+                else "stop" if self._stop else "deadline"
+            )
+        if self.metrics is not None:
+            self.metrics.att_batches_closed.inc(closed_by)
         # the batch's chain of spans begins here, back-dated to the
         # arrival of its oldest item: collect_wait is over, pool_wait runs
-        life = _BatchLife(self.tracer, batch, time.perf_counter())
+        life = _BatchLife(self.tracer, batch, time.perf_counter(), closed_by)
         try:
             self.controller.pool.spawn(
                 lambda: self._verify_batch(batch, life), Priority.LOW
@@ -372,8 +396,10 @@ class AttestationVerifier:
             queue_wait_s=time.perf_counter() - life.arrived,
             breaker_state=self.health.state if self.use_device else "",
             devices=self.mesh.device_count if self.mesh is not None else 1,
+            bucket=self.batch_bucket if self.use_device else None,
         )
         fl.trace(life.root)
+        fl.record.closed_by = life.closed_by
         fl.record.collect_wait_s = life.popped - life.arrived
         fl.record.pool_wait_s = max(0.0, t_start - life.popped)
         skipped = False
@@ -491,8 +517,11 @@ class AttestationVerifier:
         None when the backend lacks the async seam (`_batch_check` then
         answers from the host anchor). `parent` is the failed batch that
         `prepared` is a part of, when the call is a probe of its descent
-        (`_isolate`): the call pads to the parent's bucket, so it runs
-        the parent's own executable, and is counted as a probe."""
+        (`_isolate`), and is then counted as a probe. Every call names the
+        verifier's one batch bucket as its floor (`bucket_floor`), so a
+        first pass of 1..max_batch items and a probe of any part of it run
+        the same executable; a probe also names its parent's widest
+        committee, so it stays in the parent's width bucket."""
         backend = self._ensure_backend()
         if not _health.has_async_seam(backend):
             return None
@@ -531,22 +560,24 @@ class AttestationVerifier:
         sigs = [A.Signature(p) for p in points]
         if self.metrics is not None:
             self.metrics.device_batch_sigs.inc(len(sigs))
-        # a probe names its parent's sizes as the bucket's floor; a first
-        # pass passes nothing, so a foreign backend's seam stays as it was
-        pin = {}
-        if parent is not None:
-            pin["bucket_floor"] = (
-                len(parent), max(len(p[5]) for p in parent)
-            )
-            self._count_probe(len(prepared), len(parent))
+        # padding slots carry no verdict and change none (tpu/bls.py
+        # `bucket_floor`): the width axis keeps its own bucket, which a
+        # probe takes from its parent
+        if parent is None:
+            floor = (self.batch_bucket, 0)
+            self._count_first_pass(len(prepared))
+        else:
+            floor = (self.batch_bucket, max(len(p[5]) for p in parent))
+            self._count_probe(len(prepared))
         registry = self._sync_registry(prepared)
         if registry is not None:
             ver_settle = backend.fast_aggregate_verify_batch_indexed_async(
-                messages, sigs, [p[5] for p in prepared], registry, **pin
+                messages, sigs, [p[5] for p in prepared], registry,
+                bucket_floor=floor,
             )
         else:
             ver_settle = backend.fast_aggregate_verify_batch_async(
-                messages, sigs, [p[2] for p in prepared], **pin
+                messages, sigs, [p[2] for p in prepared], bucket_floor=floor,
             )
 
         def settle() -> bool:
@@ -813,7 +844,7 @@ class AttestationVerifier:
         descent.probes += 1
         with self.tracer.span("probe", {
             "op": "probe", "items": hi - lo,
-            "bucket": _flight.bucket_of(len(parent)), "depth": depth,
+            "bucket": self.batch_bucket, "depth": depth,
             "why": why,
         }):
             try:
@@ -825,13 +856,16 @@ class AttestationVerifier:
         if self.metrics is not None:
             self.metrics.att_isolation_inferred.inc()
 
-    def _count_probe(self, items: int, parent_items: int) -> None:
+    def _count_probe(self, items: int) -> None:
         if self.metrics is not None:
             self.metrics.att_isolation_probes.inc()
             self.metrics.att_isolation_probe_items.inc(items)
-            self.metrics.att_isolation_probe_slots.inc(
-                _flight.bucket_of(parent_items)
-            )
+            self.metrics.att_isolation_probe_slots.inc(self.batch_bucket)
+
+    def _count_first_pass(self, items: int) -> None:
+        if self.metrics is not None:
+            self.metrics.att_first_pass_items.inc(items)
+            self.metrics.att_first_pass_slots.inc(self.batch_bucket)
 
     def _prevalidate(self, state, attestation):
         """Committee lookup + fork-choice windows; returns

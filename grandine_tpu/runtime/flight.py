@@ -101,7 +101,9 @@ DEFAULT_SLO_BUDGET_S = 4.0  # unknown lanes
 
 def bucket_of(items: int) -> int:
     """The pow-2 device bucket `items` pads into (the shape the kernel
-    manifest compiles; tools/shapes bucketing)."""
+    manifest compiles; tools/shapes bucketing). What a lane records when
+    it names no bucket of its own; the attestation firehose names the one
+    bucket it dispatches every batch in (`begin_batch(bucket=)`)."""
     n = max(1, int(items))
     return 1 << (n - 1).bit_length()
 
@@ -135,6 +137,7 @@ class BatchRecord:
         "slo_miss", "slo_cause", "origin", "note", "devices",
         "quarantined", "brownout", "trace_id", "collect_wait_s",
         "pool_wait_s", "dispatch_wait_s", "settle_wait_s", "settle_s",
+        "closed_by",
     )
 
     def __init__(self, kind: str, lane: str) -> None:
@@ -177,6 +180,10 @@ class BatchRecord:
         self.settle_wait_s = 0.0
         #: forcing the verdict: device remainder + readback
         self.settle_s = 0.0
+        #: what closed the firehose batch at the collector: "full" (the
+        #: batch bound), "deadline" (its first item's deadline) or "stop";
+        #: "" on lanes that form no batches this way
+        self.closed_by = ""
         self.verdict: "Optional[bool]" = None
         self.fault: "Optional[str]" = None
         self.retries = 0
@@ -238,6 +245,7 @@ class BatchRecord:
             "dispatch_wait_s": round(self.dispatch_wait_s, 6),
             "settle_wait_s": round(self.settle_wait_s, 6),
             "settle_s": round(self.settle_s, 6),
+            "closed_by": self.closed_by,
         }
 
 
@@ -425,13 +433,16 @@ class FlightRecorder:
                     queue_wait_s: float = 0.0,
                     breaker_state: str = "",
                     devices: int = 1,
-                    quarantined: bool = False) -> BatchFlight:
+                    quarantined: bool = False,
+                    bucket: "Optional[int]" = None) -> BatchFlight:
         """Open one batch's flight context at dispatch time. Fill/waste
-        are derived from the pow-2 bucket the device actually pads to."""
+        are derived from the bucket the device actually pads to: the one
+        the lane names (`bucket`: the firehose's one batch bucket), else
+        the pow-2 bucket of `items`."""
         rec = BatchRecord(BATCH, lane)
         rec.kernel = kernel
         rec.items = int(items)
-        rec.bucket = bucket_of(items)
+        rec.bucket = int(bucket) if bucket else bucket_of(items)
         rec.fill = rec.items / rec.bucket if rec.bucket else 0.0
         rec.queue_wait_s = max(0.0, float(queue_wait_s))
         rec.breaker_state = breaker_state

@@ -166,8 +166,10 @@ def warm_all(
     warmed. All state here is thread-local; the shared ledger/cache
     seams take their own locks. A cold entry of a pairing kernel costs
     minutes and ~6 GB of host memory at its peak, so a caller names the
-    entries its lanes can dispatch (cli `_firehose_warm_plan`) rather
-    than warming the whole manifest on a cold machine.
+    entries its lanes can dispatch (cli `_firehose_warm_plan`: the
+    attestation firehose's ONE batch bucket x its committee widths; the
+    manifest's batch ladders are the scheduler lanes', which flush at any
+    size) rather than warming the whole manifest on a cold machine.
 
     `committee_width` is the member count of the widest committee the
     indexed aggregate rows will see: the member axis is bucketed too, and

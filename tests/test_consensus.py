@@ -254,3 +254,22 @@ def test_pubkey_cache_and_aggregate():
     assert agg == A.PublicKey.aggregate(many)
     with pytest.raises(A.BlsError):
         keys.aggregate_pubkeys([])
+
+
+@pytest.mark.parametrize("first", ["minimal", "mainnet"])
+def test_committee_partition_is_cached_per_preset(first):
+    """One process can hold states of two presets that share the shuffle
+    seed and the active set (the benchmark's tiny cells and the replay
+    generator both found 64 validators on the same mix): each preset gets
+    ITS partition, whichever came first, not the other's from the cache."""
+    seed = bytes([0x5A, first == "minimal"]) * 16
+    active = np.arange(64, dtype=np.uint64)
+    presets = {"minimal": Config.minimal().preset,
+               "mainnet": Config.mainnet().preset}
+    order = [first] + [name for name in presets if name != first]
+    for name in order:
+        p = presets[name]
+        got = accessors.committee_partition(seed, active, p)
+        want = accessors.committee_count_per_slot(64, p) * p.SLOTS_PER_EPOCH
+        assert len(got) == want, name
+        assert sorted(int(i) for c in got for i in c) == list(range(64))

@@ -243,7 +243,8 @@ class _HandshakeBackend:
         n = len(points)
         return lambda: np.ones((n,), bool)
 
-    def fast_aggregate_verify_batch_async(self, messages, sigs, members):
+    def fast_aggregate_verify_batch_async(self, messages, sigs, members,
+                                          bucket_floor=None):
         k = self.dispatches
         self.dispatches += 1
         self.dispatched[k].set()

@@ -13,6 +13,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
@@ -259,16 +261,26 @@ def test_compile_cache_can_be_placed(monkeypatch, tmp_path):
         jax.config.update("jax_compilation_cache_dir", before)
 
 
-def test_firehose_warm_plan_reads_the_head_state():
+@pytest.mark.parametrize("validators,bucket,networked,want", [
+    (64, 64, False, [(64, 4)]),
+    (64, 64, True, [(64, 4)]),
+    # 512 validators: 4 committees a slot of 16 members
+    (512, 64, False, [(64, 16)]),
+    (512, 64, True, [(64, 4), (64, 8), (64, 16)]),
+    # the batch axis is the verifier's one bucket, whatever it is
+    (512, 8, True, [(8, 4), (8, 8), (8, 16)]),
+])
+def test_firehose_warm_plan_reads_the_head_state(validators, bucket,
+                                                 networked, want):
     """`cli run --use-device` warms what ITS firehose can dispatch, not
-    the manifest: one (batch, width) pair for a gossip-less devnet, the
-    two ladders for a networked node."""
+    the manifest: ONE batch bucket (the verifier pads a batch of any size
+    into `batch_bucket`) x the committee widths the head state can
+    produce: the committees' own for a gossip-less devnet, every width up
+    to the widest for a networked node."""
     from grandine_tpu.cli import _firehose_warm_plan
     from grandine_tpu.transition.genesis import interop_genesis_state
     from grandine_tpu.types.config import Config
 
     cfg = Config.minimal()
-    state = interop_genesis_state(64, cfg)
-    assert _firehose_warm_plan(state, cfg, 64, networked=False) == [(4, 4)]
-    plan = _firehose_warm_plan(state, cfg, 64, networked=True)
-    assert plan == [(b, 4) for b in (4, 8, 16, 32, 64)]
+    state = interop_genesis_state(validators, cfg)
+    assert _firehose_warm_plan(state, cfg, bucket, networked) == want

@@ -226,6 +226,8 @@ def smoke_verify_kernels():
             [sig] * per_slot,
             [list(range(width))] * per_slot,
             _Rows(S.NODE_VALIDATORS),
+            # as the node's verifier names it: its one batch bucket
+            bucket_floor=(S.AGG_ITEMS, 0),
         )
     for kernel, fn, args in seen:
         shape = "x".join(str(d) for d in args[2].shape)
@@ -241,7 +243,7 @@ def test_smoke_routes_to_the_expected_kernels():
     assert labels == [
         "grouped_multi_verify_msm[256x64]",
         "agg_fast_verify_msm_idx[64x256]",
-        "agg_fast_verify_msm_idx[4x4]",
+        "agg_fast_verify_msm_idx[64x4]",
     ]
 
 
