@@ -377,6 +377,12 @@ class Metrics:
             "gossip batches formed, by what closed them",
             ("by",),
         )
+        # a batch that met its deadline short of the bound and found the
+        # pipeline full (pipeline_depth calls + one batch preparing): it
+        # waits at the collector for a slot, counted when the hold begins
+        self.att_batches_held = Counter(
+            "attestation_batches_held_total",
+            "short gossip batches held at the collector for a pipeline slot")
         # a first pass of any size runs in the node's one batch bucket:
         # items / slots is what padding a partial batch costs (twins of
         # the probe pair below)

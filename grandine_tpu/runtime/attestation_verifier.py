@@ -58,19 +58,25 @@ class _BatchLife:
     """What travels with one batch from the collector to its verdict: the
     root span of its chain and the stamps its waits are measured from."""
 
-    __slots__ = ("root", "arrived", "popped", "pool_span", "closed_by")
+    __slots__ = ("root", "arrived", "popped", "pool_span", "closed_by",
+                 "held_s")
 
-    def __init__(self, tracer, batch, popped: float, closed_by: str) -> None:
+    def __init__(self, tracer, batch, popped: float, closed_by: str,
+                 held_s: float) -> None:
         #: arrival of the batch's oldest item: where its life begins
         self.arrived = min(it.arrived for it in batch)
         self.popped = popped
         #: what closed the batch: "full", "deadline" or "stop"
         self.closed_by = closed_by
+        #: how long the batch stood past its deadline for a pipeline slot
+        #: (inside collect_wait; 0.0: it was not held)
+        self.held_s = held_s
         self.root = tracer.span(
             "verify_batch", {"batch": len(batch)}, start=self.arrived
         )
         tracer.span(
-            "collect_wait", {"closed_by": closed_by, "items": len(batch)},
+            "collect_wait",
+            {"closed_by": closed_by, "items": len(batch), "held_s": held_s},
             parent=self.root, start=self.arrived,
         ).finish()
         self.pool_span = tracer.span("pool_wait", parent=self.root)
@@ -176,6 +182,10 @@ class AttestationVerifier:
         self._queue: "deque[GossipAttestation]" = deque()
         self._cond = threading.Condition()
         self._active = 0
+        #: batches popped from the queue and not yet resolved (delivered,
+        #: refused, or dropped by a settle error), whichever thread
+        #: resolves them: what the collector holds a SHORT batch against
+        self._outstanding = 0
         self._stop = False
         self.stats = {
             "batches": 0, "accepted": 0, "rejected": 0, "fallbacks": 0,
@@ -293,8 +303,27 @@ class AttestationVerifier:
                 and (remaining := deadline - time.monotonic()) > 0
             ):
                 self._cond.wait(remaining)
-            # respect the concurrent-batch bound before dispatching
-            while not self._stop and self._active >= self.max_active:
+            # respect the concurrent-batch bound before dispatching. A
+            # batch that can leave full takes any active slot. One that
+            # met its deadline short is HELD while the pipeline is full
+            # (`pipeline_depth` calls the device holds, one batch preparing
+            # beside them): its call could not start before one of those
+            # ends, so it waits here, not in the device's stream, and what
+            # arrives meanwhile rides in its call. Re-read on every wake
+            # (a submit, a batch resolved): it leaves when a slot frees or
+            # when it has filled, whichever comes first
+            held_at = None
+            while not self._stop:
+                held = (
+                    len(self._queue) < self.max_batch
+                    and self._outstanding >= self.pipeline_depth + 1
+                )
+                if not held and self._active < self.max_active:
+                    break
+                if held and held_at is None:
+                    held_at = time.perf_counter()
+                    if self.metrics is not None:
+                        self.metrics.att_batches_held.inc()
                 self._cond.wait()
             if self._stop and not self._queue:
                 return True
@@ -305,9 +334,11 @@ class AttestationVerifier:
             if not batch:
                 return False
             self._active += 1
+            self._outstanding += 1
+            popped = time.perf_counter()
             # what bounded the batch as it leaves: a batch that met its
-            # deadline short and filled while it waited for an active slot
-            # leaves full
+            # deadline short and filled while it waited for a slot leaves
+            # full
             closed_by = (
                 "full" if len(batch) >= self.max_batch
                 else "stop" if self._stop else "deadline"
@@ -316,16 +347,20 @@ class AttestationVerifier:
             self.metrics.att_batches_closed.inc(closed_by)
         # the batch's chain of spans begins here, back-dated to the
         # arrival of its oldest item: collect_wait is over, pool_wait runs
-        life = _BatchLife(self.tracer, batch, time.perf_counter(), closed_by)
+        life = _BatchLife(
+            self.tracer, batch, popped, closed_by,
+            0.0 if held_at is None else popped - held_at,
+        )
         try:
             self.controller.pool.spawn(
                 lambda: self._verify_batch(batch, life), Priority.LOW
             )
         except Exception:
-            # pool stopped / spawn failure: release the active slot so
-            # the collector cannot wedge on max_active
+            # pool stopped / spawn failure: release the batch's slots so
+            # the collector cannot wedge on them
             with self._cond:
                 self._active -= 1
+                self._outstanding -= 1
                 self._cond.notify_all()
             life.root.finish()
             raise
@@ -351,15 +386,20 @@ class AttestationVerifier:
         flight record commits — here, or on the completion thread."""
         t_batch = time.perf_counter()
         life.pool_span.finish()
+        handed_over = False
         try:
             with self.tracer.attach(life.root):
-                self._verify_batch_traced(batch, life, t_batch)
+                handed_over = self._verify_batch_traced(batch, life, t_batch)
         except BaseException:
             life.root.finish()  # no record committed: end the chain here
             raise
         finally:
             with self._cond:
                 self._active -= 1
+                if not handed_over:
+                    # resolved (or lost) on this thread; a batch handed
+                    # over stays outstanding until `_complete` is done
+                    self._outstanding -= 1
                 self._cond.notify()
             with self._stats_lock:
                 self.stats["batches"] += 1
@@ -370,7 +410,9 @@ class AttestationVerifier:
                 )
 
     def _verify_batch_traced(self, batch: "Sequence[GossipAttestation]",
-                             life: _BatchLife, t_start: float) -> None:
+                             life: _BatchLife, t_start: float) -> bool:
+        """True when the batch was handed to the completion thread, which
+        then resolves it; otherwise it is resolved on return."""
         snapshot = self.controller.snapshot()
         state = snapshot.head_state
         prepared = []
@@ -388,7 +430,7 @@ class AttestationVerifier:
                         self.stats["rejected"] += 1
         if not prepared:
             life.root.finish()
-            return
+            return False
         # what the SLO tracker charges to the queue: the oldest item's
         # arrival to here, i.e. collect_wait + pool_wait + prevalidation
         fl = self.flight.begin_batch(
@@ -401,6 +443,7 @@ class AttestationVerifier:
         fl.trace(life.root)
         fl.record.closed_by = life.closed_by
         fl.record.collect_wait_s = life.popped - life.arrived
+        fl.record.held_s = life.held_s
         fl.record.pool_wait_s = max(0.0, t_start - life.popped)
         skipped = False
         if self.use_device and self._completion is not None:
@@ -428,7 +471,7 @@ class AttestationVerifier:
                     # host_prep while the device executes this one
                     fl.record.kernel = "fast_aggregate"
                     self._enqueue_settle(settle, prepared, fl)
-                    return
+                    return True
         t0 = time.perf_counter()
         ok = self._batch_check(prepared)
         dt = time.perf_counter() - t0
@@ -437,6 +480,7 @@ class AttestationVerifier:
         else:
             fl.note_host(dt)
         self._resolve_batch(prepared, ok, fl)
+        return False
 
     def _resolve_batch(self, prepared, ok: bool, fl=None) -> None:
         """Deliver a settled batch verdict: feedback on success, bisection
@@ -706,6 +750,7 @@ class AttestationVerifier:
                 self._dispatch_sem.release()
                 with self._cond:
                     self._inflight -= 1
+                    self._outstanding -= 1
                     depth = self._inflight
                     self._cond.notify_all()
                 if self.metrics is not None:

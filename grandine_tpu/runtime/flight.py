@@ -137,7 +137,7 @@ class BatchRecord:
         "slo_miss", "slo_cause", "origin", "note", "devices",
         "quarantined", "brownout", "trace_id", "collect_wait_s",
         "pool_wait_s", "dispatch_wait_s", "settle_wait_s", "settle_s",
-        "closed_by",
+        "closed_by", "held_s",
     )
 
     def __init__(self, kind: str, lane: str) -> None:
@@ -171,6 +171,10 @@ class BatchRecord:
         #: (all `perf_counter` deltas; 0.0 where the path has no such
         #: wait): oldest item's arrival -> the collector pops the batch
         self.collect_wait_s = 0.0
+        #: the part of collect_wait_s the batch stood at the collector
+        #: PAST its deadline, short of the batch bound, because the
+        #: pipeline had no slot for it (0.0: it was not held)
+        self.held_s = 0.0
         #: batch popped -> its task starts on a pool thread
         self.pool_wait_s = 0.0
         #: the pool thread blocked on the pipeline's dispatch semaphore
@@ -241,6 +245,7 @@ class BatchRecord:
             "brownout": self.brownout,
             "trace_id": self.trace_id,
             "collect_wait_s": round(self.collect_wait_s, 6),
+            "held_s": round(self.held_s, 6),
             "pool_wait_s": round(self.pool_wait_s, 6),
             "dispatch_wait_s": round(self.dispatch_wait_s, 6),
             "settle_wait_s": round(self.settle_wait_s, 6),
