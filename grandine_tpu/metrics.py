@@ -392,6 +392,25 @@ class Metrics:
         self.att_first_pass_slots = Counter(
             "attestation_first_pass_slots_total",
             "padded batch slots of those calls")
+        # the member axis has one bucket a node too, the width floor's
+        # (the widest committee dispatched so far): members / member slots
+        # is how much of the padded member matrix a first pass fills
+        self.att_first_pass_members = Counter(
+            "attestation_first_pass_members_total",
+            "real committee members in first-pass device calls")
+        self.att_first_pass_member_slots = Counter(
+            "attestation_first_pass_member_slots_total",
+            "padded member slots of those calls (batch slots x width bucket)")
+        self.att_width_floor_raised = Counter(
+            "attestation_width_floor_raised_total",
+            "times the firehose's width floor moved to a higher member bucket")
+        self.att_width_bucket = Gauge(
+            "attestation_width_bucket",
+            "member bucket the firehose's device calls run in")
+        self.att_mixed_batches = Counter(
+            "attestation_mixed_batches_total",
+            "gossip batches whose narrowest and widest item fall in "
+            "different member buckets")
         # the descent over a failed batch (_isolate): its probes are
         # calls of the batch's own executable, padded to the batch's
         # bucket — items / slots is what that padding costs

@@ -13,7 +13,10 @@ the batch bound keeps device launches dense when it's fast. A batch of any
 size 1..max_batch is dispatched padded into ONE batch bucket
 (`AttestationVerifier.batch_bucket`): the kernel's time is flat in its
 batch axis, so a smaller native bucket buys nothing and costs an
-executable (minutes to compile, ~80 s to load).
+executable (minutes to compile, ~80 s to load). The member axis has ONE
+bucket a node too, chosen by what the verifier has seen: the widest
+committee's, from the first call that held one on (`width_floor`), so a
+queue of single votes AND aggregates runs one resident executable.
 """
 
 from __future__ import annotations
@@ -34,6 +37,12 @@ from grandine_tpu.runtime.thread_pool import Priority
 from grandine_tpu.tracing import NULL_TRACER, stage as _stage
 
 MAX_BATCH = 64  # attestation_verifier.rs:37
+
+
+def _width_bucket(width: int) -> int:
+    """The member-axis bucket a widest committee of `width` pads into
+    (tpu/bls.py `_bucket(width, lo=4)`, without importing JAX)."""
+    return max(4, _flight.bucket_of(width))
 
 
 class GossipAttestation:
@@ -196,6 +205,17 @@ class AttestationVerifier:
         self._stats_lock = threading.Lock()
         #: guards the lazy TpuBlsBackend build (pool workers race to it)
         self._backend_lock = threading.Lock()
+        #: the widest committee of any device call built so far, kept only
+        #: where it reaches a higher member bucket than the floor's (0: none
+        #: has left bucket 4 yet). Every call names it beside the batch
+        #: bucket, so once one full-size aggregate has gone out, every
+        #: later call, a single vote included, runs THAT executable. Raised
+        #: under `_width_lock` where a call is built (`_device_dispatch`),
+        #: never lowered; a width is bound by the head state's committees
+        #: (`get_attesting_indices`), so no peer raises it past the
+        #: network's own bucket
+        self._width_floor = 0
+        self._width_lock = threading.Lock()
 
         #: device-resident pubkey registry (tpu/registry.py): the verify
         #: plane's warm path gathers committee pubkeys on-device by
@@ -245,6 +265,19 @@ class AttestationVerifier:
         of a failed batch): what the warm-up compiles per committee width
         and what the flight row records."""
         return _flight.bucket_of(self.max_batch)
+
+    @property
+    def width_floor(self) -> int:
+        """The widest committee every later device call is padded to at
+        the least (0: nothing wider than bucket 4 dispatched yet)."""
+        with self._width_lock:
+            return self._width_floor
+
+    @property
+    def width_bucket(self) -> int:
+        """The member bucket of `width_floor`: what a call of single votes
+        runs in today."""
+        return _width_bucket(self.width_floor)
 
     # ----------------------------------------------------------- ingestion
 
@@ -445,6 +478,14 @@ class AttestationVerifier:
         fl.record.collect_wait_s = life.popped - life.arrived
         fl.record.held_s = life.held_s
         fl.record.pool_wait_s = max(0.0, t_start - life.popped)
+        # an item's width is its attesting indices: known from here on
+        widths = [len(p[5]) for p in prepared]
+        fl.record.width, fl.record.width_min = max(widths), min(widths)
+        life.root.set_attr("width", fl.record.width)
+        life.root.set_attr("width_min", fl.record.width_min)
+        if (self.metrics is not None and _width_bucket(fl.record.width_min)
+                != _width_bucket(fl.record.width)):
+            self.metrics.att_mixed_batches.inc()
         skipped = False
         if self.use_device and self._completion is not None:
             if not self.health.allow_device():
@@ -456,7 +497,7 @@ class AttestationVerifier:
             else:
                 t0 = time.perf_counter()
                 try:
-                    settle = self._device_dispatch(prepared)
+                    settle = self._device_dispatch(prepared, fl=fl)
                     fl.note_device(time.perf_counter() - t0)
                 except Exception:
                     fl.note_device(time.perf_counter() - t0)
@@ -555,17 +596,19 @@ class AttestationVerifier:
 
     # ------------------------------------------------------------ pipeline
 
-    def _device_dispatch(self, prepared, parent=None):
+    def _device_dispatch(self, prepared, parent=None, fl=None):
         """Host prep + async device dispatch for one prepared batch.
         Returns a zero-arg settle callable producing the batch verdict, or
         None when the backend lacks the async seam (`_batch_check` then
         answers from the host anchor). `parent` is the failed batch that
         `prepared` is a part of, when the call is a probe of its descent
         (`_isolate`), and is then counted as a probe. Every call names the
-        verifier's one batch bucket as its floor (`bucket_floor`), so a
-        first pass of 1..max_batch items and a probe of any part of it run
-        the same executable; a probe also names its parent's widest
-        committee, so it stays in the parent's width bucket."""
+        verifier's one batch bucket and its width floor as its floor
+        (`bucket_floor`), so a first pass of 1..max_batch items of any
+        width seen so far and a probe of any part of it run the same
+        executable; a probe also names its parent's widest committee, so
+        it stays in the parent's width bucket. `fl` is the first pass's
+        flight context: it is told the width bucket dispatched."""
         backend = self._ensure_backend()
         if not _health.has_async_seam(backend):
             return None
@@ -605,14 +648,22 @@ class AttestationVerifier:
         if self.metrics is not None:
             self.metrics.device_batch_sigs.inc(len(sigs))
         # padding slots carry no verdict and change none (tpu/bls.py
-        # `bucket_floor`): the width axis keeps its own bucket, which a
-        # probe takes from its parent
+        # `bucket_floor`): the width axis runs in the floor's bucket, and
+        # a probe in its parent's at the least
+        widest = max(len(p[5]) for p in (prepared if parent is None
+                                         else parent))
+        width_floor = self._raise_width_floor(widest)
+        width_bucket = _width_bucket(width_floor)
         if parent is None:
-            floor = (self.batch_bucket, 0)
-            self._count_first_pass(len(prepared))
+            floor = (self.batch_bucket, width_floor)
+            self._count_first_pass(prepared, width_bucket)
         else:
-            floor = (self.batch_bucket, max(len(p[5]) for p in parent))
+            floor = (self.batch_bucket, max(width_floor, widest))
             self._count_probe(len(prepared))
+        if fl is not None:
+            fl.record.width_bucket = width_bucket
+            if fl.root is not None:
+                fl.root.set_attr("width_bucket", width_bucket)
         registry = self._sync_registry(prepared)
         if registry is not None:
             ver_settle = backend.fast_aggregate_verify_batch_indexed_async(
@@ -663,7 +714,7 @@ class AttestationVerifier:
             fl.note_retry()
         t0 = time.perf_counter()
         try:
-            return self._device_dispatch(prepared)
+            return self._device_dispatch(prepared, fl=fl)
         except Exception:
             self.health.record_fault("dispatch")
             if fl is not None:
@@ -907,10 +958,28 @@ class AttestationVerifier:
             self.metrics.att_isolation_probe_items.inc(items)
             self.metrics.att_isolation_probe_slots.inc(self.batch_bucket)
 
-    def _count_first_pass(self, items: int) -> None:
+    def _count_first_pass(self, prepared, width_bucket: int) -> None:
         if self.metrics is not None:
-            self.metrics.att_first_pass_items.inc(items)
+            self.metrics.att_first_pass_items.inc(len(prepared))
             self.metrics.att_first_pass_slots.inc(self.batch_bucket)
+            self.metrics.att_first_pass_members.inc(
+                sum(len(p[5]) for p in prepared))
+            self.metrics.att_first_pass_member_slots.inc(
+                self.batch_bucket * width_bucket)
+
+    def _raise_width_floor(self, widest: int) -> int:
+        """The width floor for a call whose widest committee is `widest`:
+        raised to it first where that reaches a higher member bucket."""
+        with self._width_lock:
+            raised = _width_bucket(widest) > _width_bucket(self._width_floor)
+            if raised:
+                self._width_floor = widest
+            floor = self._width_floor
+        if self.metrics is not None:
+            if raised:
+                self.metrics.att_width_floor_raised.inc()
+            self.metrics.att_width_bucket.set(_width_bucket(floor))
+        return floor
 
     def _prevalidate(self, state, attestation):
         """Committee lookup + fork-choice windows; returns

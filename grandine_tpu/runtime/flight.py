@@ -137,7 +137,7 @@ class BatchRecord:
         "slo_miss", "slo_cause", "origin", "note", "devices",
         "quarantined", "brownout", "trace_id", "collect_wait_s",
         "pool_wait_s", "dispatch_wait_s", "settle_wait_s", "settle_s",
-        "closed_by", "held_s",
+        "closed_by", "held_s", "width", "width_min", "width_bucket",
     )
 
     def __init__(self, kind: str, lane: str) -> None:
@@ -188,6 +188,12 @@ class BatchRecord:
         #: batch bound), "deadline" (its first item's deadline) or "stop";
         #: "" on lanes that form no batches this way
         self.closed_by = ""
+        #: the firehose batch's widest and narrowest committee (attesting
+        #: indices of an item) and the member bucket its first pass was
+        #: DISPATCHED in (the verifier's width floor's; 0: no device call)
+        self.width = 0
+        self.width_min = 0
+        self.width_bucket = 0
         self.verdict: "Optional[bool]" = None
         self.fault: "Optional[str]" = None
         self.retries = 0
@@ -251,6 +257,9 @@ class BatchRecord:
             "settle_wait_s": round(self.settle_wait_s, 6),
             "settle_s": round(self.settle_s, 6),
             "closed_by": self.closed_by,
+            "width": self.width,
+            "width_min": self.width_min,
+            "width_bucket": self.width_bucket,
         }
 
 

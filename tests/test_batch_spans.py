@@ -89,9 +89,9 @@ def drive(genesis, metrics, tracer, device: bool, then=None):
     verifier.dispatched = []
     dispatch = verifier._device_dispatch
 
-    def recording(prepared, parent=None):
+    def recording(prepared, parent=None, **kw):
         verifier.dispatched.append(prepared)
-        return dispatch(prepared, parent)
+        return dispatch(prepared, parent, **kw)
 
     verifier._device_dispatch = recording
     try:
