@@ -177,6 +177,7 @@ def release_executables(what: str) -> None:
 
     before = _rss()
     jax.clear_caches()
+    gc.unfreeze()  # compile_scope.settle_heap froze them with the rest
     gc.collect()
     trim_host_memory()
     _say(released=what, rss_before=before, rss=_rss())

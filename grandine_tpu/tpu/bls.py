@@ -157,35 +157,51 @@ def _flat_km(arr, m: int, k: int):
     return jnp.swapaxes(a, 0, 1).reshape((k * m,) + a.shape[2:])
 
 
-def _rlc_finish(f, sig_acc_jac):
-    """Multiply the accumulated Fp12 product by the single e(−g1, Σ rᵢ·sigᵢ)
-    factor and run the shared final exponentiation. The one place (single-
-    and multi-chip) that evaluates the RLC product equation."""
-    sig_inf = F.fp2_is_zero(sig_acc_jac[2])
-    sig_h = TP.jacobian_to_homogeneous(sig_acc_jac)
-    neg_x = L.const_fp([int(d) for d in _NEG_G1_DEV[0]], (1,))
-    neg_y = L.const_fp([int(d) for d in _NEG_G1_DEV[1]], (1,))
-    neg_z = L.const_fp(L.ONE_MONT_DIGITS, (1,))
-    sig_h1 = tuple(F.lead2(c) for c in sig_h)
-    f_sig = TP.miller_loop((neg_x, neg_y, neg_z), sig_h1, sig_inf[None])
-    f_total = F.fp12_mul(f, tuple(F.take6(c, 0) for c in f_sig))
-    return TP.final_exp_is_one(f_total)
+def _neg_g1(n: int):
+    """The constant −g1 as an (n,)-batched Jacobian G1 point (Z = 1)."""
+    return (
+        L.const_fp([int(d) for d in _NEG_G1_DEV[0]], (n,)),
+        L.const_fp([int(d) for d in _NEG_G1_DEV[1]], (n,)),
+        L.const_fp(L.ONE_MONT_DIGITS, (n,)),
+    )
 
 
 def _rlc_finish_grouped(f_groups, sig_acc_jac, g: int):
-    """Width-g generalization of _rlc_finish: f_groups is a (g,)-batched
+    """The RLC product equation once a GROUP: f_groups is a (g,)-batched
     Fp12 (per-group Miller products), sig_acc_jac a (g,)-batched Jacobian
     G2 (per-group Σ rᵢ·sigᵢ). Each group gets its own e(−g1, ·) factor and
     the shared final exponentiation runs ONCE at width g — the per-group
     verdicts cost one device pass, not g."""
     sig_inf = F.fp2_is_zero(sig_acc_jac[2])
     sig_h = TP.jacobian_to_homogeneous(sig_acc_jac)
-    neg_x = L.const_fp([int(d) for d in _NEG_G1_DEV[0]], (g,))
-    neg_y = L.const_fp([int(d) for d in _NEG_G1_DEV[1]], (g,))
-    neg_z = L.const_fp(L.ONE_MONT_DIGITS, (g,))
-    f_sig = TP.miller_loop((neg_x, neg_y, neg_z), sig_h, sig_inf)
+    f_sig = TP.miller_loop(_neg_g1(g), sig_h, sig_inf)
     f_total = F.fp12_mul(f_groups, f_sig)
     return TP.final_exp_is_one(f_total)
+
+
+def _rlc_miller_product(rpk_jac, pair_inf, msg_x, msg_y, sig_acc_jac,
+                        sig_off=None):
+    """∏ e(rᵢ·pkᵢ, H(mᵢ)) · e(−g1, Σ rᵢ·sigᵢ) ahead of the final
+    exponentiation, from ONE batched Miller loop: the n message pairs and
+    the signature pair (−g1, Σ rᵢ·sigᵢ) as lane n. A Miller loop of its own
+    for that one pair ran with the limbs on the lanes and cost nearly three
+    times the batched loop (limbs.py LANE_FLOOR); as a 65th lane it is
+    free. The one place (single- and multi-chip) that evaluates the RLC
+    product equation's left side. `sig_off` (a traced bool) masks the
+    signature lane: the multi-chip callers keep the factor on one chip."""
+    n = msg_x[0].shape[1]
+    sig_inf = F.fp2_is_zero(sig_acc_jac[2])
+    if sig_off is not None:
+        sig_inf = jnp.logical_or(sig_inf, sig_off)
+    sig_h = TP.jacobian_to_homogeneous(sig_acc_jac)
+    # message points: affine → homogeneous projective on the twist
+    msg_q = (msg_x, msg_y, F.fp2_one((n,)))
+    P = tuple(L.concat_fp([a, b]) for a, b in zip(rpk_jac, _neg_g1(1)))
+    Q = tuple(F.cat2([a, F.lead2(b)]) for a, b in zip(msg_q, sig_h))
+    inf = jnp.concatenate([pair_inf, sig_inf[None]])
+    f = TP.miller_loop(P, Q, inf)
+    f_msgs = TP.fp12_product_tree(tuple(F.slice6(c, 0, n) for c in f))
+    return F.fp12_mul(f_msgs, tuple(F.take6(c, n) for c in f))
 
 
 def _rlc_pairing_check(rpk_jac, pair_inf, msg_x, msg_y, sig_acc_jac):
@@ -195,12 +211,10 @@ def _rlc_pairing_check(rpk_jac, pair_inf, msg_x, msg_y, sig_acc_jac):
 
         ∏ e(rᵢ·pkᵢ, H(mᵢ)) · e(−g1, Σ rᵢ·sigᵢ) == 1
 
-    with one shared final exponentiation."""
-    n = msg_x[0].shape[1]
-    # message points: affine → homogeneous projective on the twist
-    msg_q = (msg_x, msg_y, F.fp2_one((n,)))
-    f_msgs = TP.miller_loop(rpk_jac, msg_q, pair_inf)
-    return _rlc_finish(TP.fp12_product_tree(f_msgs), sig_acc_jac)
+    with one Miller loop and one shared final exponentiation."""
+    return TP.final_exp_is_one(
+        _rlc_miller_product(rpk_jac, pair_inf, msg_x, msg_y, sig_acc_jac)
+    )
 
 
 def _psi_ladder_check(P, inf, x_bits):
@@ -1035,17 +1049,17 @@ def make_sharded_multi_verify(mesh, axis: str = "batch",
             sig[0], sig[1], sig_inf, lo, hi, _g2_endo(n_local), C.FP2_OPS
         )
         sX, sY, sZ = C.sum_points(rsig, C.FP2_OPS)  # local G2 partial sum
-        n = msg_x.shape[0]
-        msg_q = (msg[0], msg[1], F.fp2_one((n,)))
-        f_local = TP.fp12_product_tree(
-            TP.miller_loop(rpk, msg_q, pk_inf | msg_inf)
-        )
         # cross-chip: gather the per-chip partials (tiny), finish replicated.
         # Each limb array is a scalar per chip → all_gather yields (n_dev,).
-        f_all = gather_tree(f_local)
         sig_all = gather_tree((sX, sY, sZ))
         sig_acc = C.sum_points(sig_all, C.FP2_OPS)
-        ok = _rlc_finish(TP.fp12_product_tree(f_all), sig_acc)
+        # the signature pair rides in chip 0's Miller loop alone
+        f_local = _rlc_miller_product(
+            rpk, pk_inf | msg_inf, msg[0], msg[1], sig_acc,
+            sig_off=lax.axis_index(axis) != 0,
+        )
+        f_all = gather_tree(f_local)
+        ok = TP.final_exp_is_one(TP.fp12_product_tree(f_all))
         if check_subgroup:
             # fused ψ membership: each chip checks its local signature
             # rows, one bool crosses the mesh
@@ -1229,12 +1243,15 @@ def make_sharded_multi_verify_msm(
         pair_inf = lax.dynamic_slice_in_dim(
             L.is_zero_val(gpk[2]) | msg_inf_l, start, m_loc, axis=0
         )
-        msg_q = (msg_s[0], msg_s[1], F.fp2_one((m_loc,)))
-        f_local = TP.fp12_product_tree(TP.miller_loop(gpk_s, msg_q, pair_inf))
+        # the signature pair rides in chip 0's Miller loop alone
+        f_local = _rlc_miller_product(
+            gpk_s, pair_inf, msg_s[0], msg_s[1], sig_acc,
+            sig_off=lax.axis_index(axis) != 0,
+        )
         f_all = jax.tree.map(
             lambda x: lax.all_gather(x, axis, axis=1), f_local
         )
-        ok = _rlc_finish(TP.fp12_product_tree(f_all), sig_acc)
+        ok = TP.final_exp_is_one(TP.fp12_product_tree(f_all))
         if check_subgroup:
             mem_local = _fused_subgroup_mask(sig, sig_inf_f).all()
             ok = jnp.logical_and(ok, lax.all_gather(mem_local, axis).all())

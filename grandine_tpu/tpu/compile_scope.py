@@ -1,4 +1,4 @@
-"""Where the program compiles: one scope, one clock, one trim.
+"""Where the program compiles: one scope, one clock, one trim, one freeze.
 
 A first dispatch of a (kernel, shapes) pair blocks on trace + XLA
 compilation — minutes and ~6 GB of host memory for the pairing kernels,
@@ -16,6 +16,16 @@ entry of the warm loop in runtime/warmup.py) behaves the same:
     to the deadline, so a first call that lands inside a watchdog-bounded
     settle cannot open the breaker.
 
+  - what is alive when a compile ends stays alive: the chain's state, the
+    registry, JAX's traced programs, ~1.1 million containers by the
+    firehose's first call at 50,000 validators. A collection of Python's
+    oldest generation walks all of them with every thread stopped, 350 to
+    470 ms, and comes whenever a quarter as many new containers have
+    survived: inside a gossip phase such a stall owns the window's tail
+    (PERF.md section 6, PR 31 and PR 33). `settle_heap()` collects once,
+    where a stall of minutes is ending anyway, and moves what survived to
+    the permanent generation, which no later collection walks;
+    `compiling()` calls it on exit.
   - what a compile costs is three different things (Python tracing,
     lowering to MLIR, the XLA compile or the persistent cache's load), and
     only the last one the cache saves. JAX reports each itself
@@ -32,6 +42,7 @@ Imports nothing heavy: runtime/health.py pulls this in on host-only nodes
 from __future__ import annotations
 
 import ctypes
+import gc
 import threading
 import time
 from contextlib import contextmanager
@@ -74,11 +85,23 @@ def trim_host_memory() -> None:
         _malloc_trim(0)
 
 
+def settle_heap() -> None:
+    """Collect now, then keep every container that survived out of all
+    later collections (`gc.freeze`). Reference counting frees a frozen
+    object as before; only a frozen CYCLE that dies later stays, until
+    someone thaws it (`gc.unfreeze`, as chip_smoke.py does where it drops
+    its executables). After the first call a further one walks only what
+    was made since."""
+    gc.collect()
+    gc.freeze()
+
+
 @contextmanager
 def compiling():
-    """Mark the calling thread as compiling; trims host memory on exit.
-    Scopes nest (the warm loop wraps the backend's own first-call stage):
-    only the outermost one runs the clock and the trim."""
+    """Mark the calling thread as compiling; trims host memory and
+    settles the heap on exit. Scopes nest (the warm loop wraps the
+    backend's own first-call stage): only the outermost one runs the
+    clock, the trim and the freeze."""
     ident = threading.get_ident()
     with _LOCK:
         row = _CLOCK.setdefault(ident, [0.0, None, {}])
@@ -96,6 +119,7 @@ def compiling():
                 row[2].clear()
                 _TOTAL[0] += dt
                 _TOTAL[1] += 1
+            settle_heap()
             trim_host_memory()
 
 
@@ -169,4 +193,4 @@ def phase_totals() -> "tuple[dict, dict]":
 
 
 __all__ = ["compiling", "compile_seconds", "totals", "trim_host_memory",
-           "listen", "phase_totals", "PHASES"]
+           "settle_heap", "listen", "phase_totals", "PHASES"]

@@ -23,6 +23,13 @@ microbenchmark that predates PERF_LEDGER.jsonl and is gone):
     lane shuffles), and montmul internally scans over the leading limb axis
     with its column accumulators as a 27-tuple carry that lives in VMEM —
     keeping the ~12 ns/element speed with ~30 flat ops per call site.
+A narrow batch puts the limbs back on the lanes: under 32 wide XLA lays a
+(26, n) array out `{0,1}` (the 26 limbs along the 128 lanes, the batch
+on sublanes), which is the first design again, and the same 63-step Miller
+loop cost 38.16 ms a call at width 1 against 13.59 at width 64
+(PERF_LEDGER.jsonl, PR 32, `breakdown.device_ops`). So no loop of the verify
+call carries a batch under LANE_FLOOR: `widen_lanes` pads it with copies and
+the result is read from the lanes that were there.
 
 Why 15-bit signed digits:
   - products of two digits: ≤ LMAX² < 2³¹ — exact in int32;
@@ -82,6 +89,19 @@ R2 = R_MONT * R_MONT % P
 N0_INV = (-pow(P, -1, 1 << LIMB_BITS)) % (1 << LIMB_BITS)
 
 _DT = jnp.int32
+
+#: The narrowest trailing batch axis that XLA's TPU compiler lays out with
+#: the batch on the 128 lanes: a loop's (26, n) carries are `{0,1}` (limbs
+#: on the lanes, module docstring) at n = 1, 2, 8, 16 and `{1,0:T(8,128)}`
+#: from 32 up (tests/test_tpu_compile.py holds it). One v5e, 20 calls each
+#: (PERF.md section 6, PR 33): the 63-step Miller loop 36.7 ms at 1 lane,
+#: 23.0 at 16, 11.5 at 32, 10.5 at 64; the final exponentiation's hard
+#: part 23.6 at 2, 28.4 at 16, 18.3 at 32, 13.6 at 64. The chip would
+#: take 64 (4.7 ms a call more); the CPU, where the tests run and a lane
+#: costs its share of the time, triples a 4-item call's time there and
+#: doubles it at 32 (PERF.md section 7): 32 until the rehearsals' windows
+#: have room for it.
+LANE_FLOOR = 32
 
 
 # --- host-side conversions -------------------------------------------------
@@ -174,6 +194,19 @@ def unstack_fp(fp, k: int, axis: int = 1) -> list:
 def concat_fp(elems, axis: int = 1) -> jnp.ndarray:
     """Concatenate Fp elements along an existing batch axis."""
     return jnp.concatenate(list(elems), axis=axis)
+
+
+def widen_lanes(fp) -> jnp.ndarray:
+    """Pad the TRAILING batch axis up to LANE_FLOOR with copies of its last
+    lane, so that a loop over the value runs with the batch on the lanes.
+    A batch already that wide comes back as it is (the width is static
+    under jit). Callers read their result from the lanes they brought."""
+    assert fp.ndim >= 2, "widen_lanes pads a batch axis, not the limb axis"
+    n = fp.shape[-1]
+    if n >= LANE_FLOOR:
+        return fp
+    fill = jnp.broadcast_to(fp[..., -1:], fp.shape[:-1] + (LANE_FLOOR - n,))
+    return jnp.concatenate([fp, fill], axis=-1)
 
 
 def index_fp(fp, idx) -> jnp.ndarray:

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from grandine_tpu.tpu import curve as C
+from grandine_tpu.tpu import limbs as L
 
 #: Scan lane count T (bucket-accumulation width). More lanes = fewer
 #: sequential scan steps (S = ceil(2NW / T)), BUT the montmul inner scan
@@ -338,18 +339,20 @@ def msm_bucket_scan(
     U = _sel3(ops, idx_b >= 1, U, inf_secB)  # digit 0 carries weight 0
     totals = _reduce_last_axis(U, B, ops)  # (n_sec,)
 
-    # 4. Horner over windows (hi → lo): acc = 2^w·acc ⊞ T_win, per group
+    # 4. Horner over windows (hi → lo): acc = 2^w·acc ⊞ T_win, per group;
+    # fewer than L.LANE_FLOOR groups (the verify kernels' one Σ rᵢ·sigᵢ)
+    # run padded with copies, the batch on the lanes (limbs.py)
     W, w = windows, window_bits
     xs_rev = tuple(
         jax.tree.map(
-            lambda a: jnp.moveaxis(
+            lambda a: L.widen_lanes(jnp.moveaxis(
                 a.reshape(a.shape[0], n_groups, W), 2, 0
-            )[::-1],
+            )[::-1]),
             e,
         )
         for e in totals
     )
-    init = _point_inf(ops, (n_groups,))
+    init = _point_inf(ops, (max(n_groups, L.LANE_FLOOR),))
 
     def horner(acc, win_pt):
         # w doubles as a fori_loop (same anti-unroll discipline as above)
@@ -357,7 +360,7 @@ def msm_bucket_scan(
         return C.point_add_complete(acc, tuple(win_pt), ops), None
 
     acc, _ = lax.scan(horner, init, xs_rev)
-    return acc
+    return tuple(jax.tree.map(lambda a: a[:, :n_groups], e) for e in acc)
 
 
 def expand_glv_points(x, y, inf, endo, ops):

@@ -221,9 +221,12 @@ def miller_loop(P_jac, Q_proj, inf_mask):
     compiled the same graph six times over — XLA compile time is
     superlinear in graph size).
     """
-    g1c = prepare_g1(P_jac)
     shape = Q_proj[0][0].shape[1:]
-    f0 = F.fp12_one(shape)
+    # a batch under L.LANE_FLOOR runs padded with copies, the batch on the
+    # lanes (limbs.py); the lanes that came are the lanes returned
+    P_jac, Q_proj = jax.tree.map(L.widen_lanes, (P_jac, Q_proj))
+    g1c = prepare_g1(P_jac)
+    f0 = F.fp12_one(Q_proj[0][0].shape[1:])
 
     def step(carry, bit):
         T, f = carry
@@ -242,6 +245,7 @@ def miller_loop(P_jac, Q_proj, inf_mask):
     bits = jnp.asarray(np.array(_BITS_AFTER_MSB, dtype=np.int32))
     (_, f), _ = lax.scan(step, (Q_proj, f0), bits)
 
+    f = jax.tree.map(lambda x: x[..., : shape[-1]], f)
     f = F.fp12_conj(f)  # negative BLS parameter
     return F.fp12_select(inf_mask, F.fp12_one(shape), f)
 
@@ -292,25 +296,29 @@ def final_exponentiation(f):
     return _hard_part(m)
 
 
-def _stack12(a, b):
-    """Stack two same-shape Fp12 elements along a NEW leading batch axis."""
-    return jax.tree.map(lambda x, y: jnp.stack([x, y], axis=1), a, b)
-
-
 def final_exp_is_one(f):
     """final_exponentiation(f) == 1, WITHOUT the Fp12 inversion.
 
     f^(p⁶-1) = conj(f)/f, so the easy-part output is carried as a
-    numerator/denominator PAIR stacked into one width-2 batch — the hard
-    part then runs once at width 2 (same latency as width 1) and the check
-    becomes num == den. The ~580-sequential-multiply Fermat inversion this
-    replaces was ~90% of the final-exp wall time on device (round-4
-    profile: fp12_inv 482 ms of 532 ms at width 1)."""
-    pair = _stack12(F.fp12_conj(f), f)  # (num, den) ≡ f^(p⁶-1)
+    numerator/denominator PAIR side by side on one batch axis (the g
+    numerators, then the g denominators) — the hard part then runs once
+    over both (same latency as one) and the check becomes num == den. The
+    ~580-sequential-multiply Fermat inversion this replaces was ~90% of
+    the final-exp wall time on device (round-4 profile: fp12_inv 482 ms of
+    532 ms at width 1). That axis is widened to L.LANE_FLOOR: the hard
+    part's five 63-step ladders over a pair of values ran with the limbs
+    on the lanes (limbs.py)."""
+    shape = f[0][0][0].shape[1:]
+    g = int(np.prod(shape, dtype=np.int64))
+    flat = jax.tree.map(lambda x: x.reshape(x.shape[0], g), f)
+    pair = jax.tree.map(  # (num…, den…) ≡ f^(p⁶-1)
+        lambda n, d: L.widen_lanes(jnp.concatenate([n, d], axis=1)),
+        F.fp12_conj(flat), flat,
+    )
     m = F.fp12_mul(F.fp12_frobenius_n(pair, 2), pair)  # ^(p²+1)
     e = _hard_part(m)
-    num = jax.tree.map(lambda x: x[:, 0], e)
-    den = jax.tree.map(lambda x: x[:, 1], e)
+    num = jax.tree.map(lambda x: x[:, :g], e)
+    den = jax.tree.map(lambda x: x[:, g : 2 * g], e)
     diff = jax.tree.leaves(jax.tree.map(L.sub_mod, num, den))
     # one fused Montgomery reduction (×R·R⁻¹ = identity) pulls the 12
     # component values into (−0.1p, 2p) before the 8p-bounded zero test
@@ -320,7 +328,7 @@ def final_exp_is_one(f):
     # compounded m·p/R terms; theorem (a) still holds and the product
     # contracts into (-0.1p, 2p) (see tools/ranges/bounds.txt).
     red = L.montmul(stacked, one)  # lint: disable=limb-range
-    return jnp.all(L.is_zero_val(red), axis=0)
+    return jnp.all(L.is_zero_val(red), axis=0).reshape(shape)
 
 
 def multi_pairing_check(P_jac, Q_proj, inf_mask):
@@ -338,6 +346,10 @@ def fp12_product_tree(f):
     (see curve._tree_reduce_points for why)."""
     n = f[0][0][0].shape[1]
     assert n & (n - 1) == 0, "fp12_product_tree requires a power-of-two batch"
+    if n < L.LANE_FLOOR:  # the batch on the lanes (limbs.py): pad with one
+        ones = F.fp12_one((L.LANE_FLOOR - n,))
+        f = jax.tree.map(lambda x, o: jnp.concatenate([x, o], axis=1), f, ones)
+        n = L.LANE_FLOOR
     levels = n.bit_length() - 1
     if levels:
 

@@ -19,6 +19,7 @@ deviceless executable is written to it but cannot be read back.
 """
 
 import contextlib
+import re
 import time
 
 import numpy as np
@@ -154,6 +155,54 @@ def test_building_block_compiles_for_v5e(name, one_chip):
     _compiled, dt, total = _compile(fn, _structs(args, one_chip))
     assert total < EXECUTABLE_BUDGET, (name, total)
     print(f"{name}: {dt:.1f}s, {total} bytes")
+
+
+# ----------------------- tier-1: the batch stays on the lanes (LANE_FLOOR)
+
+
+def _miller_structs(n, sharding):
+    import jax
+
+    def fp():
+        return jax.ShapeDtypeStruct((26, n), np.int32, sharding=sharding)
+
+    def fp2():
+        return (fp(), fp())
+
+    inf = jax.ShapeDtypeStruct((n,), np.bool_, sharding=sharding)
+    return (fp(), fp(), fp()), (fp2(), fp2(), fp2()), inf
+
+
+#: a 2-D limb array with the limbs on the lanes, `s32[26,n]{0,1:...}`
+_LIMBS_ON_LANES = re.compile(r"s32\[26,\d+\]\{0,1[:}]")
+
+
+@pytest.mark.parametrize("width, where", [(65, "anywhere"), (1, "in_a_loop")])
+def test_miller_loop_keeps_the_batch_on_the_lanes(width, where, one_chip):
+    """A narrow (26, n) array the compiler lays out {0,1}: the 26 limbs on
+    the 128 lanes, the slow design of limbs.py's docstring (38.2 ms a
+    Miller loop at width 1 against 13.6 at 64, ledger PR 32). At the
+    verify call's folded width (64 message pairs + the signature pair)
+    no limb array of the program is laid out so; at width 1 the loop pads
+    itself to limbs.LANE_FLOOR, so no loop CARRIES one (the arguments,
+    one lane wide, still are)."""
+    import jax
+
+    from grandine_tpu.tpu import pairing as TP
+
+    t0 = time.perf_counter()
+    compiled = jax.jit(TP.miller_loop).lower(
+        *_miller_structs(width, one_chip)
+    ).compile()
+    text = compiled.as_text()
+    print(f"miller_loop[{width}]: {time.perf_counter() - t0:.1f}s")
+    if where == "in_a_loop":
+        text = "\n".join(
+            line.split(" while(")[0] for line in text.splitlines()
+            if " while(" in line
+        )
+    assert "s32[26," in text
+    assert not sorted(set(_LIMBS_ON_LANES.findall(text)))
 
 
 # ------------------------------------- slow: the smoke's verify executables
