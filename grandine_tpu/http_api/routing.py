@@ -646,9 +646,9 @@ def get_debug_flight(ctx, params, query, body):
 
 def get_debug_profile(ctx, params, query, body):
     """Kernel-profiler view + capture control. Default GET serves the
-    always-on estimator (per-kernel device seconds, dispatch counts,
-    the finished-session ring, HBM family bytes, coverage vs the flight
-    recorder); `?kernel=` / `?scheme=` filter the estimator rows,
+    always-on device timeline (per-kernel busy seconds, idle seconds by
+    cause, dispatch counts, the finished-session ring, HBM family
+    bytes); `?kernel=` / `?scheme=` filter the busy rows,
     `?n=` bounds the session list. `?action=start[&trace_dir=...]`
     opens a capture session (409 when one is active), `?action=stop`
     closes it and returns the finished session record."""
@@ -680,7 +680,7 @@ def get_debug_profile(ctx, params, query, body):
         raise ApiError(400, "n must be non-negative")
     return {
         "data": ctx.profiler.summary(
-            kernel=kernel, scheme=scheme, n_sessions=n, flight=ctx.flight
+            kernel=kernel, scheme=scheme, n_sessions=n
         )
     }
 

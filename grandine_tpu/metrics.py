@@ -9,6 +9,7 @@ exposition. The scrape endpoint is served by the HTTP API layer.
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from typing import Optional, Sequence
@@ -547,6 +548,16 @@ class Metrics:
             ),
             defaults={"lane": "attestation", "op": ""},
         )
+        # the same stages' CPU seconds on their own thread (`thread_time`):
+        # wall minus CPU is time off the CPU, waiting for the GIL, a lock
+        # or I/O
+        self.verify_stage_cpu_seconds = LabeledCounter(
+            "verify_stage_cpu_seconds_total",
+            "CPU seconds of the stage's thread inside the stage, by "
+            "pipeline stage, lane and part (op)",
+            ("stage", "lane", "op"),
+            defaults={"lane": "attestation", "op": ""},
+        )
         # the compile scope by phase (tpu/compile_scope.py): what JAX
         # itself reports (jax.monitoring) of the time the program spent
         # inside `compiling()`. Process-wide counts, brought up to date
@@ -699,8 +710,8 @@ class Metrics:
         # flight recorder (runtime/flight.py): per-lane SLO misses with
         # a CLOSED cause enum (flight.SLO_CAUSES — the lint rule
         # rejects values outside it), bucket-fill/padding-waste per
-        # kernel (multi-chip capacity planning), and the duty-cycle /
-        # occupancy gauges measuring the two-deep overlap. Origins are
+        # kernel (multi-chip capacity planning), and the duty-cycle gauge
+        # the brownout controller reads. Origins are
         # NEVER labels here — they live only in the bounded flight
         # top-K table.
         self.verify_slo_miss = LabeledCounter(
@@ -777,21 +788,36 @@ class Metrics:
             "fraction of wall time with at least one verify batch on "
             "the device",
         )
-        self.verify_pipeline_occupancy = Gauge(
-            "verify_pipeline_occupancy",
-            "time-weighted mean verify batches in flight (the two-deep "
-            "overlap's real depth)",
-        )
-        # device-time profiling plane (runtime/profiler.py): dispatch→
-        # settle deltas reconciled from committed flight records, live
-        # device bytes by array family, and capture-session churn.
-        # Labels are the CLOSED kernel/scheme/family sets — never
-        # session ids (lint: metrics-cardinality)
+        # device-time profiling plane (runtime/profiler.py): the device
+        # timeline's busy seconds by kernel and its idle seconds by what
+        # held the next call back, live device bytes by array family, and
+        # capture-session churn. Labels are the CLOSED kernel/scheme/
+        # cause/family sets — never session ids (lint: metrics-cardinality)
         self.verify_device_seconds = LabeledCounter(
             "verify_device_seconds_total",
-            "estimated device seconds attributed per kernel and scheme "
-            "(flight-record dispatch-to-settle deltas)",
+            "device busy seconds per kernel and scheme: each call from "
+            "max(its dispatch, the previous call's end) to its output "
+            "being ready",
             ("kernel", "scheme"),
+        )
+        self.verify_device_idle_seconds = LabeledCounter(
+            "verify_device_idle_seconds_total",
+            "device idle seconds, by what held back the call that ended "
+            "the idle stretch (closed enum: profiler.IDLE_CAUSES)",
+            ("cause",),
+        )
+        # the interpreter's collections (runtime/profiler.py
+        # watch_collections): raised to the observer's totals on expose
+        self.process_gc_pause_seconds = LabeledCounter(
+            "process_gc_pause_seconds_total",
+            "seconds the interpreter spent in garbage collections, by "
+            "generation",
+            ("generation",),
+        )
+        self.process_gc_collections = LabeledCounter(
+            "process_gc_collections_total",
+            "garbage collections of the interpreter, by generation",
+            ("generation",),
         )
         self.verify_device_hbm_bytes = LabeledGauge(
             "verify_device_hbm_bytes",
@@ -961,9 +987,19 @@ class Metrics:
                 child = family.labels(label)
                 child.inc(total - child.value)
 
+    def _sync_profiler(self) -> None:
+        """The device timeline's open idle stretch charged up to now, and
+        the collection counters raised to the process's totals, where
+        the profiler counts into these metrics (runtime/profiler.py
+        `sync_metrics`; nothing when it is not loaded)."""
+        mod = sys.modules.get("grandine_tpu.runtime.profiler")
+        if mod is not None:
+            mod.sync_metrics(self)
+
     def expose(self) -> str:
         """Prometheus text exposition of every registered metric."""
         self._sync_compile_scope()
+        self._sync_profiler()
         return "".join(m.expose() for m in self.all())
 
 

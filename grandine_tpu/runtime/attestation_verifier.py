@@ -33,6 +33,7 @@ from grandine_tpu.crypto import bls as A
 from grandine_tpu.fork_choice.store import ForkChoiceError, ValidAttestation
 from grandine_tpu.runtime import flight as _flight
 from grandine_tpu.runtime import health as _health
+from grandine_tpu.runtime import profiler as _profiler
 from grandine_tpu.runtime.thread_pool import Priority
 from grandine_tpu.tracing import NULL_TRACER, stage as _stage
 
@@ -68,10 +69,10 @@ class _BatchLife:
     root span of its chain and the stamps its waits are measured from."""
 
     __slots__ = ("root", "arrived", "popped", "pool_span", "closed_by",
-                 "held_s")
+                 "held_s", "slot_wait_s")
 
     def __init__(self, tracer, batch, popped: float, closed_by: str,
-                 held_s: float) -> None:
+                 held_s: float, slot_wait_s: float) -> None:
         #: arrival of the batch's oldest item: where its life begins
         self.arrived = min(it.arrived for it in batch)
         self.popped = popped
@@ -80,6 +81,11 @@ class _BatchLife:
         #: how long the batch stood past its deadline for a pipeline slot
         #: (inside collect_wait; 0.0: it was not held)
         self.held_s = held_s
+        #: how long the formed batch waited at the collector for a slot of
+        #: either bound: the hold, or `max_active` batches in flight (pool
+        #: threads still waiting for the pipeline's settles); the device's
+        #: idle time over it is back-pressure (runtime/profiler.py `hold`)
+        self.slot_wait_s = slot_wait_s
         self.root = tracer.span(
             "verify_batch", {"batch": len(batch)}, start=self.arrived
         )
@@ -345,7 +351,7 @@ class AttestationVerifier:
             # arrives meanwhile rides in its call. Re-read on every wake
             # (a submit, a batch resolved): it leaves when a slot frees or
             # when it has filled, whichever comes first
-            held_at = None
+            held_at = waited_at = None
             while not self._stop:
                 held = (
                     len(self._queue) < self.max_batch
@@ -353,6 +359,8 @@ class AttestationVerifier:
                 )
                 if not held and self._active < self.max_active:
                     break
+                if waited_at is None:
+                    waited_at = time.perf_counter()
                 if held and held_at is None:
                     held_at = time.perf_counter()
                     if self.metrics is not None:
@@ -383,6 +391,7 @@ class AttestationVerifier:
         life = _BatchLife(
             self.tracer, batch, popped, closed_by,
             0.0 if held_at is None else popped - held_at,
+            0.0 if waited_at is None else popped - waited_at,
         )
         try:
             self.controller.pool.spawn(
@@ -461,6 +470,7 @@ class AttestationVerifier:
                     # same race the block task path catches)
                     with self._stats_lock:
                         self.stats["rejected"] += 1
+        t_prevalidated = time.perf_counter()
         if not prepared:
             life.root.finish()
             return False
@@ -496,15 +506,25 @@ class AttestationVerifier:
                 skipped = True
             else:
                 t0 = time.perf_counter()
-                try:
-                    settle = self._device_dispatch(prepared, fl=fl)
-                    fl.note_device(time.perf_counter() - t0)
-                except Exception:
-                    fl.note_device(time.perf_counter() - t0)
-                    fl.note_fault("dispatch")
-                    self.health.record_fault("dispatch")
-                    # bounded transient retry: one immediate re-dispatch
-                    settle = self._retry_dispatch(prepared, fl)
+                # what the batch did before its call, for the device's
+                # idle time ahead of it (runtime/profiler.py)
+                with _profiler.dispatch_phases(
+                    ("collect", life.arrived),
+                    ("hold", life.popped - life.slot_wait_s),
+                    ("pool_wait", life.popped),
+                    ("prevalidate", t_start),
+                    ("host_prep", t_prevalidated),
+                ):
+                    try:
+                        settle = self._device_dispatch(prepared, fl=fl)
+                        fl.note_device(time.perf_counter() - t0)
+                    except Exception:
+                        fl.note_device(time.perf_counter() - t0)
+                        fl.note_fault("dispatch")
+                        self.health.record_fault("dispatch")
+                        # bounded transient retry: one immediate
+                        # re-dispatch
+                        settle = self._retry_dispatch(prepared, fl)
                 if settle is not None:
                     # pipelined path: readback is deferred to the
                     # completion thread so this pool thread (and the
@@ -942,7 +962,7 @@ class AttestationVerifier:
             "op": "probe", "items": hi - lo,
             "bucket": self.batch_bucket, "depth": depth,
             "why": why,
-        }):
+        }), _profiler.dispatch_phases(("descent", float("-inf"))):
             try:
                 return bool(self._batch_check(parent[lo:hi], parent))
             except ValueError:

@@ -35,11 +35,13 @@ reads as consecutive records) and derives four things on top:
                      the flight ring and the debug endpoint — never as
                      Prometheus label values (unbounded cardinality;
                      the lint rule enforces this too).
-  duty cycle       — device_enter/device_exit bracket on-device work;
-                     the recorder integrates busy time and in-flight
-                     depth into `verify_device_duty_cycle` and
-                     `verify_pipeline_occupancy`, the real measure of
-                     the two-deep overlap.
+  duty cycle       — device_enter/device_exit bracket dispatched work
+                     (host deltas: dispatch handed off -> settle
+                     forced); the recorder integrates busy time into
+                     `verify_device_duty_cycle`, which the brownout
+                     controller reads. The device's own busy and idle
+                     time is the profiler's timeline (runtime/
+                     profiler.py).
 
 Lock-light by design: one short-hold lock guards the ring index and the
 duty-cycle accumulators; records are built outside it. Recording is
@@ -160,7 +162,8 @@ class BatchRecord:
         #: note. On the firehose: the whole of `_device_dispatch` (G2
         #: decompression, registry sync, packing, upload, the dispatch
         #: call) plus `settle_s`; NOT dispatch_wait_s or settle_wait_s.
-        #: The device's own time is in the profiler's trace alone
+        #: The device's own time is the profiler's timeline
+        #: (`verify_device_seconds_total`)
         self.device_s = 0.0
         self.host_s = 0.0
         self.bisect_s = 0.0
@@ -414,24 +417,17 @@ class FlightRecorder:
         #: by the BrownoutController on each transition (a torn read
         #: only mis-stamps one record's level by one tick)
         self.brownout_level = "normal"
-        #: runtime.profiler.KernelProfiler hook: every committed record
-        #: carrying a kernel feeds its dispatch→settle device seconds to
-        #: the profiler's always-on estimator (node.py wires the node's
-        #: profiler here; None = no attribution, recording unchanged)
-        self.profiler = None
         #: ring storage: preallocated slots, one short-hold lock around
         #: index bumps and duty-cycle accounting — record assembly and
         #: SLO attribution happen outside it
         self._ring: "list[Optional[BatchRecord]]" = [None] * self.capacity
         self._lock = threading.Lock()
         self._seq = 0
-        #: duty cycle / occupancy integrals
+        #: duty cycle integral
         self._t0 = self.clock()
         self._inflight = 0
         self._busy_since = 0.0
         self._busy_total = 0.0
-        self._occ_mark = self._t0
-        self._occ_integral = 0.0
         #: running aggregates for summary() (cheap dict bumps, also
         #: under the one lock so snapshots are coherent)
         self._slo_miss: "dict[tuple, int]" = {}
@@ -494,9 +490,6 @@ class FlightRecorder:
                 m.verify_padding_waste.inc(
                     rec.kernel, amount=rec.bucket - rec.items
                 )
-        prof = self.profiler
-        if prof is not None and rec.kernel:
-            prof.on_batch(rec)
         waste = rec.bucket - rec.items
         with self._lock:
             self._batches += 1
@@ -576,8 +569,6 @@ class FlightRecorder:
         """One batch entered the device (dispatch handed off)."""
         now = self.clock()
         with self._lock:
-            self._occ_integral += self._inflight * (now - self._occ_mark)
-            self._occ_mark = now
             if self._inflight == 0:
                 self._busy_since = now
             self._inflight += 1
@@ -586,17 +577,13 @@ class FlightRecorder:
         """One batch left the device (settle forced)."""
         now = self.clock()
         with self._lock:
-            self._occ_integral += self._inflight * (now - self._occ_mark)
-            self._occ_mark = now
             if self._inflight > 0:
                 self._inflight -= 1
                 if self._inflight == 0:
                     self._busy_total += now - self._busy_since
             duty = self._duty_locked(now)
-            occ = self._occupancy_locked(now)
         if self.metrics is not None:
             self.metrics.verify_device_duty_cycle.set(duty)
-            self.metrics.verify_pipeline_occupancy.set(occ)
 
     def _duty_locked(self, now: float) -> float:
         elapsed = now - self._t0
@@ -607,31 +594,9 @@ class FlightRecorder:
             busy += now - self._busy_since
         return min(1.0, busy / elapsed)
 
-    def _occupancy_locked(self, now: float) -> float:
-        elapsed = now - self._t0
-        if elapsed <= 0.0:
-            return 0.0
-        return (
-            self._occ_integral + self._inflight * (now - self._occ_mark)
-        ) / elapsed
-
     def duty_cycle(self) -> float:
         with self._lock:
             return self._duty_locked(self.clock())
-
-    def busy_seconds(self) -> float:
-        """Total wall seconds with at least one batch on the device —
-        the denominator of the profiler's coverage metric."""
-        now = self.clock()
-        with self._lock:
-            busy = self._busy_total
-            if self._inflight > 0:
-                busy += now - self._busy_since
-        return busy
-
-    def occupancy(self) -> float:
-        with self._lock:
-            return self._occupancy_locked(self.clock())
 
     # ----------------------------------------------------------- the ring
 
@@ -683,7 +648,7 @@ class FlightRecorder:
 
     def summary(self) -> dict:
         """The bench JSON-line payload: fill ratio and padding waste per
-        kernel, duty cycle / occupancy, SLO misses by lane and cause,
+        kernel, duty cycle, SLO misses by lane and cause,
         fault counts, and the origin top-K."""
         now = self.clock()
         with self._lock:
@@ -697,7 +662,6 @@ class FlightRecorder:
             waste = dict(self._waste)
             faults = dict(self._faults)
             duty = self._duty_locked(now)
-            occ = self._occupancy_locked(now)
         return {
             "batches": batches,
             "records": recorded,
@@ -705,7 +669,6 @@ class FlightRecorder:
             "fill_ratio": {k: round(v, 4) for k, v in sorted(fills.items())},
             "padding_waste": dict(sorted(waste.items())),
             "device_duty_cycle": round(duty, 4),
-            "pipeline_occupancy": round(occ, 4),
             "slo_miss": self.slo_misses(),
             "faults": dict(sorted(faults.items())),
             "failing_origins": self.origins.snapshot()[:8],

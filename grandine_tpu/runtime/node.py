@@ -63,15 +63,20 @@ class InProcessNode:
         #: transitions share a single ordered timeline (the debug
         #: endpoint GET /eth/v1/debug/grandine/flight serves it)
         self.flight = FlightRecorder(metrics=metrics)
-        #: ONE kernel profiler for the whole verify plane: the flight
-        #: recorder reconciles every committed batch's device seconds
-        #: into it, and the dispatch seams reach the same instance via
-        #: the module default so capture sessions annotate every kernel
-        #: (GET /eth/v1/debug/grandine/profile serves/controls it)
-        from grandine_tpu.runtime.profiler import KernelProfiler, set_profiler
+        #: ONE kernel profiler for the whole verify plane: the dispatch
+        #: seams reach it via the module default, so its device timeline
+        #: stamps every kernel call and capture sessions annotate every
+        #: kernel (GET /eth/v1/debug/grandine/profile serves/controls it);
+        #: the interpreter's collections are watched from here on, into
+        #: the node's metrics and spans
+        from grandine_tpu.runtime.profiler import (
+            KernelProfiler,
+            set_profiler,
+            watch_collections,
+        )
 
         self.profiler = set_profiler(KernelProfiler(metrics=metrics))
-        self.flight.profiler = self.profiler
+        watch_collections(metrics, tracer)
         #: ONE health supervisor for the whole device verify plane: a
         #: breaker fault observed by either the scheduler or the
         #: attestation firehose quarantines the device for both

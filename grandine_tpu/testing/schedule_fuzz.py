@@ -520,8 +520,8 @@ def scenario_sign_ticket(seed: int, **kw) -> dict:
 
 def scenario_flight_ring(seed: int, **kw) -> dict:
     """FlightRecorder under concurrent commit/snapshot/duty traffic: the
-    ring, aggregate counters, origin table, and occupancy integrals must
-    stay coherent."""
+    ring, aggregate counters, origin table, and the duty-cycle integral
+    must stay coherent."""
     import grandine_tpu.runtime.flight as fl
 
     fz = ScheduleFuzzer(seed, watched=[fl.__file__], **kw)

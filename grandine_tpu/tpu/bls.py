@@ -1398,7 +1398,8 @@ def _node_profiler():
     must not pull the runtime package in at module import time. The
     profiler's annotate() is a dict bump when no capture session is
     active; during a session it opens the TraceAnnotation scope keyed
-    (scheme, kernel, bucket)."""
+    (scheme, kernel, bucket). Its dispatched() queues the call's output
+    for the device timeline's watcher thread."""
     mod = sys.modules.get("grandine_tpu.runtime.profiler")
     if mod is None:
         from grandine_tpu.runtime import profiler as mod
@@ -1688,6 +1689,8 @@ class TpuBlsBackend:
         else:
             with prof.annotate(kernel, sigs):
                 out = fn(*args)
+        # the device timeline: enqueued now, busy until `out` is ready
+        prof.dispatched(kernel, out, sigs, self.tracer)
         if block and self._observed():
             with self._stage("execute", kernel=kernel):
                 self._block(out)

@@ -91,10 +91,12 @@ class Span:
         self.attrs[key] = value
         return self
 
-    def finish(self) -> None:
+    def finish(self, end: Optional[float] = None) -> None:
+        """End the span now, or at the `perf_counter` reading `end` (a
+        span stamped by a thread that did not live it: the device's)."""
         if self.end is not None:  # idempotent
             return
-        self.end = time.perf_counter()
+        self.end = time.perf_counter() if end is None else end
         self._tracer._on_finish(self)
 
     def __enter__(self) -> "Span":
@@ -141,7 +143,7 @@ class _NullSpan:
     def set_attr(self, *_a, **_k) -> "_NullSpan":
         return self
 
-    def finish(self) -> None:
+    def finish(self, end: Optional[float] = None) -> None:
         pass
 
     def __enter__(self) -> "_NullSpan":
@@ -170,7 +172,10 @@ class Tracer:
     def __init__(self, capacity: int = 4096, enabled: bool = True) -> None:
         self.capacity = int(capacity)
         self.enabled = bool(enabled)
-        self._lock = threading.Lock()
+        #: re-entrant, as is the sink's: a collection of the interpreter
+        #: can run on a thread inside either, and its `gc` span (runtime/
+        #: profiler.py) is opened and ended right there
+        self._lock = threading.RLock()
         self._finished: deque = deque(maxlen=self.capacity)
         self._next_id = 1
         self._local = threading.local()
@@ -178,7 +183,7 @@ class Tracer:
         #: lock, flushed when a root span ends (a whole trace is then on
         #: disk), on `clear()`, on `flush()` and at interpreter exit
         self._jsonl = None
-        self._jsonl_lock = threading.Lock()
+        self._jsonl_lock = threading.RLock()
 
     # ----------------------------------------------------------- span API
 
@@ -325,8 +330,10 @@ NULL_TRACER = Tracer(capacity=1, enabled=False)
 # One helper for every verify plane (the firehose, the scheduler lanes,
 # bulk replay, the device backend): a stage is a child span under the
 # thread's current span, one `verify_stage_seconds{stage,lane,op}`
-# observation and, while a profiler capture session is on, a host span in
-# the profiler's own trace.
+# observation, its thread's CPU seconds into `verify_stage_cpu_seconds_
+# total{stage,lane,op}` and the span's `cpu_s` (wall minus CPU is time the
+# thread was off the CPU: waiting for the GIL, a lock or I/O) and, while a
+# profiler capture session is on, a host span in the profiler's own trace.
 
 #: the CLOSED set of `op` values on verify_stage_seconds: what a stage
 #: that several call sites feed is split by. "" is a stage with one part.
@@ -377,7 +384,7 @@ class stage:
     what the stage took."""
 
     __slots__ = ("_tracer", "_metrics", "_stage", "_lane", "_op", "_attrs",
-                 "_t0", "_span", "_mark")
+                 "_t0", "_c0", "_span", "_mark")
 
     def __init__(self, tracer: Tracer, metrics, stage: str, lane: str,
                  op: str = "", **attrs) -> None:
@@ -399,10 +406,13 @@ class stage:
         if mark is not None:
             mark.__enter__()
         self._t0 = time.perf_counter()
+        self._c0 = time.thread_time()
         self._span = self._tracer.span(self._stage, self._attrs)
         return self._span.__enter__()
 
     def __exit__(self, *exc) -> None:
+        cpu = time.thread_time() - self._c0
+        self._span.set_attr("cpu_s", cpu)
         self._span.__exit__(*exc)
         dt = time.perf_counter() - self._t0
         if self._mark is not None:
@@ -411,3 +421,6 @@ class stage:
             self._metrics.verify_stage_seconds.labels(
                 self._stage, self._lane, self._op
             ).observe(dt)
+            self._metrics.verify_stage_cpu_seconds.labels(
+                self._stage, self._lane, self._op
+            ).inc(cpu)

@@ -275,10 +275,13 @@ def test_stages_reach_the_profilers_clock_only_in_a_session(
         drive(genesis, Metrics(), Tracer(), device=True)
     finally:
         prof.stop()
-    seen = set(Recorder.names)
+    # a collection of the interpreter inside the session writes its own
+    # `process/gc_gen<g>/b0` (runtime/profiler.py): not a stage
+    seen = {n for n in Recorder.names if not n.startswith("process/gc_gen")}
     assert seen, "a capture session wrote no host span"
-    for name in seen:
+    for name in set(Recorder.names):
         assert trace_reduce.HOST_SPAN.match(name), name
+    for name in seen:
         assert re.match(r"^attestation/[a-z_0-9]+/b\d+$", name), name
     for what in ("prevalidate", "g2_decompress", "pack_aggregate",
                  "upload_bytes", "settle", "execute", "readback",

@@ -1,6 +1,6 @@
 """Flight recorder: ring wraparound and snapshot filters, SLO cause
 attribution, bounded top-K origin table (space-saving eviction), fault
-aggregation across retries, duty-cycle/occupancy integrals, concurrent
+aggregation across retries, the duty-cycle integral, concurrent
 record/snapshot safety, the debug endpoint, and the ≤5% always-on
 recording overhead guard.
 """
@@ -187,7 +187,7 @@ def test_batch_flight_threads_origin_into_table():
     assert fl.summary()["failing_origins"][0]["failures"] == 1
 
 
-# ------------------------------------------------------ duty / occupancy
+# ------------------------------------------------------------ duty cycle
 
 
 def test_duty_cycle_and_occupancy_integrals():
@@ -201,9 +201,8 @@ def test_duty_cycle_and_occupancy_integrals():
     t[0] = 3.0
     fl.device_exit()           # idle at t=3
     t[0] = 4.0
-    # busy 0..3 of 4s elapsed; occupancy integral 1+2+1 = 4 over 4s
+    # busy 0..3 of 4s elapsed
     assert abs(fl.duty_cycle() - 0.75) < 1e-9
-    assert abs(fl.occupancy() - 1.0) < 1e-9
     m = Metrics()
     fl2 = FlightRecorder(metrics=m, clock=lambda: t[0])
     fl2.device_enter()

@@ -1,9 +1,11 @@
 """Kernel profiler: annotation-registry coverage of the dispatch
 universe, bounded capture-session ring + start/stop contract, the debug
-endpoint (filters, capture control, 503 unwired), estimator
-reconciliation against a fake-clock flight timeline, the ≤5% always-off
-overhead guard, and the capture-toggle recompile/verdict regression
-test (a mid-soak start/stop must not perturb the shape ledger).
+endpoint (filters, capture control, 503 unwired), the device timeline's
+per-kernel busy seconds against calls whose readiness the test controls,
+the ≤5% always-off overhead guard, and the capture-toggle recompile/
+verdict regression test (a mid-soak start/stop must not perturb the
+shape ledger). tests/test_device_timeline.py drives the timeline's idle
+accounting.
 """
 
 import os
@@ -14,7 +16,6 @@ import pytest
 
 from grandine_tpu.http_api.routing import ApiContext, build_router
 from grandine_tpu.metrics import Metrics
-from grandine_tpu.runtime.flight import FlightRecorder
 from grandine_tpu.runtime.profiler import (
     HBM_FAMILIES,
     KERNEL_SCHEMES,
@@ -25,6 +26,26 @@ from grandine_tpu.runtime.profiler import (
 )
 
 REPO = os.path.join(os.path.dirname(__file__), "..")
+
+
+class _Out:
+    """A kernel call's output whose readiness the test controls: ready
+    `after` seconds past the call's dispatch."""
+
+    def __init__(self, after: float = 0.0) -> None:
+        self.due = time.perf_counter() + after
+
+    def block_until_ready(self):
+        time.sleep(max(0.0, self.due - time.perf_counter()))
+        return self
+
+
+def _settled(p: KernelProfiler, calls: int, timeout: float = 5.0) -> None:
+    """Until the watcher has stamped `calls` calls in all."""
+    end = time.monotonic() + timeout
+    while sum(r["calls"] for r in p.summary()["device_seconds"]) < calls:
+        assert time.monotonic() < end, p.summary()
+        time.sleep(0.002)
 
 
 # ------------------------------------------------- annotation registry
@@ -134,22 +155,19 @@ def test_session_ring_bounds_and_start_stop_contract():
     assert p.active_session() is None
 
 
-def test_session_counts_batches_and_metric():
+def test_session_counts_calls_and_metric():
     m = Metrics()
     p = KernelProfiler(metrics=m)
-    fl = FlightRecorder()
-    fl.profiler = p
     p.start(note="windowed")
-    bf = fl.begin_batch("block", "multi_verify", 8)
-    bf.note_device(0.25)
-    bf.finish(True)
+    p.dispatched("multi_verify_msm", _Out(0.05), 8)
+    _settled(p, 1)
     sess = p.stop()
-    assert sess["batches"] == 1
-    assert sess["device_s"] == pytest.approx(0.25)
+    assert sess["calls"] == 1
+    assert 0.05 <= sess["device_s"] < 0.5
     assert m.verify_profile_sessions.value == 1.0
     assert m.verify_device_seconds.labels(
-        "multi_verify", "bls"
-    ).value == pytest.approx(0.25)
+        "multi_verify_msm", "bls"
+    ).value == pytest.approx(sess["device_s"])
 
 
 def test_update_hbm_families():
@@ -179,19 +197,11 @@ def test_update_hbm_families():
 
 def _profile_ctx():
     clock = [100.0]
-    fl = FlightRecorder(clock=lambda: clock[0])
     p = KernelProfiler(clock=lambda: clock[0])
-    fl.profiler = p
-    fl.device_enter()
-    bf = fl.begin_batch("block", "multi_verify", 8)
-    clock[0] += 0.5
-    bf.note_device(0.5)
-    bf.finish(True)
-    fl.device_exit()
-    bf = fl.begin_batch("ed25519", "ed25519_verify", 32)
-    bf.note_device(0.1)
-    bf.finish(True)
-    return ApiContext(None, None, flight=fl, profiler=p), p, clock
+    p.dispatched("multi_verify_msm", _Out(0.01), 8)
+    p.dispatched("ed25519_verify", _Out(0.0), 32)
+    _settled(p, 2)
+    return ApiContext(None, None, profiler=p), p, clock
 
 
 def test_profile_endpoint_summary_and_filters():
@@ -205,16 +215,17 @@ def test_profile_endpoint_summary_and_filters():
     assert status == 200
     data = payload["data"]
     kernels = {r["kernel"] for r in data["device_seconds"]}
-    assert kernels == {"multi_verify", "ed25519_verify"}
+    assert kernels == {"multi_verify_msm", "ed25519_verify"}
     assert data["sessions_total"] == 0 and data["active_session"] is None
-    assert "coverage" in data  # flight recorder saw busy time
+    assert "coverage" not in data  # no estimator beside the timeline
+    assert set(data["idle_seconds"]) <= {"other", "gc"}  # no phases named
     json.dumps(payload)
 
     status, payload = router.dispatch(
         ctx, "GET", "/eth/v1/debug/grandine/profile", {"scheme": "bls"}
     )
     rows = payload["data"]["device_seconds"]
-    assert [r["kernel"] for r in rows] == ["multi_verify"]
+    assert [r["kernel"] for r in rows] == ["multi_verify_msm"]
 
     status, payload = router.dispatch(
         ctx, "GET", "/eth/v1/debug/grandine/profile",
@@ -264,82 +275,69 @@ def test_profile_endpoint_capture_control_and_unwired():
     )[0] == 503
 
 
-# ------------------------------------------- estimator reconciliation
+# ------------------------------------------------- the device timeline
 
 
-def test_estimator_reconciles_fake_clock_flight_timeline():
-    """Drive a scripted flight timeline on a fake clock: the profiler's
-    attributed seconds must equal the recorder's device-busy integral
-    exactly (coverage 1.0), and per-kernel totals must match what each
-    batch reported."""
-    clock = [1000.0]
-    fl = FlightRecorder(clock=lambda: clock[0])
-    p = KernelProfiler(clock=lambda: clock[0])
-    fl.profiler = p
-
-    script = [
-        ("block", "multi_verify", 8, 0.50),
-        ("attestation", "fast_aggregate", 64, 1.25),
-        ("ed25519", "ed25519_verify", 32, 0.25),
-        ("block", "multi_verify", 8, 0.50),
-    ]
-    for lane, kernel, items, dev in script:
-        fl.device_enter()
-        bf = fl.begin_batch(lane, kernel, items)
-        clock[0] += dev
-        bf.note_device(dev)
-        bf.finish(True)
-        fl.device_exit()
-
-    assert fl.busy_seconds() == pytest.approx(2.5)
-    assert p.attributed_seconds() == pytest.approx(2.5)
-    assert p.coverage(fl) == pytest.approx(1.0)
+def test_timeline_busy_seconds_per_kernel_tile_the_calls():
+    """Calls on the device back to back, each ready a known time after the
+    one before: each kernel's busy seconds are its calls' stretches, and
+    together they tile first dispatch -> last ready, with no idle time
+    charged between them (each call was dispatched before the previous
+    one was ready)."""
+    p = KernelProfiler()
+    script = [("multi_verify_msm", 0.04), ("agg_fast_verify_msm_idx", 0.06),
+              ("ed25519_verify", 0.02), ("multi_verify_msm", 0.04)]
+    t0 = time.perf_counter()
+    due = 0.0
+    for kernel, busy in script:
+        due += busy
+        out = _Out()
+        out.due = t0 + due
+        p.dispatched(kernel, out, 8)
+    _settled(p, len(script))
     dev = p.device_seconds()
-    assert dev[("multi_verify", "bls")] == pytest.approx(1.0)
-    assert dev[("fast_aggregate", "bls")] == pytest.approx(1.25)
-    assert dev[("ed25519_verify", "ed25519")] == pytest.approx(0.25)
-    rows = {
-        (r["kernel"], r["scheme"]): r["batches"]
-        for r in p.summary(flight=fl)["device_seconds"]
-    }
-    assert rows[("multi_verify", "bls")] == 2
-    # acceptance floor: the node bench reports this as profiler_coverage
-    assert p.coverage(fl) >= 0.90
+    assert sum(dev.values()) == pytest.approx(due, abs=0.02)
+    assert dev[("multi_verify_msm", "bls")] == pytest.approx(0.08, abs=0.02)
+    assert dev[("agg_fast_verify_msm_idx", "bls")] == pytest.approx(
+        0.06, abs=0.02)
+    assert dev[("ed25519_verify", "ed25519")] == pytest.approx(
+        0.02, abs=0.02)
+    rows = {(r["kernel"], r["scheme"]): r["calls"]
+            for r in p.summary()["device_seconds"]}
+    assert rows[("multi_verify_msm", "bls")] == 2
+    assert p.idle_seconds() == {}
 
 
-def test_coverage_none_without_flight_or_busy_time():
+def test_a_host_value_or_a_failed_call_is_ready_at_once():
+    """An output that is not an array (a host value), and one whose
+    computation failed, are stamped ready where the watcher meets them;
+    the watcher lives on."""
+
+    class _Failed:
+        def block_until_ready(self):
+            raise RuntimeError("the computation failed")
+
     p = KernelProfiler()
-    assert p.coverage(None) is None
-    fl = FlightRecorder()
-    assert p.coverage(fl) is None  # no device time recorded
-    assert "coverage" not in p.summary(flight=fl)
+    p.dispatched("multi_verify_msm", True, 4)
+    p.dispatched("multi_verify_msm", (_Failed(), [_Out(0.01)]), 4)
+    p.dispatched("multi_verify_msm", _Out(0.0), 4)
+    _settled(p, 3)
+    assert p.device_seconds()[("multi_verify_msm", "bls")] < 0.5
 
 
-def test_kernelless_records_are_skipped():
-    fl = FlightRecorder()
-    p = KernelProfiler()
-    fl.profiler = p
-    bf = fl.begin_batch("block", "", 4)  # scheduler pre-dispatch label
-    bf.note_device(0.3)
-    bf.finish(True)
-    assert p.device_seconds() == {}
-
-
-def test_on_batch_concurrent_with_capture_toggle():
-    """Committing batches from worker threads while another thread
-    flips capture on/off must neither race nor lose counts."""
-    fl = FlightRecorder()
+def test_dispatched_concurrent_with_capture_toggle():
+    """Calls dispatched from worker threads while another thread flips
+    capture on/off must neither race nor lose a call."""
     p = KernelProfiler(capacity=4)
-    fl.profiler = p
     stop = threading.Event()
-    errors = []
+    errors, sent = [], [0, 0, 0]
 
-    def writer():
+    def writer(i):
         try:
             while not stop.is_set():
-                bf = fl.begin_batch("block", "multi_verify", 8)
-                bf.note_device(0.001)
-                bf.finish(True)
+                p.dispatched("multi_verify_msm", _Out(), 8)
+                sent[i] += 1
+                time.sleep(0.0005)
         except Exception as exc:  # pragma: no cover
             errors.append(exc)
 
@@ -351,8 +349,8 @@ def test_on_batch_concurrent_with_capture_toggle():
         except Exception as exc:  # pragma: no cover
             errors.append(exc)
 
-    threads = [threading.Thread(target=writer, daemon=True)
-               for _ in range(3)] + [
+    threads = [threading.Thread(target=writer, args=(i,), daemon=True)
+               for i in range(3)] + [
         threading.Thread(target=toggler, daemon=True)
     ]
     for t in threads:
@@ -362,10 +360,8 @@ def test_on_batch_concurrent_with_capture_toggle():
     for t in threads:
         t.join(2.0)
     assert not errors
-    key = ("multi_verify", "bls")
-    dev = p.device_seconds()
-    batches = p.summary()["device_seconds"][0]["batches"]
-    assert dev[key] == pytest.approx(0.001 * batches)
+    _settled(p, sum(sent))
+    assert p.summary()["device_seconds"][0]["calls"] == sum(sent)
     assert len(p.sessions()) <= 4
 
 
@@ -373,10 +369,10 @@ def test_on_batch_concurrent_with_capture_toggle():
 
 
 def _profiled_workload(fl, rounds: int, prof=None) -> float:
-    """The flight-commit path, optionally with a profiler hooked: 16
-    sha256-staged batches per round, one annotate() scope per batch when
-    a profiler rides along (the same per-batch cost the dispatch seams
-    pay). Returns seconds."""
+    """The flight-commit path, optionally with a profiler riding along:
+    16 sha256-staged batches per round, one annotate() scope per batch
+    when a profiler rides along (the same per-batch cost the dispatch
+    seams pay). Returns seconds."""
     import contextlib
     import hashlib
 
@@ -397,14 +393,14 @@ def _profiled_workload(fl, rounds: int, prof=None) -> float:
 
 
 def test_always_off_overhead_within_5_percent():
-    """Estimator always-on but capture off: hooking the profiler into
-    the flight recorder (plus one annotate() per batch) must cost ≤5%
-    vs the bare recorder on the same synthetic workload — min-of-5 with
-    a small epsilon, mirroring the flight/observability guards."""
+    """Capture off: one annotate() per batch must cost ≤5% vs the bare
+    recorder on the same synthetic workload — min-of-5 with a small
+    epsilon, mirroring the flight/observability guards."""
+    from grandine_tpu.runtime.flight import FlightRecorder
+
     plain = FlightRecorder(capacity=4096)
     hooked = FlightRecorder(capacity=4096)
     prof = KernelProfiler()
-    hooked.profiler = prof
 
     _profiled_workload(plain, 1)  # warm both paths
     _profiled_workload(hooked, 1, prof)
@@ -413,7 +409,6 @@ def test_always_off_overhead_within_5_percent():
     assert t_on <= t_off * 1.05 + 0.002, (
         f"profiled {t_on * 1e3:.2f}ms vs plain {t_off * 1e3:.2f}ms"
     )
-    assert prof.attributed_seconds() > 0
     assert prof.summary()["dispatches"]["multi_verify"] >= 16 * 6
 
 

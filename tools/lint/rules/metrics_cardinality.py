@@ -78,6 +78,12 @@ _ENUM_LABELS = {
     "verify_stage_seconds": (
         "op", "grandine_tpu/tracing.py", "STAGE_OPS"
     ),
+    "verify_stage_cpu_seconds": (
+        "op", "grandine_tpu/tracing.py", "STAGE_OPS"
+    ),
+    "verify_device_idle_seconds": (
+        "cause", "grandine_tpu/runtime/profiler.py", "IDLE_CAUSES"
+    ),
 }
 #: the stage helpers (tracing.stage and the `_stage` methods that bind a
 #: tracer, metrics and a lane to it) take the `op` label as a keyword and
