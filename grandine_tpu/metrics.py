@@ -412,6 +412,19 @@ class Metrics:
             "attestation_mixed_batches_total",
             "gossip batches whose narrowest and widest item fall in "
             "different member buckets")
+        # the slasher feed's own thread takes every delivered batch that
+        # waits for it in one slasher call: batches / calls is how far it
+        # coalesces (1.0 where nothing queues); a hand-over that found its
+        # bounded queue full waited for room
+        self.att_slasher_feed_calls = Counter(
+            "attestation_slasher_feed_calls_total",
+            "slasher calls the firehose's feeder thread made")
+        self.att_slasher_feed_batches = Counter(
+            "attestation_slasher_feed_batches_total",
+            "delivered batches those calls fed")
+        self.att_slasher_feed_blocked = Counter(
+            "attestation_slasher_feed_blocked_total",
+            "hand-overs to the feeder that found its queue full")
         # the descent over a failed batch (_isolate): its probes are
         # calls of the batch's own executable, padded to the batch's
         # bucket — items / slots is what that padding costs

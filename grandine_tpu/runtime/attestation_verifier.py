@@ -255,11 +255,29 @@ class AttestationVerifier:
             self._completion_thread = threading.Thread(
                 target=self._complete, name="attestation-settle", daemon=True
             )
+        #: the slasher feed runs on a thread of its own, behind a FIFO of
+        #: delivered batches: the delivering thread hands the accepted
+        #: pairs over and frees its pipeline slot. At most
+        #: `pipeline_depth` batches wait; a hand-over beyond that blocks
+        #: (`slasher_wait`), so the slasher stays within `pipeline_depth`
+        #: + 1 batches of fork choice
+        self._feed_queue: "Optional[queue.Queue]" = None
+        self._feeder: "Optional[threading.Thread]" = None
+        #: batches handed to the feeder and not yet fed (under _cond):
+        #: what `flush` waits for beside the pipeline
+        self._unfed = 0
+        if slasher is not None:
+            self._feed_queue = queue.Queue(maxsize=self.pipeline_depth)
+            self._feeder = threading.Thread(
+                target=self._feed, name="attestation-slasher", daemon=True
+            )
         # construct every thread before starting any: a started thread
         # must never observe a half-initialized verifier
         self._collector = threading.Thread(
             target=self._collect, name="attestation-verifier", daemon=True
         )
+        if self._feeder is not None:
+            self._feeder.start()
         if self._completion_thread is not None:
             self._completion_thread.start()
         self._collector.start()
@@ -603,16 +621,70 @@ class AttestationVerifier:
         fl.finish(bad_count == 0)
 
     def _feedback(self, accepted) -> None:
-        """The `feedback` stage in its two parts, one after the other:
-        the verdicts to fork choice, then the slasher feed — AFTER
-        delivery, so a slasher problem never costs fork choice its
-        verified votes."""
+        """The verdicts to fork choice (`feedback` / `deliver`), then the
+        accepted pairs to the feeder thread (`_feed`), which runs the
+        `feedback` / `slasher_feed` part: AFTER delivery, so a slasher
+        problem never costs fork choice its verified votes, and off this
+        thread, so a feed never holds the next verdict back. Blocks only
+        while `pipeline_depth` batches already wait for the feeder."""
         with self._stage("feedback", op="deliver", items=len(accepted)):
             self.controller.on_valid_attestation_batch(
                 [p[3] for p in accepted]
             )
-        with self._stage("feedback", op="slasher_feed", items=len(accepted)):
-            self._feed_slasher([(p[4], p[3]) for p in accepted])
+        if self._feed_queue is None:
+            return
+        # the batch's root rides along: the feed's stage hangs under it
+        waiting = ([(p[4], p[3]) for p in accepted], self.tracer.capture())
+        with self._cond:
+            self._unfed += 1
+        try:
+            self._feed_queue.put_nowait(waiting)
+        except queue.Full:
+            if self.metrics is not None:
+                self.metrics.att_slasher_feed_blocked.inc()
+            with self.tracer.span("slasher_wait", {"items": len(accepted)}):
+                self._feed_queue.put(waiting)
+
+    def _feed(self) -> None:
+        """Feeder thread: feeds delivered batches in delivery order until
+        the sentinel `stop` queues behind the last one."""
+        while True:
+            # crash containment: `_feed_slasher` counts the slasher's own
+            # faults; anything else is accounted here and the loop goes on
+            try:
+                if self._feed_once():
+                    return
+            except Exception:
+                self._count_daemon_failure("attestation-slasher")
+
+    def _feed_once(self) -> bool:
+        """Wait for a delivered batch, take it with every batch queued
+        behind it at that moment, and feed them all in ONE `_feed_slasher`
+        call (`Slasher.on_attestations_bulk` is calling `on_attestation`
+        in order: one call detects what one call a batch would, in one
+        storage transaction). The stage's seconds stay the sum over the
+        window's batches, its span hangs under the first batch's root.
+        True at the sentinel."""
+        taken = [self._feed_queue.get()]
+        for _ in range(self._feed_queue.qsize()):
+            taken.append(self._feed_queue.get_nowait())
+        batches = [t for t in taken if t is not None]
+        try:
+            if batches:
+                if self.metrics is not None:
+                    self.metrics.att_slasher_feed_calls.inc()
+                    self.metrics.att_slasher_feed_batches.inc(len(batches))
+                pairs = [pair for b, _root in batches for pair in b]
+                with self.tracer.attach(batches[0][1]), self._stage(
+                    "feedback", op="slasher_feed", items=len(pairs),
+                    batches=len(batches),
+                ):
+                    self._feed_slasher(pairs)
+        finally:
+            with self._cond:
+                self._unfed -= len(batches)
+                self._cond.notify_all()
+        return len(batches) < len(taken)
 
     # ------------------------------------------------------------ pipeline
 
@@ -1041,9 +1113,10 @@ class AttestationVerifier:
         """Run every ACCEPTED attestation through the slasher; a hit is
         turned into a full AttesterSlashing op for the proposer pipeline
         when the conflicting attestation is still in the evidence window
-        (slasher.rs → validator slashing forwarding). Serialized by
-        _slasher_lock (the slasher's span chunks are not thread-safe) and
-        exception-isolated — detection must never break verification."""
+        (slasher.rs → validator slashing forwarding). The feeder thread's
+        body (`_feed_once`), synchronous; serialized by _slasher_lock (the
+        slasher's span chunks are not thread-safe) and exception-isolated
+        — detection must never break verification."""
         if self.slasher is None:
             return
         try:
@@ -1116,17 +1189,17 @@ class AttestationVerifier:
         could be built)."""
         if self.operation_pool is None:
             return None
+        # the conflicting vote as the slasher read it at detection: a
+        # later vote of the same call at that target does not replace it
         if hit.kind == "double_vote":
             prior_target = int(hit.evidence["target_epoch"])
-            prior_root = bytes.fromhex(hit.evidence["roots"][0])
         elif hit.kind in ("surround_vote", "surrounded_vote"):
             prior_target = int(hit.evidence["existing"][1])
-            rec = self.slasher.record_for(hit.validator_index, prior_target)
-            if rec is None:
-                return None  # evidence pruned
-            prior_root = rec[1]
         else:
             return None
+        if hit.evidence["roots"][0] is None:
+            return None  # evidence pruned
+        prior_root = bytes.fromhex(hit.evidence["roots"][0])
         entries = self._recent_attestations.get(prior_target, {}).get(
             prior_root, []
         )
@@ -1213,14 +1286,15 @@ class AttestationVerifier:
     # ------------------------------------------------------------ control
 
     def flush(self, timeout: float = 30.0) -> None:
-        """Drain the queue, all in-flight batches, and the pipelined
-        settle queue (test barrier)."""
+        """Drain the queue, all in-flight batches, the pipelined settle
+        queue and the slasher feed (test barrier)."""
         deadline = time.monotonic() + timeout
         with self._cond:
             self._cond.notify()
         while time.monotonic() < deadline:
             with self._cond:
-                if not self._queue and self._active == 0 and self._inflight == 0:
+                if (not self._queue and self._active == 0
+                        and self._inflight == 0 and self._unfed == 0):
                     return
                 self._cond.notify()
             time.sleep(0.01)
@@ -1231,12 +1305,21 @@ class AttestationVerifier:
             self._stop = True
             self._cond.notify_all()
         self._collector.join(timeout=5)
+        # the batches the collector spawned last resolve or reach the
+        # completion queue before its sentinel does
+        with self._cond:
+            self._cond.wait_for(lambda: self._active == 0, timeout=5)
         if self._completion is not None:
             # sentinel queues BEHIND any still-pending settles, so they
             # drain before the thread exits
             self._completion.put(None)
             if self._completion_thread is not None:
                 self._completion_thread.join(timeout=10)
+        if self._feeder is not None:
+            # and the feeder's BEHIND the last delivered batch: nothing
+            # accepted goes unfed
+            self._feed_queue.put(None)
+            self._feeder.join(timeout=10)
 
 
 __all__ = ["AttestationVerifier", "GossipAttestation", "MAX_BATCH"]

@@ -93,6 +93,19 @@ class Slashing:
         return f"Slashing({self.kind}, validator={self.validator_index})"
 
 
+def _surround(rec, existing_target: int, s: int, t: int,
+              data_root: bytes) -> dict:
+    """A surround hit's evidence: the recorded vote it conflicts with as
+    read at detection, (source, target) and its root beside the new
+    vote's (None where the record is gone), so a later write of the same
+    (validator, target) cannot change what the hit names."""
+    return {
+        "existing": [rec[0] if rec else -1, existing_target],
+        "new": [s, t],
+        "roots": [rec[1].hex() if rec else None, data_root.hex()],
+    }
+
+
 class Slasher:
     def __init__(self, database: "Optional[Database]" = None,
                  history_epochs: int = 4096, metrics=None,
@@ -362,18 +375,17 @@ class Slasher:
             min_t = int(min_vals[pos])
             if min_t != unset and min_t < t:
                 rec = self._record(i, min_t)
-                out.append(Slashing("surround_vote", i, {
-                    "existing": [rec[0] if rec else -1, min_t],
-                    "new": [s, t],
-                }))
+                out.append(Slashing(
+                    "surround_vote", i, _surround(rec, min_t, s, t, data_root)
+                ))
                 continue
             max_t = int(max_vals[pos])
             if max_t > t:
                 rec = self._record(i, max_t)
-                out.append(Slashing("surrounded_vote", i, {
-                    "existing": [rec[0] if rec else -1, max_t],
-                    "new": [s, t],
-                }))
+                out.append(Slashing(
+                    "surrounded_vote", i,
+                    _surround(rec, max_t, s, t, data_root),
+                ))
                 continue
             out.append(None)
         return out
@@ -390,17 +402,15 @@ class Slasher:
         min_t = int(self._chunk("min", vchunk, echunk)[row, col])
         if min_t != int(_UNSET_MIN) and min_t < t:
             rec = self._record(i, min_t)
-            return Slashing("surround_vote", i, {
-                "existing": [rec[0] if rec else -1, min_t],
-                "new": [s, t],
-            })
+            return Slashing(
+                "surround_vote", i, _surround(rec, min_t, s, t, data_root)
+            )
         max_t = int(self._chunk("max", vchunk, echunk)[row, col])
         if max_t > t:
             rec = self._record(i, max_t)
-            return Slashing("surrounded_vote", i, {
-                "existing": [rec[0] if rec else -1, max_t],
-                "new": [s, t],
-            })
+            return Slashing(
+                "surrounded_vote", i, _surround(rec, max_t, s, t, data_root)
+            )
         return None
 
     def _update_spans(self, i: int, s: int, t: int) -> None:

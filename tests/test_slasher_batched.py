@@ -89,14 +89,18 @@ def test_batched_directed_kinds():
     assert [(h.kind, h.validator_index) for h in hits] == [
         ("surround_vote", 7), ("surround_vote", 290),
     ]
-    assert hits[0].evidence == {"existing": [10, 20], "new": [5, 30]}
+    assert hits[0].evidence == {"existing": [10, 20], "new": [5, 30],
+                                "roots": [(b"\xaa" * 32).hex(),
+                                          (b"\xbb" * 32).hex()]}
 
     # surrounded: (12, 15) is surrounded by the recorded (10, 20)
     hits = sl.on_attestation([8], 12, 15, b"\xcc" * 32)
     assert [(h.kind, h.validator_index) for h in hits] == [
         ("surrounded_vote", 8),
     ]
-    assert hits[0].evidence == {"existing": [10, 20], "new": [12, 15]}
+    assert hits[0].evidence == {"existing": [10, 20], "new": [12, 15],
+                                "roots": [(b"\xaa" * 32).hex(),
+                                          (b"\xcc" * 32).hex()]}
 
     # double vote: same target, different root
     hits = sl.on_attestation([9, 11], 11, 20, b"\xdd" * 32)
@@ -329,9 +333,30 @@ def test_offences_inside_one_call_are_found(db_kind, tmp_path):
         "target_epoch": 20,
         "roots": [(b"\xaa" * 32).hex(), (b"\xbb" * 32).hex()],
     }
-    assert hits[1].evidence == {"existing": [10, 20], "new": [5, 30]}
-    assert hits[2].evidence == {"existing": [5, 30], "new": [6, 29]}
+    # a surround names the vote it found, root and all: 8's (10, 20)
+    assert hits[1].evidence == {"existing": [10, 20], "new": [5, 30],
+                                "roots": [(b"\xaa" * 32).hex(),
+                                          (b"\xcc" * 32).hex()]}
+    assert hits[2].evidence == {"existing": [5, 30], "new": [6, 29],
+                                "roots": [(b"\xcc" * 32).hex(),
+                                          (b"\xdd" * 32).hex()]}
     assert sl.record_for(7, 20) == (11, b"\xbb" * 32)  # last write wins
+
+
+def test_a_surround_names_the_vote_it_found_not_a_later_one():
+    """A later vote of the same call at the surrounded target replaces
+    the record, not the root the surround hit names."""
+    sl = Slasher()
+    out = sl.on_attestations_bulk([
+        ([8], 10, 20, b"\xaa" * 32),
+        ([8], 5, 30, b"\xcc" * 32),       # surrounds (10, 20) of root aa
+        ([8], 11, 20, b"\xbb" * 32),      # double vote at 20
+    ])
+    surround, double = out[1][0], out[2][0]
+    assert surround.kind == "surround_vote"
+    assert surround.evidence["roots"][0] == (b"\xaa" * 32).hex()
+    assert double.kind == "double_vote"
+    assert sl.record_for(8, 20) == (11, b"\xbb" * 32)
 
 
 @pytest.mark.parametrize("db_kind", DBS)
