@@ -68,6 +68,7 @@ class RegistryColumns:
         "activation_epoch",
         "exit_epoch",
         "withdrawable_epoch",
+        "_active",
     )
 
     def __init__(self, validators) -> None:
@@ -93,15 +94,39 @@ class RegistryColumns:
         self.withdrawable_epoch = np.fromiter(
             (int(v.withdrawable_epoch) for v in vs), np.uint64, n
         )
+        # the active sets below are a function of these two: frozen, so
+        # a memoised set cannot outlive a change to them
+        self.activation_epoch.setflags(write=False)
+        self.exit_epoch.setflags(write=False)
+        #: epoch -> (active indices, their digest), the last few epochs
+        self._active: "dict[int, tuple[np.ndarray, bytes]]" = {}
 
     def __len__(self) -> int:
         return len(self.pubkeys)
 
-    def active_indices(self, epoch: int) -> np.ndarray:
+    def active_set(self, epoch: int) -> "tuple[np.ndarray, bytes]":
+        """(active indices at `epoch`, read-only; their digest, the
+        shuffle caches' key), computed once per epoch of this registry
+        view: every vote of an epoch looks its committee up through them."""
+        hit = self._active.get(epoch)
+        if hit is not None:
+            with _CACHE_LOCK:
+                _ACTIVE_SET_COUNTS[1] += 1
+            return hit
         e = np.uint64(epoch)
-        return np.nonzero(
+        active = np.nonzero(
             (self.activation_epoch <= e) & (e < self.exit_epoch)
         )[0].astype(np.int64)
+        active.setflags(write=False)
+        hit = (active, _active_digest(active))
+        with _CACHE_LOCK:
+            self._active[epoch] = hit
+            while len(self._active) > _ACTIVE_EPOCHS:
+                del self._active[next(iter(self._active))]
+        return hit
+
+    def active_indices(self, epoch: int) -> np.ndarray:
+        return self.active_set(epoch)[0]
 
 
 _COLUMNS_CACHE: OrderedDict = OrderedDict()  # id(items) -> (items, columns)
@@ -124,8 +149,26 @@ def registry_columns(state) -> RegistryColumns:
     return cols
 
 
+#: epochs of active sets a registry view keeps (the previous, current and
+#: next epoch, and one more)
+_ACTIVE_EPOCHS = 4
+
+#: [active-set digests computed, active sets found memoised], process-wide
+_ACTIVE_SET_COUNTS = [0, 0]
+
+
 def _active_digest(active: np.ndarray) -> bytes:
+    with _CACHE_LOCK:
+        _ACTIVE_SET_COUNTS[0] += 1
     return hashlib.blake2b(active.tobytes(), digest_size=16).digest()
+
+
+def active_set_totals() -> "tuple[int, int]":
+    """(active-set digests computed, active sets found memoised)
+    process-wide: about one digest per (registry view, epoch), not one per
+    committee lookup."""
+    with _CACHE_LOCK:
+        return _ACTIVE_SET_COUNTS[0], _ACTIVE_SET_COUNTS[1]
 
 
 # ------------------------------------------------------------ shuffle caches
@@ -142,9 +185,14 @@ _PARTITION_CACHE: OrderedDict = OrderedDict()
 
 
 def shuffled_active_indices(
-    seed: bytes, active: np.ndarray, p: Preset
+    seed: bytes, active: np.ndarray, p: Preset,
+    digest: "bytes | None" = None,
 ) -> np.ndarray:
-    key = (seed, _active_digest(active), p.SHUFFLE_ROUND_COUNT)
+    """`digest`: `active`'s, where the caller holds it
+    (`RegistryColumns.active_set`)."""
+    if digest is None:
+        digest = _active_digest(active)
+    key = (seed, digest, p.SHUFFLE_ROUND_COUNT)
     with _CACHE_LOCK:
         hit = _SHUFFLE_CACHE.get(key)
         if hit is not None:
@@ -160,11 +208,15 @@ def shuffled_active_indices(
 
 
 def committee_partition(
-    seed: bytes, active: np.ndarray, p: Preset
+    seed: bytes, active: np.ndarray, p: Preset,
+    digest: "bytes | None" = None,
 ) -> "list[np.ndarray]":
     """All committees of the epoch with shuffle seed `seed`, flat-indexed
-    k = (slot % SLOTS_PER_EPOCH) * committees_per_slot + committee_index."""
-    key = (seed, _active_digest(active), p.SHUFFLE_ROUND_COUNT,
+    k = (slot % SLOTS_PER_EPOCH) * committees_per_slot + committee_index.
+    `digest` as for `shuffled_active_indices`."""
+    if digest is None:
+        digest = _active_digest(active)
+    key = (seed, digest, p.SHUFFLE_ROUND_COUNT,
            p.SLOTS_PER_EPOCH, p.MAX_COMMITTEES_PER_SLOT,
            p.TARGET_COMMITTEE_SIZE)
     with _CACHE_LOCK:
@@ -172,7 +224,7 @@ def committee_partition(
         if hit is not None:
             _PARTITION_CACHE.move_to_end(key)
             return hit
-    shuffled = shuffled_active_indices(seed, active, p)
+    shuffled = shuffled_active_indices(seed, active, p, digest)
     n = len(shuffled)
     count = committee_count_per_slot(n, p) * p.SLOTS_PER_EPOCH
     hit = [
@@ -237,10 +289,10 @@ def get_committee_count_per_slot(state, epoch: int, p: Preset) -> int:
 
 def _attester_partition(state, epoch: int, p: Preset) -> "list[np.ndarray]":
     seed = misc.get_seed(state, epoch, DOMAIN_BEACON_ATTESTER, p)
-    active = get_active_validator_indices(state, epoch)
+    active, digest = registry_columns(state).active_set(epoch)
     if len(active) == 0:
         raise ValueError(f"no active validators at epoch {epoch}")
-    return committee_partition(seed, active, p)
+    return committee_partition(seed, active, p, digest)
 
 
 def get_beacon_committee(state, slot: int, index: int, p: Preset) -> np.ndarray:
@@ -380,12 +432,12 @@ def get_next_sync_committee_indices(state, cfg) -> "list[int]":
     p = cfg.preset
     epoch = get_current_epoch(state, p) + 1
     cols = registry_columns(state)
-    active = cols.active_indices(epoch)
+    active, digest = cols.active_set(epoch)
     n = len(active)
     if n == 0:
         raise ValueError("no active validators for sync committee")
     seed = misc.get_seed(state, epoch, DOMAIN_SYNC_COMMITTEE, p)
-    shuffled = shuffled_active_indices(seed, active, p)
+    shuffled = shuffled_active_indices(seed, active, p, digest)
     max_eb = p.MAX_EFFECTIVE_BALANCE
     out: "list[int]" = []
     i = 0
@@ -419,6 +471,7 @@ def get_next_sync_committee(state, types_ns, cfg):
 __all__ = [
     "RegistryColumns",
     "registry_columns",
+    "active_set_totals",
     "shuffled_active_indices",
     "committee_partition",
     "get_current_epoch",

@@ -425,6 +425,15 @@ class Metrics:
         self.att_slasher_feed_blocked = Counter(
             "attestation_slasher_feed_blocked_total",
             "hand-overs to the feeder that found its queue full")
+        # prevalidation decompresses no member key: a check off the
+        # resident registry's path does, for its own items (upload: no
+        # registry in sync; host: the host anchor)
+        self.att_member_keys_decompressed = LabeledCounter(
+            "attestation_member_keys_decompressed_total",
+            "member public keys the firehose decompressed on the host for "
+            "checks off the registry path",
+            ("path",),
+        )
         # the descent over a failed batch (_isolate): its probes are
         # calls of the batch's own executable, padded to the batch's
         # bucket — items / slots is what that padding costs

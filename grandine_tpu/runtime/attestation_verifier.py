@@ -97,6 +97,19 @@ class _BatchLife:
         self.pool_span = tracer.span("pool_wait", parent=self.root)
 
 
+class _MemberKeys:
+    """An item's committee members' public keys, decompressed on the host
+    the first time a check off the resident registry's path reads them
+    (`AttestationVerifier._member_keys`); the registry's path reads the
+    indices alone. `refused`: one of them does not decompress."""
+
+    __slots__ = ("points", "refused")
+
+    def __init__(self) -> None:
+        self.points: "Optional[list]" = None
+        self.refused = False
+
+
 class _Descent:
     """What one descent over a failed batch (`_isolate`) has found so far:
     the batch, the positions of the items named bad, the probes made."""
@@ -573,9 +586,14 @@ class AttestationVerifier:
                 ),
             )
         if ok:
+            # a check off the registry path verified all but the items
+            # whose keys it `refused` (`_member_keys`)
+            delivered = [p for p in prepared if not p[2].refused]
             with self._stats_lock:
-                self.stats["accepted"] += len(prepared)
-            self._feedback(prepared)
+                self.stats["accepted"] += len(delivered)
+                self.stats["rejected"] += len(prepared) - len(delivered)
+            if delivered:
+                self._feedback(delivered)
             fl.finish(True)
             return
         # batch failed: BISECT to the bad items with batch checks —
@@ -600,6 +618,9 @@ class AttestationVerifier:
             )
         if bad_count and self.metrics is not None:
             self.metrics.att_isolated_batches.inc()
+        good_ids = {id(p) for p in good_items}
+        delivered = [p for p in good_items if not p[2].refused]
+        bad_count += len(good_items) - len(delivered)
         if bad_count == 0:
             # the batch verdict said "invalid" yet bisection cleared
             # every item: a wrong-verdict device — file the fault kind
@@ -609,15 +630,14 @@ class AttestationVerifier:
         else:
             # attribute each bisection-named bad item to its gossip
             # origin (bounded top-K — the quarantine lane's feed)
-            good_ids = {id(p) for p in good_items}
             for p in prepared:
                 if id(p) not in good_ids:
                     fl.note_origin_failure(p[7])
         with self._stats_lock:
-            self.stats["accepted"] += len(good_items)
+            self.stats["accepted"] += len(delivered)
             self.stats["rejected"] += bad_count
-        if good_items:
-            self._feedback(good_items)
+        if delivered:
+            self._feedback(delivered)
         fl.finish(bad_count == 0)
 
     def _feedback(self, accepted) -> None:
@@ -763,9 +783,11 @@ class AttestationVerifier:
                 bucket_floor=floor,
             )
         else:
+            keyed = self._member_keys(prepared, "upload")
             ver_settle = backend.fast_aggregate_verify_batch_async(
-                messages, sigs, [p[2] for p in prepared], bucket_floor=floor,
-            )
+                [messages[i] for i in keyed], [sigs[i] for i in keyed],
+                [prepared[i][2].points for i in keyed], bucket_floor=floor,
+            ) if keyed else (lambda: True)
 
         def settle() -> bool:
             if sub_settle is not None and not bool(sub_settle().all()):
@@ -1076,9 +1098,11 @@ class AttestationVerifier:
     def _prevalidate(self, state, attestation):
         """Committee lookup + fork-choice windows; returns
         (signing_root, signature_bytes, member_keys, ValidAttestation,
-        attestation, member_indices, state_pubkey_columns) — the index
-        list and the state's compressed-pubkey tuple ride along so the
-        registry path can gather on-device without touching the keys."""
+        attestation, member_indices, state_pubkey_columns). No key is
+        decompressed here: the registry path gathers the members on the
+        device by index, and a check off it decompresses them from the
+        state's compressed-pubkey tuple into `member_keys`, a
+        `_MemberKeys` (`_member_keys`)."""
         p = self.cfg.preset
         data = attestation.data
         indices = accessors.get_attesting_indices(
@@ -1096,15 +1120,35 @@ class AttestationVerifier:
             idx_list,
         )
         root = signing.attestation_signing_root(state, data, self.cfg)
-        cols = accessors.registry_columns(state)
-        members = [
-            keys.decompress_pubkey(cols.pubkeys[i], trusted=True)
-            for i in idx_list
-        ]
         return (
-            root, bytes(attestation.signature), members, valid, attestation,
-            idx_list, cols.pubkeys,
+            root, bytes(attestation.signature), _MemberKeys(), valid,
+            attestation, idx_list, accessors.registry_columns(state).pubkeys,
         )
+
+    def _member_keys(self, prepared, path: str) -> "list[int]":
+        """The positions in `prepared` a check off the registry path
+        verifies, their members' keys decompressed into `p[2]` through the
+        process-wide key cache where no check has yet (`path`: "upload" or
+        "host", the counter's label). An item one of whose keys does not
+        decompress is left out: it is `refused`, and `_resolve_batch`
+        rejects it on its own while its batch-mates are judged without
+        it."""
+        decompressed = 0
+        for p in prepared:
+            held = p[2]
+            if held.points is None and not held.refused:
+                decompressed += len(p[5])
+                try:
+                    held.points = [
+                        keys.decompress_pubkey(p[6][i], trusted=True)
+                        for i in p[5]
+                    ]
+                except A.BlsError:
+                    held.refused = True
+        if decompressed and self.metrics is not None:
+            self.metrics.att_member_keys_decompressed.inc(
+                path, amount=decompressed)
+        return [i for i, p in enumerate(prepared) if not p[2].refused]
 
     #: evidence retention window (epochs) for building slashing ops
     SLASHER_EVIDENCE_EPOCHS = 64
@@ -1275,10 +1319,10 @@ class AttestationVerifier:
         with self._stage("execute", path="host", items=len(prepared)):
             try:
                 return all(
-                    A.Signature.from_bytes(p[1]).fast_aggregate_verify(
-                        p[0], p[2]
-                    )
-                    for p in prepared
+                    A.Signature.from_bytes(prepared[i][1])
+                    .fast_aggregate_verify(prepared[i][0],
+                                           prepared[i][2].points)
+                    for i in self._member_keys(prepared, "host")
                 )
             except A.BlsError:
                 return False

@@ -273,3 +273,145 @@ def test_committee_partition_is_cached_per_preset(first):
         want = accessors.committee_count_per_slot(64, p) * p.SLOTS_PER_EPOCH
         assert len(got) == want, name
         assert sorted(int(i) for c in got for i in c) == list(range(64))
+
+
+# ------------------------------------- the active set, once per registry view
+
+
+@pytest.fixture(scope="module")
+def mainnet_states():
+    """Two registries of one process at the mainnet preset's shapes (32
+    slots an epoch, 90 shuffle rounds), each on a minimal validator set:
+    256 and 320 validators on different mixes."""
+    from benchmark.generators.keys import ProgressionKeys
+
+    cfg = Config.mainnet()
+    keys_ = ProgressionKeys(320, 39).pubkey_bytes()
+    a = interop_genesis_state(256, cfg, pubkeys=keys_[:256])
+    b = interop_genesis_state(320, cfg, eth1_block_hash=b"\x17" * 32,
+                              pubkeys=keys_)
+    return cfg, a, b
+
+
+def _fresh_committee(state, slot: int, index: int, p) -> np.ndarray:
+    """Spec `get_beacon_committee` from the containers alone: the active
+    set read validator by validator, the seed, the whole-list shuffle; no
+    cache of the accessors."""
+    epoch = misc.compute_epoch_at_slot(slot, p)
+    active = np.array(
+        [i for i, v in enumerate(state.validators)
+         if int(v.activation_epoch) <= epoch < int(v.exit_epoch)],
+        dtype=np.int64)
+    seed = misc.get_seed(state, epoch, DOMAIN_BEACON_ATTESTER, p)
+    parts = misc.compute_committee_partition(active, seed, p)
+    per_slot = len(parts) // p.SLOTS_PER_EPOCH
+    return parts[(slot % p.SLOTS_PER_EPOCH) * per_slot + index]
+
+
+def _agree(state, epoch: int, p) -> int:
+    """Every committee of `epoch` and the attesting indices of every other
+    member, memoised against fresh; returns the lookups made."""
+    from types import SimpleNamespace
+
+    lookups = 0
+    count = accessors.get_committee_count_per_slot(state, epoch, p)
+    for slot in range(epoch * p.SLOTS_PER_EPOCH,
+                      (epoch + 1) * p.SLOTS_PER_EPOCH):
+        for index in range(count):
+            want = _fresh_committee(state, slot, index, p)
+            got = accessors.get_beacon_committee(state, slot, index, p)
+            assert got.tolist() == want.tolist(), (slot, index)
+            bits = np.arange(len(want)) % 2 == 0
+            data = SimpleNamespace(slot=slot, index=index)
+            assert accessors.get_attesting_indices(
+                state, data, SimpleNamespace(array=bits), p,
+            ).tolist() == want[bits].tolist()
+            lookups += 2
+    return lookups
+
+
+@pytest.mark.parametrize("case", ["epoch_boundary", "exit", "two_registries"])
+def test_memoised_committees_are_the_fresh_ones(mainnet_states, case):
+    """`get_beacon_committee` / `get_attesting_indices` through the active
+    set memoised on the registry view agree with a fresh computation: on
+    both sides of an epoch boundary (a state processed into the next
+    epoch), after an exit takes a validator out of the active set, and
+    with two registries looked up in turn. One digest at most is computed
+    per (registry view, epoch)."""
+    from grandine_tpu.transition.slots import process_slots
+
+    cfg, a, b = mainnet_states
+    p = cfg.preset
+    digests0 = accessors.active_set_totals()[0]
+    if case == "epoch_boundary":
+        post = process_slots(a, p.SLOTS_PER_EPOCH, cfg)
+        looked = [(a, 0), (a, 1), (post, 1), (post, 2), (a, 1)]
+    elif case == "exit":
+        draft = StateDraft(a, cfg)
+        mutators.initiate_validator_exit(draft, 7)
+        post = draft.commit()
+        gone = int(post.validators[7].exit_epoch)
+        assert gone < FAR_FUTURE_EPOCH
+        assert 7 in accessors.get_active_validator_indices(a, gone)
+        assert 7 not in accessors.get_active_validator_indices(post, gone)
+        looked = [(a, gone), (post, gone - 1), (post, gone), (a, gone)]
+    else:
+        looked = [(a, 0), (b, 0), (a, 0), (b, 1), (a, 1), (b, 0)]
+        assert (accessors.get_beacon_committee(a, 0, 0, p).tolist()
+                != accessors.get_beacon_committee(b, 0, 0, p).tolist())
+    for state, epoch in looked:
+        assert _agree(state, epoch, p) > 0
+    views = {(id(accessors.registry_columns(s)), e) for s, e in looked}
+    assert accessors.active_set_totals()[0] - digests0 <= len(views)
+    # a memoised active set cannot be written through
+    active = accessors.get_active_validator_indices(a, 0)
+    with pytest.raises(ValueError):
+        active[0] = 1
+
+
+def test_the_active_set_memo_holds_under_threads(mainnet_states):
+    """Sixteen threads look up eight epochs of one registry view (twice
+    what the memo keeps, so evictions race with lookups) under a short
+    switch interval: every answer is the fresh active set and its
+    digest, and the memo stays within its epochs."""
+    import hashlib
+    import sys
+    import threading
+
+    cfg, a, _b = mainnet_states
+    draft = StateDraft(a, cfg)
+    mutators.initiate_validator_exit(draft, 3)
+    cols = accessors.registry_columns(draft.commit())
+    gone = int(cols.exit_epoch[3])
+    epochs = range(gone - 4, gone + 4)
+    want = {}
+    for e in epochs:
+        active = np.array([i for i in range(len(cols))
+                           if cols.activation_epoch[i] <= e
+                           < cols.exit_epoch[i]], dtype=np.int64)
+        want[e] = (active.tolist(), hashlib.blake2b(
+            active.tobytes(), digest_size=16).digest())
+    assert want[gone][0] != want[gone - 1][0]
+    wrong = []
+
+    def look(k: int) -> None:
+        for i in range(100):
+            e = epochs[(k + i) % len(epochs)]
+            active, digest = cols.active_set(e)
+            if (active.tolist(), digest) != want[e]:
+                wrong.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=look, args=(k,))
+                   for k in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert wrong == []
+    assert len(cols._active) <= 4

@@ -53,12 +53,15 @@ class RecordingBackend:
     """The device seam, fused, with no kernel: a call is valid when every
     item in it is (`verdict(message, signature bytes, member indices)`).
     `calls` holds (kernel, (batch bucket, member bucket), items) per call,
-    the shape as tpu/bls.py `_aggregate_bucket` pads it."""
+    the shape as tpu/bls.py `_aggregate_bucket` pads it. The upload entry
+    answers only where `index_of` (compressed key -> validator index) is
+    given: a verifier with no registry."""
 
     fuse_subgroup = True
 
-    def __init__(self, verdict) -> None:
+    def __init__(self, verdict, index_of=None) -> None:
         self.verdict, self.calls = verdict, []
+        self.index_of = index_of
 
     def g2_subgroup_check_batch_async(self, points):
         raise AssertionError("fused: never called")
@@ -85,8 +88,13 @@ class RecordingBackend:
                                           bucket_floor=None):
         self._call(UPLOAD, messages, sigs, [len(ks) for ks in members],
                    bucket_floor)
-        raise AssertionError("the registry is in sync: the upload entry "
-                             "is not taken")
+        if self.index_of is None:
+            raise AssertionError("the registry is in sync: the upload "
+                                 "entry is not taken")
+        ok = all(self.verdict(m, A.g2_to_bytes(s.point),
+                              tuple(self.index_of[k.to_bytes()] for k in ks))
+                 for m, s, ks in zip(messages, sigs, members))
+        return lambda: ok
 
 
 @pytest.fixture(scope="module")
@@ -134,18 +142,21 @@ def wire(ns, item):
     )
 
 
-def held(items, delivered, probes) -> None:
+def held(items, delivered, probes, unkeyed=()) -> None:
     """The two invariants, on the probes as the descent made them, and
-    the batch's own order in what was delivered."""
+    the batch's own order in what was delivered. `unkeyed`: positions a
+    check off the registry path refused for a key that does not
+    decompress, rejected on their own without a probe."""
     place = {it.members[0]: i for i, it in enumerate(items)}
     got = [place[v] for v in delivered]
     assert got == sorted(set(got))
+    assert not set(got) & set(unkeyed)
     if not probes:  # the first pass verified: no descent
-        assert len(got) == len(items)
+        assert len(got) + len(unkeyed) == len(items)
         return
     verified = {i for part, ok in probes if ok for i in part}
     assert set(got) <= verified
-    for i in set(range(len(items))) - set(got):
+    for i in set(range(len(items))) - set(got) - set(unkeyed):
         assert ((i,), False) in probes
         assert i not in verified
 
@@ -162,24 +173,38 @@ def todays_probes(n, bad) -> int:
     return 1 if n == 1 else descend(0, n)
 
 
-def run_batch(genesis, items, verdict):
-    """`items` as ONE batch through a verifier over the recording backend,
-    registry in sync. Returns what was delivered (validator index), the
-    verifier's stats, the backend, the metrics, spans and flight rows, the
-    descent's probes as (positions, verdict), and the growth of the
-    compile scope's count over the batch. Asserts the invariants
-    (`held`)."""
+def run_batch(genesis, items, verdict, path="registry", unkeyed=()):
+    """`items` as ONE batch through a verifier over the recording backend:
+    by `path` with the registry in sync ("registry"), with no registry
+    ("upload") or with the breaker OPEN ("host": the host anchor, real
+    crypto). Returns what was delivered (validator index), the verifier's
+    stats, the backend, the metrics, spans and flight rows, the descent's
+    probes as (positions, verdict), the growth of the compile scope's
+    count and of the process-wide key cache over the batch. Asserts the
+    invariants (`held`, with `unkeyed` its positions refused for a key)."""
     from grandine_tpu.consensus import accessors
+    from grandine_tpu.consensus import keys as key_cache
+    from grandine_tpu.runtime import health as _health
     from grandine_tpu.transition.fork_upgrade import state_phase
     from grandine_tpu.types.containers import spec_types
 
     metrics, tracer = Metrics(), Tracer()
     ctrl = Controller(genesis, CFG, verifier_factory=NullVerifier,
                       metrics=metrics, tracer=tracer)
-    backend = RecordingBackend(verdict)
+    index_of = None
+    if path == "upload":
+        index_of = {bytes(v.pubkey): i
+                    for i, v in enumerate(genesis.validators)}
+    backend = RecordingBackend(verdict, index_of)
+    health = _health.BackendHealthSupervisor(
+        metrics=metrics, backoff_initial_s=3600.0, backoff_max_s=3600.0)
+    if path == "host":
+        for _ in range(health.breaker.fault_threshold):
+            health.record_fault("dispatch")
+        assert health.state == _health.OPEN
     verifier = AttestationVerifier(
         ctrl, backend=backend, use_device=True, max_batch=len(items),
-        deadline_s=0.5,
+        deadline_s=0.5, use_registry=path != "upload", health=health,
     )
     delivered = []
     inner = ctrl.on_valid_attestation_batch
@@ -205,12 +230,14 @@ def run_batch(genesis, items, verdict):
     verifier._batch_check = recorded_check
     try:
         state = ctrl.snapshot().head_state
-        assert verifier.registry.ensure(
-            accessors.registry_columns(state).pubkeys)
+        if verifier.registry is not None:
+            assert verifier.registry.ensure(
+                accessors.registry_columns(state).pubkeys)
         ns = getattr(spec_types(CFG.preset), state_phase(state, CFG).key)
         ctrl.on_tick(Tick(SLOT, TickKind.ATTEST))
         ctrl.wait()
         compiles0 = compile_scope.totals()[1]
+        cached0 = len(key_cache._CACHE)
         verifier.submit_many([wire(ns, it) for it in items])
         verifier.flush(timeout=120.0)
         ctrl.wait()
@@ -218,11 +245,12 @@ def run_batch(genesis, items, verdict):
         rows = [r.as_dict()
                 for r in verifier.flight.snapshot(lane="attestation")
                 if r.kind == BATCH]
-        held(items, delivered, probes)
+        held(items, delivered, probes, unkeyed)
         return {"delivered": delivered, "stats": dict(verifier.stats),
                 "backend": backend, "metrics": metrics, "probes": probes,
                 "spans": tracer.finished_spans(), "rows": rows,
-                "compiled": compiled, "breaker": verifier.health.state}
+                "compiled": compiled, "breaker": verifier.health.state,
+                "keys_cached": len(key_cache._CACHE) - cached0}
     finally:
         verifier.stop()
         ctrl.stop()
@@ -534,3 +562,154 @@ def test_a_batch_of_one_is_rechecked_once(chain, first_pass):
     (row,) = out["rows"]
     assert row["fault"] == ("verdict" if first_pass else None)
     assert row["probes"] == (1 if first_pass else 0)
+
+
+# -- member keys: decompressed only off the registry path ------------------
+
+PATHS = ["registry", "upload", "host"]
+
+
+def off_curve_key() -> bytes:
+    """A compressed G1 key whose x has no point on the curve: wire-well
+    formed (the registry takes it and its device decompressor zeroes the
+    row), refused by the host's decompression."""
+    for x in range(1, 64):
+        key = bytes([0x80]) + x.to_bytes(47, "big")
+        try:
+            A.g1_from_bytes(key, subgroup_check=False)
+        except A.BlsError:
+            return key
+    raise AssertionError("no x off the curve below 64")
+
+
+@pytest.fixture(scope="module")
+def unkeyed_chain(chain):
+    """The chain's validators with ONE of the slot's first four voters'
+    key replaced by `off_curve_key()` (the first whose genesis builds: its
+    sync committee must not hold the key), and the slot's votes made
+    anew over that genesis (committees do not depend on the keys).
+    Returns (genesis, the first four votes, the unkeyed voter's
+    position)."""
+    keys, _genesis, items = chain
+    for pos, item in enumerate(items[:4]):
+        pubkeys = list(keys.pubkey_bytes())
+        pubkeys[item.members[0]] = off_curve_key()
+        try:
+            genesis = interop_genesis_state(
+                N, CFG, eth1_block_hash=RANDAO_MIX, pubkeys=pubkeys)
+            break
+        except A.BlsError:
+            continue
+    ctrl = Controller(genesis, CFG, verifier_factory=NullVerifier)
+    try:
+        head = ctrl.snapshot()
+        state = head.head_state
+        ident = ChainIdentity(
+            genesis_validators_root=bytes(state.genesis_validators_root),
+            fork_version=bytes(state.fork.current_version),
+            anchor_root=bytes(head.head_root), randao_mix=RANDAO_MIX,
+        )
+    finally:
+        ctrl.stop()
+    votes = AttestationTraffic({"members": "single"}, SHAPES, keys, ident,
+                               SEED).slot_items(SLOT)[:4]
+    assert [it.members for it in votes] == [it.members for it in items[:4]]
+    return genesis, votes, pos
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_member_keys_are_decompressed_only_off_the_registry_path(
+        chain, path):
+    """Prevalidation decompresses no member key. Over the resident
+    registry nothing does: the counter stays at 0 and the process-wide
+    key cache does not grow. With no registry (the upload entry) and
+    with the breaker OPEN (the host anchor, real crypto) the check
+    decompresses its own items' keys, counted under its path, and the
+    valid batch is delivered whole."""
+    from grandine_tpu.consensus import keys as key_cache
+
+    keys, genesis, items = chain
+    batch = list(items[:4])
+    for it in batch:  # cold, as a validator's first vote finds its key
+        key_cache._CACHE.pop(keys.pubkey_bytes()[it.members[0]], None)
+    out = run_batch(genesis, batch, anchor_verdict(keys), path=path)
+    assert out["delivered"] == [it.members[0] for it in batch]
+    assert out["stats"]["accepted"] == 4 and out["stats"]["rejected"] == 0
+    counter = out["metrics"].att_member_keys_decompressed
+    members = sum(len(it.members) for it in batch)
+    for label in ("upload", "host"):
+        assert counter.value(label) == (members if label == path else 0)
+    kernels = {k for k, _s, _n in out["backend"].calls}
+    if path == "registry":
+        assert out["keys_cached"] == 0
+        assert kernels == {IDX}
+    else:
+        assert kernels == ({UPLOAD} if path == "upload" else set())
+    assert out["probes"] == []
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_a_key_that_does_not_decompress_rejects_its_vote_alone(
+        unkeyed_chain, path):
+    """A vote naming a key the host cannot decompress is rejected, and its
+    batch-mates are delivered, on every path. Over the registry the
+    device's validity mask fails the row closed (the recording backend
+    refuses any call that names it): the batch fails and the descent
+    names the vote by a probe of itself alone. Off it the check leaves
+    the vote out and it is rejected on its own: no descent."""
+    genesis, batch, pos = unkeyed_chain
+    bad = batch[pos].members[0]
+    sound = {it.signature for it in batch}
+
+    def verdict(message, sig_bytes, indices):
+        return sig_bytes in sound and bad not in indices
+
+    out = run_batch(genesis, batch, verdict, path=path,
+                    unkeyed=() if path == "registry" else (pos,))
+    assert out["delivered"] == [
+        it.members[0] for i, it in enumerate(batch) if i != pos]
+    assert out["stats"]["accepted"] == 3 and out["stats"]["rejected"] == 1
+    (row,) = out["rows"]
+    counter = out["metrics"].att_member_keys_decompressed
+    if path == "registry":
+        assert ((pos,), False) in out["probes"]
+        assert row["probes"] == len(out["probes"]) > 0
+        assert counter.value("upload") == counter.value("host") == 0
+    else:
+        assert out["probes"] == [] and row["fault"] is None
+        assert counter.value(path) == len(batch)
+        assert out["breaker"] == ("open" if path == "host" else "closed")
+    if path == "upload":
+        assert [n for _k, _s, n in out["backend"].calls] == [3]
+
+
+def test_a_batch_of_64_votes_computes_the_active_sets_digest_once(chain):
+    """A batch's prevalidation looks each vote's committee up through the
+    epoch's active set memoised on the registry view: over 64 votes on a
+    view no lookup has seen, ONE digest is computed and the other 63
+    lookups find it (`accessors.active_set_totals`)."""
+    from grandine_tpu.consensus import accessors
+    from grandine_tpu.transition.fork_upgrade import state_phase
+    from grandine_tpu.types.containers import spec_types
+
+    _keys, genesis, items = chain
+    ctrl = Controller(genesis, CFG, verifier_factory=NullVerifier)
+    verifier = AttestationVerifier(ctrl, use_device=False)
+    try:
+        ctrl.on_tick(Tick(SLOT, TickKind.ATTEST))
+        ctrl.wait()
+        state = ctrl.snapshot().head_state
+        ns = getattr(spec_types(CFG.preset), state_phase(state, CFG).key)
+        # the same registry under a new tuple: a view no lookup has seen
+        view = state.replace(validators=list(state.validators))
+        assert (accessors.registry_columns(view)
+                is not accessors.registry_columns(state))
+        digests0, hits0 = accessors.active_set_totals()
+        prepared = [verifier._prevalidate(view, wire(ns, it))
+                    for it in items]
+        digests, hits = accessors.active_set_totals()
+        assert len(prepared) == 64
+        assert (digests - digests0, hits - hits0) == (1, 63)
+    finally:
+        verifier.stop()
+        ctrl.stop()
